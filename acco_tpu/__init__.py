@@ -19,50 +19,4 @@ Three training modes over a `jax.sharding.Mesh`:
 
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental with the old
-    # ``check_rep`` spelling; the codebase targets the stable
-    # ``jax.shard_map(..., check_vma=...)`` API. Bridge once here (every
-    # module in the package imports acco_tpu first).
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-
-    _jax.shard_map = _compat_shard_map
-
-from jax.experimental.pallas import tpu as _pltpu
-
-if not hasattr(_pltpu, "CompilerParams"):
-    # jax < 0.5 names it TPUCompilerParams; same constructor surface.
-    _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-
-if not hasattr(_jax.lax, "axis_size"):
-    # jax < 0.4.38 has no lax.axis_size; psum of a static 1 constant-folds
-    # to the axis size as a Python int (product over an axis tuple), which
-    # is exactly axis_size's contract.
-    _jax.lax.axis_size = lambda axis_name: _jax.lax.psum(1, axis_name)
-
-if not hasattr(_jax, "typeof"):
-    # jax < 0.6 has no jax.typeof; core.get_aval is the same lookup.
-    # (block_attention only reads the aval's OPTIONAL .vma — the
-    # varying-mesh-axis set, which doesn't exist pre-vma and correctly
-    # reads as absent.)
-    _jax.typeof = lambda x: _jax.core.get_aval(x)
-
-if not hasattr(_jax.lax, "pcast"):
-    # jax < 0.7 has no lax.pcast and no varying-mesh-axis (vma) type
-    # system: every shard_map value is implicitly allowed to vary over
-    # the mesh axes, so the cast the ring-attention accumulators need
-    # under check_vma=True (replicated -> varying) is the identity here.
-    # (All shard_maps in this package pass check_vma=False, which the
-    # bridge above maps to check_rep=False — nothing checks rep types.)
-    _jax.lax.pcast = lambda x, axis_name, to=None: x
-
 from acco_tpu.configuration import ConfigNode, compose_config  # noqa: F401

@@ -94,8 +94,9 @@ def _flat_args(lowered) -> list[tuple[str, object, bool]]:
 
 def _kept_var_idx(compiled):
     """Indices of traced args kept after DCE, from the executable
-    internals when exposed (jaxlib 0.4.3x: ``MeshExecutable
-    ._kept_var_idx``) — the unambiguous entry-param alignment."""
+    internals when exposed (jax 0.9.0 still has ``MeshExecutable
+    ._kept_var_idx``; without it the caller aligns by shape) — the
+    unambiguous entry-param alignment."""
     if compiled is None:
         return None
     for obj in (compiled, getattr(compiled, "_executable", None)):

@@ -6,21 +6,20 @@ ACCO round programs, the DDP step, eval — is a deterministic function of
 byte-identically on every launch, every preemption-resume, and every test
 that constructs a trainer. JAX ships a persistent compilation cache keyed
 on the serialized HLO + compile options + jaxlib version that turns those
-recompiles into disk deserializations (~10x faster, measured in
-bench.py's ``compile_cold_ms`` vs ``compile_warm_ms``); this module is
-the one place that wires it up and counts what it does.
+recompiles into disk deserializations (on a v5e, GPT-Neo-125M ACCO:
+18-19 s per round program cold, 0.3-1.1 s warm; PERF.md); this module
+is the one place that wires it up and counts what it does.
 
-Two deliberate deviations from JAX's defaults:
+Where the cache lives is one rule (:func:`setup_compilation_cache`):
+``$JAX_COMPILATION_CACHE_DIR`` when the environment sets it, otherwise
+``<checkout>/outputs/compile_cache``. The path is part of what makes a
+relaunch hit, so no entry point derives it from the clock, the pid, a
+temp dir or the working directory.
 
-- ``min_compile_time_secs=0`` / ``min_entry_size_bytes=-1``: JAX skips
-  caching programs that compile in under a second, which is exactly the
-  population the 8-virtual-device CPU test suite compiles hundreds of
-  times over; caching everything is what lets structurally identical
-  tiny programs stop recompiling across tests (tests/conftest.py).
-- the cache dir is *respected if already configured*: the test conftest
-  claims it session-wide before any trainer runs, and a trainer
-  constructed inside a test must not silently re-point the session's
-  cache at its own run dir (``force=True`` is the explicit override).
+One deliberate deviation from JAX's defaults:
+``min_compile_time_secs=0`` / ``min_entry_size_bytes=-1``. JAX skips
+caching programs that compile in under a second; caching everything is
+what lets a relaunch of a small config report zero misses.
 
 Counters come from JAX's monitoring events (the same ones its own
 telemetry uses): ``cache_hits`` / ``compile_requests`` /
@@ -72,7 +71,7 @@ def _install_listeners() -> None:
     with _LOCK:
         if _LISTENERS_INSTALLED:
             return
-        from jax._src import monitoring
+        from jax import monitoring
 
         def on_event(event: str, **kwargs) -> None:
             if event == _HIT_EVENT:
@@ -205,28 +204,31 @@ def active_cache_dir() -> Optional[str]:
     return jax.config.jax_compilation_cache_dir
 
 
+#: The checkout this package lives in. A relative cache dir is resolved
+#: against it, never against the working directory: the path is part of
+#: what makes a relaunch hit, so it must not move with the caller's cwd.
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, "outputs", "compile_cache")
+
+
 def setup_compilation_cache(
-    cache_dir: str,
+    cache_dir: str = DEFAULT_CACHE_DIR,
     *,
     min_compile_time_secs: float = 0.0,
     min_entry_size_bytes: int = -1,
-    max_size_bytes: Optional[int] = None,
-    force: bool = False,
-    export_env: bool = False,
     log=None,
 ) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+    """Turn JAX's persistent compilation cache on and return its dir.
 
-    Returns the ACTIVE cache dir: ``cache_dir`` when it was applied, the
-    pre-existing dir when one was already configured (and ``force`` is
-    False — first configurer wins, so a session-wide cache set by
-    tests/conftest.py survives trainers constructed inside tests), or
-    None when ``cache_dir`` is falsy (explicit opt-out; existing config
-    untouched).
-
-    ``export_env=True`` additionally exports the settings as JAX_* env
-    vars so *subprocesses* (AOT canary tests, bench workers) inherit the
-    same cache.
+    Where the cache lives: ``$JAX_COMPILATION_CACHE_DIR`` if the
+    environment sets it (the cache was placed from outside, and nothing
+    in this repo points it anywhere else), otherwise ``cache_dir``, a
+    relative one resolved against the checkout (default
+    ``<checkout>/outputs/compile_cache``). A falsy ``cache_dir`` is the
+    opt-out: the configuration is left as it is and the dir that is
+    already active (or None) is returned.
     """
     log = log or _log
     _install_listeners()  # observability even when the dir was pre-set
@@ -235,35 +237,30 @@ def setup_compilation_cache(
     existing = jax.config.jax_compilation_cache_dir
     if not cache_dir:
         return existing or None
-    cache_dir = os.path.abspath(os.path.expanduser(str(cache_dir)))
-    if existing and os.path.abspath(existing) != cache_dir and not force:
-        log.debug(
-            "compile cache already at %s; leaving it (requested %s)",
-            existing,
-            cache_dir,
-        )
-        return existing
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        _CHECKOUT, os.path.expanduser(str(cache_dir))
+    )
+    if existing != cache_dir:
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # An abandoned warmup (close(wait=False)) may still be compiling
+        # on background threads; resetting the cache object under a live
+        # compile is a race, and those threads' monitoring events would
+        # land inside the NEXT warmup's counting window. Drain them first.
+        from acco_tpu.compile.warmup import drain_abandoned_compiles
+
+        drained = drain_abandoned_compiles()
+        if drained:
+            log.debug("drained %d abandoned warmup executor(s)", drained)
+        # jax memoizes its is-the-cache-usable verdict at the FIRST
+        # compile: a process that compiled anything before this call —
+        # model init, a device_put — has the verdict frozen at "unused"
+        # and would silently never read or write the dir we just
+        # configured. Reset so the next compile re-evaluates.
+        from jax.experimental.compilation_cache import compilation_cache
+
+        compilation_cache.reset_cache()
     jax.config.update("jax_enable_compilation_cache", True)
-    # An abandoned warmup (close(wait=False)) may still be compiling on
-    # background threads; resetting the cache object under a live
-    # compile is a race, and those threads' monitoring events would land
-    # inside the NEXT warmup's counting window. Drain them first.
-    from acco_tpu.compile.warmup import drain_abandoned_compiles
-
-    drained = drain_abandoned_compiles()
-    if drained:
-        log.debug("drained %d abandoned warmup executor(s)", drained)
-    # jax memoizes its is-the-cache-usable verdict at the FIRST compile
-    # (compilation_cache._cache_checked/_cache_used): a process that
-    # compiled anything before this call — model init, a device_put —
-    # has the verdict frozen at "unused" and would silently never read
-    # or write the dir we just configured. Reset to pristine so the next
-    # compile re-evaluates against the new settings.
-    from jax._src import compilation_cache as _cc
-
-    _cc.reset_cache()
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs",
         float(min_compile_time_secs),
@@ -271,18 +268,4 @@ def setup_compilation_cache(
     jax.config.update(
         "jax_persistent_cache_min_entry_size_bytes", int(min_entry_size_bytes)
     )
-    if max_size_bytes is not None:
-        jax.config.update("jax_compilation_cache_max_size", int(max_size_bytes))
-    if export_env:
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
-        os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = str(
-            float(min_compile_time_secs)
-        )
-        os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = str(
-            int(min_entry_size_bytes)
-        )
-        if max_size_bytes is not None:
-            os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] = str(
-                int(max_size_bytes)
-            )
     return cache_dir

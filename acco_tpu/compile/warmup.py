@@ -71,10 +71,8 @@ class ProgramCompileRecord:
     # The jax.stages.Compiled executable. Callers SHOULD dispatch through
     # it (aot_call_with_fallback): jax's AOT and jit paths are separate,
     # so a plain jit call after warmup re-enters the compile path — an
-    # avoidable persistent-cache deserialization, and on this jaxlib
-    # (0.4.36 CPU) cache reads after an Orbax restore can segfault the
-    # process (observed; see DecoupledTrainer._train). The AOT call
-    # touches no cache at dispatch time.
+    # avoidable persistent-cache deserialization. The AOT call touches
+    # no cache at dispatch time.
     compiled: Optional[object] = None
     # Persistent-cache counters attributed to THIS program's compile at
     # event time (cache.attribute_cache_events): the compile thread

@@ -100,8 +100,7 @@ def resolve_attention_impl(
     inside the 'fused' plan at L <= 1024, and as the local-layer branch
     of the einsum plan past it (GPTNeoModel._dense_attn_plan) — so this
     resolver only ever decides the GLOBAL layers' impl. The L=2048
-    fused-vs-flash-noremat crossover point is queued on the chip
-    battery (chip_watch.sh flag_l2048); fold the verdict in here.
+    fused-vs-flash-noremat crossover has not been measured (ROADMAP S3).
     """
     impl = normalize_attention_impl(impl)
     remat = normalize_remat(remat)  # '0'/'false' must mean remat-OFF

@@ -253,6 +253,7 @@ def _banded_fwd(q, k, v, window, scale, interpret):
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="acco_banded_attn_fwd",
     )(q, *([k] * n_band), *([v] * n_band))
     from jax.ad_checkpoint import checkpoint_name
 
@@ -287,6 +288,7 @@ def _banded_bwd(window, scale, interpret, res, g):
         out_shape=jax.ShapeDtypeStruct((B, H, L, D), jnp.float32),
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="acco_banded_attn_dq",
     )(q, *([k] * n_band), *([v] * n_band), lse, delta, g)
 
     # dkv pass: views over q blocks kb..kb+n_band-1 (clamped at the top)
@@ -326,6 +328,7 @@ def _banded_bwd(window, scale, interpret, res, g):
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="acco_banded_attn_dkv",
     )(
         k, v, *([q] * n_band), *([lse] * n_band), *([delta] * n_band),
         *([g] * n_band),

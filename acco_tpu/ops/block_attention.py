@@ -221,6 +221,7 @@ def _blk_fwd(q, k, v, window, q_pos, kv_pos, scale, diag, interpret):
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
+        name="acco_block_attn_fwd",
     )(*pos_args, q, k, v)
     outs = (o, m.reshape(B, H, Lq), l.reshape(B, H, Lq))
     return outs, (q, k, v, window, q_pos, kv_pos, m)
@@ -276,6 +277,7 @@ def _blk_bwd(scale, diag, interpret, res, g):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="acco_block_attn_bwd",
     )(*pos_args, q, k, v, m, do, dm, dl)
     return (
         dq.astype(q.dtype),

@@ -211,6 +211,7 @@ def _attn_fwd(q, k, v, window, pad_mask, scale, interpret):
         ],
         compiler_params=_compiler_params(bwd=False),
         interpret=interpret,
+        name="acco_fused_attn_fwd",
     )(*args)
     # Named so the 'dots' remat policy (models/layers.wrap_remat) can
     # save the kernel's outputs: a pallas_call is not a "dot", so under
@@ -258,6 +259,7 @@ def _attn_bwd(scale, interpret, res, g):
         ],
         compiler_params=_compiler_params(bwd=True),
         interpret=interpret,
+        name="acco_fused_attn_bwd",
     )(*args)
     return (
         dq.astype(q.dtype),

@@ -285,6 +285,7 @@ def _lm_head_ce_fwd(h, w, tgt, v_real, rb, vt, interpret):
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="acco_fused_ce_fwd",
     )(vreal, h, w, tgt3)
     outs = (lse.reshape(N), tl.reshape(N), sl.reshape(N))
     return outs, (h, w, tgt, v_real, lse)
@@ -343,6 +344,7 @@ def _lm_head_ce_bwd(rb, vt, interpret, res, g):
                 jax.ShapeDtypeStruct((D, Vp), jnp.float32),
             ],
             scratch_shapes=[pltpu.VMEM((D, vt), jnp.float32)],
+            name="acco_fused_ce_bwd",
             **cp_common,
         )(*args)
         return (
@@ -369,6 +371,7 @@ def _lm_head_ce_bwd(rb, vt, interpret, res, g):
         out_specs=pl.BlockSpec((rb, D), lambda r, t: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((rb, D), jnp.float32)],
+        name="acco_fused_ce_bwd_dh",
         **cp_common,
     )(*args)
     row_tr = pl.BlockSpec((1, rb, 1), lambda t, r: (r, 0, 0))
@@ -388,6 +391,7 @@ def _lm_head_ce_bwd(rb, vt, interpret, res, g):
         out_specs=pl.BlockSpec((D, vt), lambda t, r: (0, t)),
         out_shape=jax.ShapeDtypeStruct((D, Vp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((D, vt), jnp.float32)],
+        name="acco_fused_ce_bwd_dw",
         **cp_common,
     )(*args)
     return dh.astype(h.dtype), dw.astype(w.dtype), None, None

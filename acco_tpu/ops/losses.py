@@ -78,11 +78,13 @@ def resolve_fused_loss(fused_loss, model, real_vocab, warn=None,
                        platform=None):
     """THE fused-loss capability gate, shared by the train paths
     (parallel/common.make_flat_loss_fn, parallel/pp.make_pp_loss_fn) and
-    the eval path (trainer) so they can never diverge: downgrade
+    the eval path (trainer) so they can never diverge: an explicit
     'pallas' outside the kernel envelope (ops/fused_ce.
-    supports_fused_ce) to 'chunk', and 'chunk' with Megatron vocab
-    padding (which it predates) to the materialized path. Requires the
-    model to expose ``hidden``/``lm_head``. ``n_vocab_shards``: the
+    supports_fused_ce) raises on the TPU platform — a kernel that was
+    asked for and cannot run is an error there, not a slower program —
+    and downgrades to 'chunk' elsewhere (CPU interpreter runs); 'chunk'
+    with Megatron vocab padding (which it predates) goes to the
+    materialized path. Requires the model to expose ``hidden``/``lm_head``. ``n_vocab_shards``: the
     vocab dim is sharded this many ways (tp, or pp·tp pipelined) — the
     envelope must hold for the PER-SHARD slice the kernel actually
     tiles, and the sharded fallback is always the materialized
@@ -106,11 +108,9 @@ def resolve_fused_loss(fused_loss, model, real_vocab, warn=None,
                 "hidden/lm_head surface; using materialized logits"
             )
         return False
+    if platform is None:
+        platform = jax.devices()[0].platform
     if fused_loss == "auto":
-        if platform is None:
-            import jax
-
-            platform = jax.devices()[0].platform
         fused_loss = _auto_fused_policy(
             model, n_vocab_shards, seq_sharded, platform
         )
@@ -129,6 +129,13 @@ def resolve_fused_loss(fused_loss, model, real_vocab, warn=None,
         if not supports_fused_ce(8, cfg.hidden_size, v_local):
             if requested == "auto":
                 return False
+            if platform == "tpu":
+                raise ValueError(
+                    f"fused_loss='pallas': hidden {cfg.hidden_size} / "
+                    f"per-shard vocab {v_local} is outside the kernel's "
+                    "envelope (ops/fused_ce.supports_fused_ce); ask for "
+                    "fused_loss='auto' or False instead"
+                )
             if warn is not None:
                 fallback = (
                     "'chunk'"

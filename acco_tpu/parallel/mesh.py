@@ -60,8 +60,14 @@ def initialize_distributed(log=log) -> dict:
             "n_nodes": len(hosts),
             "id_run": os.environ.get("SLURM_JOBID", "local"),
         }
+    # A single TPU host can carry the worker variables too (one name in
+    # TPU_WORKER_HOSTNAMES): one process drives all of its chips there,
+    # and a rendezvous with nobody would only wait.
+    tpu_hosts = [
+        h for h in os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",") if h.strip()
+    ]
     if "JAX_COORDINATOR_ADDRESS" in os.environ or (
-        "TPU_WORKER_HOSTNAMES" in os.environ and "TPU_WORKER_ID" in os.environ
+        len(tpu_hosts) > 1 and "TPU_WORKER_ID" in os.environ
     ):
         # TPU pod slice: jax.distributed autodetects from TPU metadata.
         jax.distributed.initialize()

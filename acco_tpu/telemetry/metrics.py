@@ -325,8 +325,6 @@ DECLARED: Tuple[MetricSpec, ...] = (
           "microbatch blocks consumed from the prefetch source"),
     _spec("loader_block_wait_ms", HISTOGRAM, "ms",
           "consumer wait per block (0 when the prefetcher ran ahead)"),
-    _spec("loader_host_stall_ms", GAUGE, "ms",
-          "bench: per-round host stall attributable to data loading"),
     # -- serve scheduler / server (serve/{scheduler,server}.py) --
     _spec("serve_requests_total", COUNTER, "requests",
           "generation requests submitted"),

@@ -122,6 +122,7 @@ class DecoupledTrainer:
         initial_params: Optional[dict] = None,
         shutdown_handler: Optional[ShutdownHandler] = None,
     ) -> None:
+        self._t_construct = time.time()
         self.model = model
         # Pretrained start (the reference's finetune mode, main.py:33-35):
         # when given, these weights replace the random init in train().
@@ -361,64 +362,18 @@ class DecoupledTrainer:
         # Compile-once subsystem (acco_tpu/compile). Persistent cache
         # first: every compile below this line — warmup or lazy — lands
         # in (or is served from) the cache, so a preemption-resume or
-        # repeat launch of the same config compiles nothing. Launches
-        # that share the dir across runs (main.py's configs point at
-        # outputs/compile_cache) get cross-launch reuse. '' disables; an
-        # already-configured dir (a caller-level setup) wins over the
-        # default. The DEFAULT is platform-split: on TPU the cache is on
-        # (dir under run_dir); on CPU it must be requested explicitly —
-        # jaxlib 0.4.36's CPU client segfaults when a process both
-        # executes cache-deserialized programs and runs an Orbax restore
-        # (reproduced; see the quarantine below), which is survivable
-        # for a single-trainer launch but not for multi-trainer hosts
-        # like the test suite, so multi-trainer-prone dict-args
-        # construction defaults to off.
+        # repeat launch of the same config compiles nothing. The config
+        # files name outputs/compile_cache (resolved against the
+        # checkout; $JAX_COMPILATION_CACHE_DIR wins over it). Without
+        # the key — a trainer built in code — the cache configuration
+        # is left as the caller set it.
         from acco_tpu.compile import setup_compilation_cache
 
         self.compile_cache_dir = setup_compilation_cache(
-            _arg(
-                args,
-                "compile_cache_dir",
-                os.path.join(self.run_dir, "compile_cache")
-                if jax.devices()[0].platform == "tpu"
-                else "",
-            ),
-            log=self.log,
+            _arg(args, "compile_cache_dir", ""), log=self.log
         )
         self.compile_report = None
         self._warmup = None
-        # Cache/restore quarantine: on jaxlib 0.4.36's CPU client,
-        # executing cache-DESERIALIZED programs in a trainer that also
-        # runs an Orbax/tensorstore restore segfaults the process
-        # (C++-level race; reproduced reliably in the resume tests, never
-        # without the cache, never without the restore). A resuming
-        # trainer on the CPU backend therefore compiles fresh — cache
-        # disabled for its lifetime, re-enabled when train() exits (or
-        # when __init__ fails); later trainers in the same process use
-        # the cache safely (verified). Known residual: a resume trainer
-        # constructed but never train()ed keeps the cache off — there is
-        # no safe earlier point to re-enable, since its warmup compiles
-        # run from construction until train()'s restore completes.
-        # TPU deserialization is a different code path and keeps the
-        # cache on resume — the compile-nothing preemption-restart is the
-        # whole point there.
-        self._cache_quarantined = False
-        if (
-            self.compile_cache_dir
-            and _arg(args, "resume_from")
-            and jax.devices()[0].platform == "cpu"
-        ):
-            self.log.info(
-                "resume on the CPU backend: persistent compile cache "
-                "disabled for this trainer (jaxlib-0.4.36 CPU "
-                "deserialize/restore race); compiles run fresh"
-            )
-            jax.config.update("jax_enable_compilation_cache", False)
-            self._cache_quarantined = True
-        # Everything below may raise (bad data, bad config): the
-        # quarantine's process-global disable must not outlive a
-        # failed constructor — later trainers in this process are
-        # promised the cache back.
         try:
             self.warmup_compile = bool(_arg(args, "warmup_compile", True))
             if self.warmup_compile:
@@ -562,9 +517,6 @@ class DecoupledTrainer:
             if self._warmup is not None:
                 self._submit_eval_warmup()
         except BaseException:
-            if self._cache_quarantined:
-                jax.config.update("jax_enable_compilation_cache", True)
-                self._cache_quarantined = False
             # A failed constructor must not leave warmup threads queueing
             # new compiles (close cancels the unstarted ones; in-flight
             # XLA compiles are uncancellable and finish in the background).
@@ -907,9 +859,8 @@ class DecoupledTrainer:
             # DIRECTLY instead of re-entering jit's compile path (jax
             # keeps AOT and jit caches separate, so a jit call after
             # warmup would re-deserialize from the persistent cache —
-            # wasted work, and on jaxlib 0.4.36's CPU client a cache
-            # read after an Orbax restore can segfault the process;
-            # the AOT call touches no cache at dispatch time).
+            # wasted work; the AOT call touches no cache at dispatch
+            # time).
             step = self._warmup.step
             for name, rec in report.programs.items():
                 if not rec.ok or rec.compiled is None:
@@ -1004,12 +955,6 @@ class DecoupledTrainer:
             # compiles finish in the background and only warm the cache).
             if self._warmup is not None:
                 self._warmup.runner.close(wait=False)
-            # End of the resume quarantine window: this trainer's
-            # programs are all built, so later trainers in the process
-            # get the cache back.
-            if self._cache_quarantined:
-                jax.config.update("jax_enable_compilation_cache", True)
-                self._cache_quarantined = False
             if installed:
                 self._shutdown.uninstall()
             if own_handler:
@@ -1050,11 +995,7 @@ class DecoupledTrainer:
         # Join the background AOT warmup (started at construction and
         # overlapped with tokenize / loader setup / state init above):
         # past this line every program this run dispatches holds its
-        # compiled executable, installed for direct AOT dispatch. Joined
-        # BEFORE the resume restore below on purpose: persistent-cache
-        # reads concurrent with (or after) an Orbax/tensorstore restore
-        # segfault this jaxlib's CPU client (observed on 0.4.36), so all
-        # cache I/O must be finished before any restore begins.
+        # compiled executable, installed for direct AOT dispatch.
         t_wj = time.perf_counter()
         self.join_warmup()
         warmup_join_ms = (time.perf_counter() - t_wj) * 1e3
@@ -1194,7 +1135,7 @@ class DecoupledTrainer:
             if self.method in ("acco", "dpu")
             else 0
         )
-        last_metrics = None
+        first_metrics = last_metrics = None
         # Host half of the watchdog, fresh per train(): fed at the
         # logging boundary (piggybacking the existing device fetch), it
         # classifies spikes vs drift and escalates K consecutive guard-
@@ -1242,6 +1183,9 @@ class DecoupledTrainer:
         interrupted = False
         window_mark = 0  # round_wall_ms index of the open attribution window
         last_round_end_us = None  # tracer-clock end of the previous round
+        # Construction to first dispatch: tokenisation, state init, the
+        # compile warmup's join, the resume restore.
+        setup_s = time.time() - self._t_construct
 
         while True:
             if count_grad_tot >= self.nb_grad_tot:
@@ -1291,6 +1235,8 @@ class DecoupledTrainer:
                 # exactly what a real anomaly would produce.
                 state, block = injector.apply(rounds_this_run, state, block)
             state, last_metrics = fn(state, block)
+            if first_metrics is None:
+                first_metrics = last_metrics
             dispatch_ms = (tracer.now_us() - ts_fetch) / 1e3
             rounds_done += 1
             rounds_this_run += 1
@@ -1688,9 +1634,17 @@ class DecoupledTrainer:
         self.final_state = state
         self.step_obj = step
         return {
+            # loss of this run's first round (kept as a device scalar
+            # until here: no sync is added to the loop) and of its last
+            "first_loss": (
+                float(first_metrics.loss)
+                if first_metrics is not None
+                else float("nan")
+            ),
             "final_loss": final_loss,
             "count_grad_tot": int(count_grad_tot),
             "rounds": rounds_done,
+            "setup_s": setup_s,
             "total_time_s": total_time,
             "method": self.method,
             # True = stopped by a shutdown request (preemption/SIGTERM)
@@ -2108,23 +2062,6 @@ class DecoupledTrainer:
         # may still be writing the very step dir we are about to
         # restore, and Orbax save/restore of one tree must not overlap.
         self.ckpt_manager.wait()
-        if (
-            self.compile_cache_dir
-            and not self._cache_quarantined
-            and jax.devices()[0].platform == "cpu"
-        ):
-            # Same jaxlib-0.4.36 hazard as the resume quarantine in
-            # __init__ (cache-deserialized execution + Orbax restore in
-            # one CPU process segfaults): a mid-run rollback is a
-            # restore, so the cache goes dark for the rest of this
-            # trainer — re-enabled in train()'s finally.
-            self.log.info(
-                "rollback on the CPU backend: persistent compile cache "
-                "disabled for the rest of this trainer (jaxlib-0.4.36 "
-                "deserialize/restore race)"
-            )
-            jax.config.update("jax_enable_compilation_cache", False)
-            self._cache_quarantined = True
         state, meta = restore_checkpoint(path, state)
         self.train_loader.set_state(fence)
         new_source = PrefetchingBlockSource(

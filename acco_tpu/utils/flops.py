@@ -11,9 +11,6 @@ model bigger.
 
 from __future__ import annotations
 
-import os
-import re
-
 
 def llama_train_flops_per_token(cfg, seq_len: int) -> float:
     """Matmul train-FLOPs per token for acco_tpu's Llama family.
@@ -46,41 +43,41 @@ def gpt_neo_train_flops_per_token(cfg, seq_len: int) -> float:
     return 3.0 * fwd
 
 
-# Peak dense bf16 TFLOP/s per JAX device, keyed on substrings of
-# jax.Device.device_kind. (v2/v3 list per-core numbers because one JAX
-# device is one core there; v4+ are megacore chips.)
-_PEAK_BF16_TFLOPS = (
-    ("v6", 918.0),
-    ("v5p", 459.0),
-    ("v5 lite", 197.0),
-    ("v5litepod", 197.0),
-    ("v5e", 197.0),
-    ("v5", 459.0),
-    ("v4", 275.0),
-    ("v3", 61.25),
-    ("v2", 22.5),
-)
+# Peak dense bf16 TFLOP/s of one JAX device, keyed by the exact
+# ``jax.Device.device_kind``, each with where the figure comes from. A
+# kind that is not listed is an error, not a default.
+PEAK_BF16_TFLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip.
+    # libtpu reports the chip as "TPU v5 lite"; "TPU v5e" is the name
+    # jax's mesh_utils also knows it by.
+    "TPU v5 lite": 197.0,
+    "TPU v5e": 197.0,
+    # Google Cloud documentation, "TPU v5p": 459 TFLOP/s bf16 per chip.
+    "TPU v5p": 459.0,
+    # Google Cloud documentation, "TPU v4": 275 TFLOP/s bf16 per chip.
+    "TPU v4": 275.0,
+    # Google Cloud documentation, "TPU v6e": 918 TFLOP/s bf16 per chip.
+    "TPU v6 lite": 918.0,
+}
 
 
-def peak_bf16_tflops(device_kind: str) -> float | None:
-    """Peak bf16 TFLOP/s for a device kind string, or None if unknown.
-
-    ``ACCO_BENCH_PEAK_TFLOPS`` overrides (e.g. for new chip generations).
-    """
-    env = os.environ.get("ACCO_BENCH_PEAK_TFLOPS")
-    if env:
-        return float(env)
-    kind = re.sub(r"[_-]", " ", device_kind.lower())
-    for key, peak in _PEAK_BF16_TFLOPS:
-        if key in kind:
-            return peak
-    return None
+def peak_bf16_tflops(device_kind: str) -> float:
+    """Peak bf16 TFLOP/s for an exact ``device_kind``; raises on a kind
+    the table does not hold."""
+    try:
+        return PEAK_BF16_TFLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak bf16 FLOP/s on record for device_kind "
+            f"{device_kind!r}; add it to PEAK_BF16_TFLOPS with its source "
+            f"(known: {sorted(PEAK_BF16_TFLOPS)})"
+        ) from None
 
 
-def mfu(tokens_per_sec_per_chip: float, flops_per_token: float, device_kind: str):
-    """Model FLOPs utilization in [0, 1], or None when the chip's peak is
-    unknown (CPU fallback runs)."""
+def mfu(
+    tokens_per_sec_per_chip: float, flops_per_token: float, device_kind: str
+) -> float:
+    """Model FLOPs utilization in [0, 1]. A device with no peak on
+    record (every CPU) raises: such a run has no MFU."""
     peak = peak_bf16_tflops(device_kind)
-    if peak is None:
-        return None
     return tokens_per_sec_per_chip * flops_per_token / (peak * 1e12)

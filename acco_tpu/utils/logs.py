@@ -83,8 +83,8 @@ def save_result(path_to_result_csv: str, dict_result: Dict[str, Any]) -> None:
 
     Every row appended through this function is by definition a live
     machine append, so it defaults ``provenance='measured'`` — the flag
-    that lets ledger consumers (chip_watch verification, step_estimate
-    calibration) filter out hand-restored rows, which carry
+    that lets ledger consumers (step_estimate calibration) filter out
+    hand-restored rows, which carry
     ``provenance='restored'`` (round-5 ADVICE #4)."""
     dict_result = dict(dict_result)
     dict_result.setdefault("provenance", "measured")

@@ -24,6 +24,14 @@ import yaml
 
 
 def main(argv: list[str] | None = None) -> dict:
+    """Compose the config, train, return the trainer's summary."""
+    return run(argv)[1]
+
+
+def run(argv: list[str] | None = None):
+    """``main`` for a caller that wants to look at the trainer afterwards
+    (chip_smoke.py reads its compiled programs and final state):
+    returns ``(trainer, summary)``."""
     argv = sys.argv[1:] if argv is None else argv
     repo_root = os.path.dirname(os.path.abspath(__file__))
 
@@ -44,18 +52,13 @@ def main(argv: list[str] | None = None) -> dict:
     log = logging.getLogger("acco_tpu")
     log.info("run dir: %s", run_dir)
 
-    from acco_tpu.utils.platform import maybe_force_cpu_platform
-
-    maybe_force_cpu_platform()
-
-    # Compile-once subsystem (acco_tpu/compile): point the persistent
-    # compilation cache at the config's dir BEFORE anything compiles.
-    # The default in config/train/*.yaml is outputs/compile_cache —
-    # shared across launches and preemption-resumes of the same config,
-    # so a repeat run compiles nothing (a resume on the CPU backend
-    # compiles fresh: the trainer quarantines the cache around Orbax
-    # restores there — see DecoupledTrainer). Set
-    # train.compile_cache_dir='' to disable.
+    # Compile-once subsystem (acco_tpu/compile): turn the persistent
+    # compilation cache on BEFORE anything compiles. It lives at
+    # $JAX_COMPILATION_CACHE_DIR if that is set, else at the config's
+    # dir (outputs/compile_cache in config/train/*.yaml, resolved
+    # against the checkout) — shared across launches and
+    # preemption-resumes of the same config, so a repeat run compiles
+    # nothing. Set train.compile_cache_dir='' to disable.
     cache_dir = cfg.train.get("compile_cache_dir")
     if cache_dir:
         from acco_tpu.compile import setup_compilation_cache
@@ -177,7 +180,7 @@ def main(argv: list[str] | None = None) -> dict:
                 int(cfg.train.get("nb_steps_tot", 0)),
             )
     log.info("done: %s", summary)
-    return summary
+    return trainer, summary
 
 
 if __name__ == "__main__":

@@ -82,32 +82,19 @@ def main(argv=None):
     with open(args.config) as f:
         cfg = yaml.safe_load(f) or {}
 
-    from acco_tpu.utils.platform import maybe_force_cpu_platform
-
-    maybe_force_cpu_platform()
-
     from acco_tpu.utils.checkpoint import resolve_serving_checkpoint
 
     step_dir = resolve_serving_checkpoint(args.resume_from, log=log)
-    has_npz = os.path.exists(os.path.join(step_dir, "params.npz"))
 
     import jax
 
-    # Persistent compile cache — same quarantine rule as the trainer: on
-    # the CPU backend, mixing cache-deserialized executables with an
-    # Orbax restore in one process segfaults (jaxlib 0.4.36), and a
-    # periodic save (no params.npz) forces the Orbax path.
+    # Persistent compile cache: a relaunch deserializes the bucket
+    # programs instead of compiling them.
     cache_dir = cfg.get("compile_cache_dir")
-    if cache_dir and (has_npz or jax.default_backend() != "cpu"):
+    if cache_dir:
         from acco_tpu.compile import setup_compilation_cache
 
         log.info("compile cache: %s", setup_compilation_cache(cache_dir, log=log))
-    elif cache_dir:
-        log.info(
-            "compile cache disabled: CPU backend + Orbax restore path "
-            "(no params.npz in %s) — jaxlib cache/restore quarantine",
-            step_dir,
-        )
 
     import jax.numpy as jnp
 

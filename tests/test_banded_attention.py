@@ -1,10 +1,10 @@
 """Banded sliding-window attention kernel (ops/banded_attention.py).
 
 Parity against the einsum reference (the same oracle the full fused
-kernel tests use), the GPT-Neo model-level cond dispatch, the envelope
-gate, and AOT Mosaic canaries at the real GPT-Neo pretrain dims — the
-interpreter accepts layouts Mosaic rejects, so every kernel here ships
-with a lowering canary (round-4 lesson)."""
+kernel tests use), the GPT-Neo model-level cond dispatch and the envelope
+gate. The interpreter accepts layouts Mosaic rejects, so the kernels are
+also compiled for the chip at the real GPT-Neo pretrain dims, in
+tests/test_tpu_compile.py."""
 
 import jax
 import jax.numpy as jnp
@@ -168,81 +168,4 @@ def test_gptneo_einsum_plan_banded_local_matches_xla(monkeypatch):
     assert xla._dense_attn_plan(128, None)[1] is False
     np.testing.assert_allclose(
         logits(auto), logits(xla), atol=2e-4, rtol=2e-4
-    )
-
-
-_AOT_SCRIPT = r"""
-import jax, jax.numpy as jnp
-from jax.experimental import topologies
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-import numpy as np
-import sys
-sys.path.insert(0, {repo!r})
-from acco_tpu.ops.banded_attention import banded_dot_product_attention
-
-topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-mesh = Mesh(np.array(list(topo.devices)[:1]), ("d",))
-rep = NamedSharding(mesh, P())
-
-B, H, L, D, W = {shape}
-q = jax.ShapeDtypeStruct((B, H, L, D), jnp.bfloat16, sharding=rep)
-k = jax.ShapeDtypeStruct((B, H, L, D), jnp.bfloat16, sharding=rep)
-v = jax.ShapeDtypeStruct((B, H, L, D), jnp.bfloat16, sharding=rep)
-
-def loss(q, k, v):
-    o = banded_dot_product_attention(q, k, v, window=W, interpret=False)
-    return jnp.sum(o.astype(jnp.float32) ** 2)
-
-jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v).compile()
-print("AOT_OK")
-"""
-
-
-def _jaxlib_version() -> tuple:
-    import jaxlib
-
-    return tuple(int(p) for p in jaxlib.__version__.split(".")[:3])
-
-
-@pytest.mark.tpu_aot
-@pytest.mark.xfail(
-    _jaxlib_version() <= (0, 4, 36),
-    reason=(
-        "jaxlib<=0.4.36 Mosaic rejects the banded kernel's lse store "
-        "layout — the [1, 1, QB] block's implicit-dim change "
-        "('Unsupported implicit dim change: from \"32,{0,*},(8,128),-1\" "
-        "to none') — at fwd lowering; the interpreter and newer Mosaic "
-        "accept it. Known F since the round-4 canary sweep; re-evaluate "
-        "on the next jaxlib bump."
-    ),
-    strict=False,
-)
-@pytest.mark.parametrize(
-    "shape",
-    [
-        (8, 12, 1024, 64, 256),  # GPT-Neo-125M flagship local layer
-        (8, 20, 1024, 128, 256),  # GPT-Neo-2.7B dims (head_dim 128)
-        (2, 2, 4096, 64, 256),  # long-seq: past the full kernel's wall
-    ],
-    ids=["neo125m", "neo27b", "l4096"],
-)
-def test_aot_tpu_lowering(shape):
-    """Mosaic lowering canary for all three banded kernels (fwd, dq,
-    dkv) at the dims the pretrain configs actually run."""
-    import os
-    import subprocess
-    import sys as _sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {
-        k: v for k, v in os.environ.items()
-        if k not in ("JAX_PLATFORMS", "ACCO_FUSED_ATTN_INTERPRET")
-    }
-    script = _AOT_SCRIPT.format(repo=repo, shape=shape)
-    proc = subprocess.run(
-        [_sys.executable, "-c", script],
-        capture_output=True, text=True, timeout=600, env=env, cwd=repo,
-    )
-    assert proc.returncode == 0 and "AOT_OK" in proc.stdout, (
-        proc.stderr[-3000:]
     )

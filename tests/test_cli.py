@@ -17,10 +17,8 @@ import main as main_mod
 def _run_main(tmp_path, monkeypatch, overrides):
     monkeypatch.chdir(tmp_path)  # outputs/ land in the tmp dir
     # These mains run IN-PROCESS: the configs' persistent compile cache
-    # must stay off here — one pytest process mixing cache-deserialized
-    # program execution with the suite's Orbax restores segfaults
-    # jaxlib 0.4.36's CPU client (see tests/conftest.py; subprocess
-    # runs inherit the session cache through the environment instead).
+    # stays off, so a test run neither writes into the checkout's
+    # outputs/compile_cache nor depends on what an earlier run left there.
     return main_mod.main(["train.compile_cache_dir="] + overrides)
 
 

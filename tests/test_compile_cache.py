@@ -8,12 +8,6 @@ program is genuinely different — serving stale HLO would be a
 correctness bug), and must still HIT when only runtime-side knobs change
 (checkpoint cadence is not part of any compiled program — recompiling
 for it would be the startup-cost bug this subsystem exists to kill).
-
-Safety envelope note: these tests only construct trainers and
-``join_warmup()`` — train() is never called on a cache-warm trainer, so
-no cache-deserialized program is ever EXECUTED in this process (the
-jaxlib-0.4.36 CPU combination of that with the suite's later Orbax
-restores is the segfault documented in tests/conftest.py).
 """
 
 import numpy as np
@@ -97,14 +91,17 @@ def _cache_files(cache_dir):
 
 
 @pytest.fixture
-def compile_cache_dir(tmp_path):
+def compile_cache_dir(tmp_path, monkeypatch):
     """Isolated cache dir for one test; jax's global cache config (and
     its memoized is-cache-used verdict) restored afterwards so the rest
-    of the suite stays in its uncached envelope."""
-    from jax._src import compilation_cache as cc
+    of the suite stays uncached. A cache the caller of pytest placed
+    through the environment would win over the test's own dir, so the
+    variable is hidden for the test."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
     from acco_tpu.compile import drain_abandoned_compiles
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_enable = jax.config.jax_enable_compilation_cache
     yield str(tmp_path / "compile-cache")
@@ -298,15 +295,60 @@ def test_aot_fallback_on_aval_mismatch(caplog):
     assert len(calls) == 2
 
 
-def test_setup_respects_existing_dir(tmp_path, compile_cache_dir):
-    """First configurer wins without force=True — a trainer's default
-    must not re-point a session-level cache."""
+def test_cache_placed_from_outside_is_left_alone(
+    eight_devices, tmp_path, compile_cache_dir, monkeypatch
+):
+    """With JAX_COMPILATION_CACHE_DIR set, that directory is the cache:
+    neither a direct call nor a trainer's config points it elsewhere."""
+    from acco_tpu.compile import active_cache_dir, setup_compilation_cache
+
+    placed = str(tmp_path / "placed-from-outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    # jax reads the variable once, at import: stand in for that
+    jax.config.update("jax_compilation_cache_dir", placed)
+
+    elsewhere = tmp_path / "elsewhere"
+    assert setup_compilation_cache(str(elsewhere)) == placed
+    trainer = _trainer(tmp_path / "elsewhere-too", tmp_path / "run")
+    assert trainer.compile_cache_dir == placed
+    assert active_cache_dir() == placed
+    assert not elsewhere.exists() and not (tmp_path / "elsewhere-too").exists()
+    assert trainer.join_warmup().cache_dir == placed
+
+
+def test_default_cache_is_in_the_checkout_from_any_cwd(
+    tmp_path, compile_cache_dir, monkeypatch
+):
+    """Unset, the cache is <checkout>/outputs/compile_cache, resolved
+    against the checkout and not the working directory."""
+    import os
+
     from acco_tpu.compile import setup_compilation_cache
 
-    first = setup_compilation_cache(compile_cache_dir)
-    assert first == str(compile_cache_dir)
-    other = str(tmp_path / "other-cache")
-    active = setup_compilation_cache(other)
-    assert active == first
-    forced = setup_compilation_cache(other, force=True)
-    assert forced == other
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    expected = os.path.join(checkout, "outputs", "compile_cache")
+    monkeypatch.chdir(tmp_path)
+    assert setup_compilation_cache() == expected
+    # what config/train/*.yaml spell, and main.py hands over as it is
+    assert setup_compilation_cache("outputs/compile_cache") == expected
+    assert not (tmp_path / "outputs").exists()
+    # '' is the opt-out: nothing changes
+    assert setup_compilation_cache("") == expected
+
+
+def test_no_entry_point_makes_up_a_cache_path():
+    """No mkdtemp, pid or clock in any cache path: a directory that moves
+    never hits."""
+    import os
+    import re
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    suspects = re.compile(r"mkdtemp|gettempdir|getpid|strftime|time\.time")
+    for rel in (
+        "main.py", "bench.py", "serve.py", "chip_smoke.py",
+        "tools/compile_report.py", "acco_tpu/compile/cache.py",
+    ):
+        with open(os.path.join(checkout, rel)) as f:
+            for n, line in enumerate(f, 1):
+                if "cache" in line.lower() and suspects.search(line):
+                    raise AssertionError(f"{rel}:{n}: {line.strip()}")

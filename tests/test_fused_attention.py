@@ -246,136 +246,3 @@ def test_bf16_inputs():
     np.testing.assert_allclose(
         got.astype(np.float32), want.astype(np.float32), atol=3e-2, rtol=3e-2
     )
-
-
-# ---------------------------------------------------------------------------
-# AOT TPU lowering canaries (no chips needed — jax.experimental.topologies)
-# ---------------------------------------------------------------------------
-
-_AOT_SCRIPT = r"""
-import jax, jax.numpy as jnp
-from jax.experimental import topologies
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-import numpy as np
-import sys, os
-sys.path.insert(0, {repo!r})
-from acco_tpu.ops.fused_attention import fused_dot_product_attention
-
-topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-dev = list(topo.devices)[:1]
-mesh = Mesh(np.array(dev), ("d",))
-rep = NamedSharding(mesh, P())
-
-B, H, Hkv, L, D = {shape}
-q = jax.ShapeDtypeStruct((B, H, L, D), jnp.bfloat16, sharding=rep)
-k = jax.ShapeDtypeStruct((B, Hkv, L, D), jnp.bfloat16, sharding=rep)
-v = jax.ShapeDtypeStruct((B, Hkv, L, D), jnp.bfloat16, sharding=rep)
-pad = jax.ShapeDtypeStruct((B, L), jnp.int32, sharding=rep)
-
-def loss(q, k, v, pad):
-    o = fused_dot_product_attention(
-        q, k, v, pad_mask={pad_arg}, window={window}, interpret=False
-    )
-    return jnp.sum(o.astype(jnp.float32) ** 2)
-
-jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v, pad).compile()
-print("AOT_OK")
-"""
-
-
-@pytest.mark.tpu_aot
-@pytest.mark.parametrize(
-    "shape,window,pad_arg",
-    [
-        ((8, 12, 12, 1024, 64), 0, "None"),  # flagship Llama-125M
-        ((2, 8, 2, 1024, 64), 0, "None"),  # GQA (Llama-3 family)
-        ((2, 12, 12, 1024, 64), 256, "pad"),  # GPT-Neo local layer + pad
-        ((1, 32, 8, 512, 128), 0, "None"),  # Llama-3-8B dims, placement seq
-        ((2, 12, 12, 2048, 64), 0, "None"),  # envelope ceiling (16 MB tile)
-    ],
-    ids=["flagship", "gqa", "windowed_pad", "llama3_8b", "l2048"],
-)
-def test_aot_tpu_lowering(shape, window, pad_arg):
-    """The Pallas interpreter accepts block shapes Mosaic rejects (the
-    round-4 [B, H, L] LSE bug shipped green through 16 interpreter
-    tests); this AOT-compiles fwd+bwd against the real TPU toolchain so
-    a lowering violation fails the suite, not the first chip run."""
-    import os
-    import subprocess
-    import sys as _sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {
-        k: v for k, v in os.environ.items()
-        if k not in ("JAX_PLATFORMS", "ACCO_FUSED_ATTN_INTERPRET")
-    }
-    script = _AOT_SCRIPT.format(
-        repo=repo, shape=shape, window=window, pad_arg=pad_arg
-    )
-    proc = subprocess.run(
-        [_sys.executable, "-c", script],
-        capture_output=True, text=True, timeout=600, env=env, cwd=repo,
-    )
-    assert proc.returncode == 0 and "AOT_OK" in proc.stdout, (
-        proc.stderr[-3000:]
-    )
-
-
-_REMAT_COUNT_SCRIPT = r"""
-import sys, re
-sys.path.insert(0, {repo!r})
-import numpy as np
-import jax, jax.numpy as jnp
-import jax.tree_util as jtu
-from jax.experimental import topologies
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from acco_tpu.models.llama import LlamaConfig, LlamaModel
-
-topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-mesh = Mesh(np.array(list(topo.devices)[:1]), ("d",))
-rep = NamedSharding(mesh, P())
-cfg = LlamaConfig(
-    vocab_size=512, hidden_size=128, num_layers=2, num_heads=2,
-    num_kv_heads=2, intermediate_size=256, max_position_embeddings=128,
-)
-model = LlamaModel(cfg, param_dtype=jnp.bfloat16, remat={remat!r},
-                   attention="fused")
-shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
-params = jtu.tree_map(
-    lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep), shapes)
-ids = jax.ShapeDtypeStruct((2, 128), jnp.int32, sharding=rep)
-def loss(p, ids):
-    return jnp.mean(model.apply(p, ids).astype(jnp.float32) ** 2)
-hlo = jax.jit(jax.grad(loss)).lower(params, ids).compile().as_text()
-print("MOSAIC_CALLS", len(re.findall(r"tpu_custom_call", hlo)))
-"""
-
-
-@pytest.mark.tpu_aot
-def test_dots_remat_does_not_rerun_fused_forward_kernel():
-    """The 'dots' policy saves the kernel's named outputs (attn_out,
-    attn_lse — layers.wrap_remat), so the backward re-trace must NOT
-    contain a second forward kernel: exactly 2 Mosaic custom-calls in
-    the whole grad program (fwd kernel in the fwd scan, bwd kernel in
-    the bwd scan), the same count as remat=False. A third call means
-    the policy lost the names and every layer's forward kernel runs
-    twice per step."""
-    import os
-    import subprocess
-    import sys as _sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {
-        k: v for k, v in os.environ.items()
-        if k not in ("JAX_PLATFORMS", "ACCO_FUSED_ATTN_INTERPRET")
-    }
-    counts = {}
-    for remat in ("dots", False):
-        proc = subprocess.run(
-            [_sys.executable, "-c",
-             _REMAT_COUNT_SCRIPT.format(repo=repo, remat=remat)],
-            capture_output=True, text=True, timeout=600, env=env, cwd=repo,
-        )
-        assert proc.returncode == 0, proc.stderr[-3000:]
-        counts[remat] = int(proc.stdout.split("MOSAIC_CALLS")[1].split()[0])
-    assert counts["dots"] == counts[False] == 2, counts

@@ -132,7 +132,7 @@ def test_make_mesh_aot_topology_ring():
     import sys as _sys
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [_sys.executable, "-c", _AOT_RING_SCRIPT.format(repo=repo)],
         capture_output=True, text=True, timeout=600, env=env, cwd=repo,

@@ -424,27 +424,7 @@ def test_gptneo_tp_pp_composed_matches_dp(eight_devices):
 
 # -- pp x sp composition ----------------------------------------------------
 
-@pytest.mark.parametrize(
-    "zigzag",
-    [
-        pytest.param(
-            False,
-            marks=pytest.mark.xfail(
-                strict=False,
-                reason=(
-                    "jaxlib 0.4.36 CPU: the non-zigzag (contiguous) ring "
-                    "layout uses the pcast-identity lane, whose CE "
-                    "reduction order differs from the dense reference by "
-                    "a few f32 ULPs; Adam amplifies that to rel ~2e-3 on "
-                    "the final params over 4 rounds. Pre-existing (PR 4 "
-                    "baseline); zigzag layout is bit-stable and stays "
-                    "strict."
-                ),
-            ),
-        ),
-        True,
-    ],
-)
+@pytest.mark.parametrize("zigzag", [False, True])
 def test_ddp_pp_sp_composed_matches_dp(eight_devices, zigzag):
     """dp x pp x sp: ring attention runs INSIDE every pipeline stage (the
     sequence sharded over sp, activations flowing stages over pp), the
@@ -506,26 +486,7 @@ def test_acco_pp_sp_composed_matches_dp(eight_devices):
     _assert_trees_close(_dense(ref, s_ref), _pp_dense(comp, s_c))
 
 
-@pytest.mark.parametrize(
-    "zigzag",
-    [
-        pytest.param(
-            False,
-            marks=pytest.mark.xfail(
-                strict=False,
-                reason=(
-                    "jaxlib 0.4.36 CPU: same non-zigzag pcast-identity "
-                    "ULP divergence as test_ddp_pp_sp_composed_matches_dp "
-                    "(Adam-amplified to rel ~4e-3 here — the windowed "
-                    "pattern touches fewer kv pages per step, so fewer "
-                    "terms average the rounding out). Pre-existing (PR 4 "
-                    "baseline); zigzag stays strict."
-                ),
-            ),
-        ),
-        True,
-    ],
-)
+@pytest.mark.parametrize("zigzag", [False, True])
 def test_gptneo_ddp_pp_sp_composed_matches_dp(eight_devices, zigzag):
     """GPT-Neo pp x sp (the reference's flagship pretrain model on the
     full composition matrix): windowed ring attention runs inside every

@@ -7,8 +7,8 @@ ppermute chain but lowers the 32-participant case blocking (measured
 the compiler, not of this code: a libtpu upgrade can move the cliff in
 either direction and would otherwise only show up as a silent perf
 regression. These tests AOT-compile tiny probe programs (no chips
-needed, ~30 s each) and fail loudly when the compiler's behavior no
-longer matches the constant:
+needed, ~3 s each on jax 0.9.0) and fail loudly when the compiler's
+behavior no longer matches the constant:
 
 * 16-device flat ring still converts async -> _FLAT_RING_MAX may stay >= 16;
 * 32-device flat ring still does NOT -> _FLAT_RING_MAX must stay < 32
@@ -28,11 +28,9 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _probe(case: str):
-    # subprocess: the TPU AOT toolchain must initialize outside this
-    # session's jax_platforms=cpu forcing (conftest)
-    env = {
-        k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS",)
-    }
+    # a child process: describing the topology takes libtpu's lock, which
+    # this pytest process must leave to the other tpu_aot children
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [
             sys.executable, os.path.join(_REPO, "tools", "permute_probe.py"),

@@ -183,17 +183,6 @@ def test_acco_tp_matches_dp(eight_devices):
     _assert_trees_close(finals["dp"], finals["tp"], **TRAJ_TOL)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason=(
-        "jaxlib 0.4.36 CPU: the dp x sp ring (pcast-identity lane, tp "
-        "absent) reassociates the head-dim contractions differently from "
-        "the dp x sp x tp lane; the one-ULP logit differences are "
-        "Adam-amplified over the 4 rounds to rel ~2e-3 on a handful of "
-        "params — pre-existing trajectory divergence (since PR 4), not a "
-        "sharding bug (the single-round losses agree to rtol 1e-5)."
-    ),
-)
 def test_acco_tp_with_context_parallelism(eight_devices):
     """dp x sp x tp (8 devices) vs dp x sp: ring attention composes with
     tensor parallelism (sequence sharded over sp, heads over tp)."""

@@ -3,9 +3,9 @@
 
 Shows what the compile-once subsystem (acco_tpu/compile) does for the
 programs a given config would dispatch: each program's lower + compile
-wall ms on a COLD persistent cache, the same through the WARM cache (a
-disk deserialization — what a repeat launch or preemption-resume pays),
-and the hit/miss counters. No dataset, tokenizer, or training state is
+wall ms on a first pass (cold wherever the cache reports a miss), the
+same through the cache that pass filled (a disk deserialization — what
+a repeat launch or preemption-resume pays), and the hit/miss counters. No dataset, tokenizer, or training state is
 touched — programs are lowered from abstract avals only, so the report
 runs in seconds on a laptop CPU for any config whose model fits in host
 memory.
@@ -15,22 +15,18 @@ Usage (same override surface as main.py)::
     python tools/compile_report.py train=acco model=tiny
     python tools/compile_report.py train=ddp model=gptneo \
         train.batch_size=4 train.max_length=512
-    python tools/compile_report.py train=acco model=tiny \
-        --cache-dir /tmp/my-cache --keep-cache
 
-By default the report uses a throwaway temp cache dir (so 'cold' is
-really cold); --cache-dir points it at a real one — e.g. the run cache
-from config/train/*.yaml (outputs/compile_cache) to check what a
-relaunch of that config would actually hit.
+The cache is the one a run of that config uses: $JAX_COMPILATION_CACHE_DIR
+if it is set, else <checkout>/outputs/compile_cache. So the first pass
+also says what a relaunch of the config would hit today; empty that
+directory (or point the variable at an empty one) for a cold reading.
 """
 
 from __future__ import annotations
 
 import argparse
 import os
-import shutil
 import sys
-import tempfile
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
@@ -46,25 +42,12 @@ def main(argv: list[str] | None = None) -> int:
         help="main.py-style config overrides (train=acco model=tiny ...)",
     )
     parser.add_argument(
-        "--cache-dir",
-        default=None,
-        help="persistent cache dir to measure against (default: fresh temp dir)",
-    )
-    parser.add_argument(
-        "--keep-cache",
-        action="store_true",
-        help="don't delete the cache dir afterwards (temp dirs included)",
-    )
-    parser.add_argument(
         "--skip-warm",
         action="store_true",
-        help="cold pass only (e.g. to just pre-populate a cache dir)",
+        help="first pass only (e.g. to fill the cache before a launch)",
     )
     args = parser.parse_args(argv)
 
-    from acco_tpu.utils.platform import maybe_force_cpu_platform
-
-    maybe_force_cpu_platform()
     # CPU-runnable by construction: give the report a multi-device mesh
     # even on a laptop, like tests/conftest.py does.
     flags = os.environ.get("XLA_FLAGS", "")
@@ -84,9 +67,7 @@ def main(argv: list[str] | None = None) -> int:
 
     cfg = compose_config(os.path.join(REPO_ROOT, "config"), args.overrides)
 
-    cache_dir = args.cache_dir or tempfile.mkdtemp(prefix="acco-compile-report-")
-    own_cache = args.cache_dir is None
-    setup_compilation_cache(cache_dir, force=True)
+    cache_dir = setup_compilation_cache()
 
     import jax.numpy as jnp
 
@@ -193,20 +174,18 @@ def main(argv: list[str] | None = None) -> int:
         f"n_acc={n_acc} global_batch={global_bs} seq={seq}"
     )
     print(f"cache dir: {cache_dir}")
-    cold, _ = one_pass("cold (populates the cache)")
+    cold, _ = one_pass("first pass (compiles what the cache does not hold)")
     if not args.skip_warm:
         warm, wdelta = one_pass("warm (what a relaunch/resume pays)")
         cold_ms = sum(r.compile_ms or 0.0 for r in cold.programs.values())
         warm_ms = sum(r.compile_ms or 0.0 for r in warm.programs.values())
         if warm_ms > 0:
             print(
-                f"\ncompile-once win: cold {cold_ms:.0f} ms -> warm "
+                f"\ncompile-once win: first pass {cold_ms:.0f} ms -> warm "
                 f"{warm_ms:.0f} ms ({cold_ms / warm_ms:.1f}x), "
                 f"{wdelta['hits']} program(s) served from the cache"
             )
     print(f"\ntotals this process: {cache_stats()}")
-    if own_cache and not args.keep_cache:
-        shutil.rmtree(cache_dir, ignore_errors=True)
     return 0
 
 

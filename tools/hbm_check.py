@@ -54,10 +54,6 @@ def build(model_json: str, n_devices: int, dp: int, tp: int, seq: int, bs: int,
           remat, fused_loss, comm: str = "ring", pp: int = 1,
           n_acc: int = 1, attn: str = "auto", sp: int = 1):
     import jax
-
-    from acco_tpu.utils.platform import force_cpu_platform
-
-    force_cpu_platform()
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, NamedSharding
@@ -625,9 +621,6 @@ def main() -> None:
         serve_report(args.serve_config, args.hbm_gb)
         return
     if args.sweep:
-        from acco_tpu.utils.platform import force_cpu_platform
-
-        force_cpu_platform()
         sweep_report(args.devices, args.hbm_gb)
         return
 

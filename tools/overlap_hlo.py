@@ -75,10 +75,6 @@ def build_round(
     model_json: str | None = None,
 ):
     import jax
-
-    from acco_tpu.utils.platform import force_cpu_platform
-
-    force_cpu_platform()
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding

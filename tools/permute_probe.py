@@ -47,10 +47,6 @@ def _pairs(kind: str, n: int):
 
 def probe(kind: str, n_devices: int, hops: int, payload_mb: float) -> dict:
     import jax
-
-    from acco_tpu.utils.platform import force_cpu_platform
-
-    force_cpu_platform()
     import re
 
     import jax.numpy as jnp

@@ -59,10 +59,6 @@ def main() -> None:
 
     import jax
 
-    from acco_tpu.utils.platform import maybe_force_cpu_platform
-
-    maybe_force_cpu_platform()
-
     import jax.numpy as jnp
 
     from acco_tpu.models.llama import LlamaConfig, LlamaModel

@@ -240,10 +240,6 @@ def build_ddp(n_devices: int, seq: int, bs_per_chip: int, n_layers: int,
     """DDP analog of overlap_hlo.build_round: abstract state + batches for
     an AOT topology compile of DDPTrainStep.step_fn."""
     import jax
-
-    from acco_tpu.utils.platform import force_cpu_platform
-
-    force_cpu_platform()
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding
