@@ -18,7 +18,12 @@ a literal first argument is the contract):
   ``*.instant("name", …)`` → must be declared in
   :data:`acco_tpu.telemetry.trace.SPAN_NAMES`, unless the call's
   ``cat`` is a :data:`~acco_tpu.telemetry.trace.FREE_CATEGORIES` member
-  (the conftest's pytest-nodeid events).
+  (the conftest's pytest-nodeid events);
+- ``*.named_scope("name")`` (``jax.named_scope``) → must be one of
+  :data:`acco_tpu.telemetry.trace.DEVICE_SCOPES`: the benchmark's device
+  metrics select ops by these names, so a scope outside the list is time
+  no metric owns. Here a non-literal argument is a finding too: there is
+  no runtime check behind this one.
 
 Dynamic names (a variable first argument) are left to the runtime
 check — the closed world still catches them on first execution; this
@@ -35,11 +40,12 @@ from dataclasses import dataclass, field
 
 from acco_tpu.analysis.host_lint import DEFAULT_EXCLUDE_DIRS, Finding
 from acco_tpu.telemetry.metrics import REGISTRY
-from acco_tpu.telemetry.trace import FREE_CATEGORIES, SPAN_NAMES
+from acco_tpu.telemetry.trace import DEVICE_SCOPES, FREE_CATEGORIES, SPAN_NAMES
 
 METRIC_METHODS = {"emit"}
 METRIC_MANY_METHODS = {"emit_many"}
 SPAN_METHODS = {"span", "complete_event", "instant"}
+SCOPE_METHODS = {"named_scope"}
 
 
 @dataclass
@@ -117,6 +123,16 @@ class _TelemetryCallVisitor(ast.NodeVisitor):
                 "spelling)",
             ))
 
+    def _check_scope(self, node: ast.Call, name: str | None) -> None:
+        self.report.checked += 1
+        if name not in DEVICE_SCOPES:
+            self.report.findings.append(Finding(
+                self.path, node.lineno, "undeclared-scope",
+                f"named_scope of {name!r}, which is not a literal member of "
+                "telemetry.trace.DEVICE_SCOPES (closed world: declare it "
+                "there, and say in PERF.md which metric reads it)",
+            ))
+
     def visit_Call(self, node: ast.Call) -> None:
         meth = _method_name(node)
         if meth in METRIC_METHODS and node.args:
@@ -136,6 +152,8 @@ class _TelemetryCallVisitor(ast.NodeVisitor):
                 cat = _span_cat(node)
                 if cat not in FREE_CATEGORIES:
                     self._check_span(node, name)
+        elif meth in SCOPE_METHODS and node.args:
+            self._check_scope(node, _literal_str(node.args[0]))
         self.generic_visit(node)
 
 

@@ -261,6 +261,12 @@ def setup_compilation_cache(
 
         compilation_cache.reset_cache()
     jax.config.update("jax_enable_compilation_cache", True)
+    # By default the cache's key leaves an instruction's metadata out, so
+    # a program that differs from a cached one only in its named scopes
+    # (telemetry.trace.DEVICE_SCOPES) or source lines is served the
+    # cached executable with the OTHER source's names: a profile of it
+    # would attribute device time to scopes this source does not have.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs",
         float(min_compile_time_secs),

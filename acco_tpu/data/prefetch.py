@@ -192,10 +192,6 @@ class PrefetchingBlockSource:
         # current (possibly just-restored) position so a checkpoint
         # written before the first consume resumes correctly
         self._consumed_state: Dict[str, int] = dict(loader.iter_state())
-        # telemetry: how long the CONSUMER blocked for the last block
-        # (0-ish when the prefetch worker ran ahead) — the trainer's
-        # step attribution reads this instead of re-timing the call.
-        self.last_wait_ms = 0.0
         self._prefetch = bool(prefetch) and depth > 0
         if self._prefetch:
             self._worker: AsyncPrefetcher | None = AsyncPrefetcher(
@@ -228,9 +224,8 @@ class PrefetchingBlockSource:
         # with prefetch on this is pure queue wait — the residual the
         # async pipeline failed to hide — and with prefetch off it is
         # the full collate+transfer cost on the critical path.
-        self.last_wait_ms = (time.perf_counter() - t0) * 1e3
         metrics.emit("loader_blocks_total", 1)
-        metrics.emit("loader_block_wait_ms", self.last_wait_ms)
+        metrics.emit("loader_block_wait_ms", (time.perf_counter() - t0) * 1e3)
         return block
 
     def iter_state(self) -> Dict[str, int]:

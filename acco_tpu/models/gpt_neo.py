@@ -250,12 +250,13 @@ class GPTNeoModel:
         attention_mask: Optional[jax.Array] = None,
     ) -> jax.Array:  # [B, L, V] f32 logits ([B, L, V/tp] local under tp)
         x = self.hidden(params, input_ids, attention_mask)
-        return jnp.einsum(
-            "bld,dv->blv",
-            x,
-            self.lm_head(params),
-            preferred_element_type=jnp.float32,
-        )
+        with jax.named_scope("model/lm_head_ce"):
+            return jnp.einsum(
+                "bld,dv->blv",
+                x,
+                self.lm_head(params),
+                preferred_element_type=jnp.float32,
+            )
 
     def lm_head(self, params: dict) -> jax.Array:
         """[D, V] output projection (GPT-Neo always ties to wte); under
@@ -273,15 +274,16 @@ class GPTNeoModel:
         eps = cfg.layer_norm_epsilon
         cp = self.sequence_axis is not None
         positions, kv_positions_fn = self._cp_positions(L, attention_mask)
-        if self.tensor_axis:
-            from acco_tpu.models.layers import vocab_parallel_embed
+        with jax.named_scope("model/embed"):
+            if self.tensor_axis:
+                from acco_tpu.models.layers import vocab_parallel_embed
 
-            tok = vocab_parallel_embed(
-                params["wte"], input_ids, self.tensor_axis
-            )
-        else:
-            tok = params["wte"][input_ids]
-        x = tok + params["wpe"][positions][None, :, :]
+                tok = vocab_parallel_embed(
+                    params["wte"], input_ids, self.tensor_axis
+                )
+            else:
+                tok = params["wte"][input_ids]
+            x = tok + params["wpe"][positions][None, :, :]
 
         fused, banded_local, global_bias, local_bias = (
             (False, False, None, None)
@@ -316,9 +318,13 @@ class GPTNeoModel:
             ),
             self.remat,
         )
-        x, _ = jax.lax.scan(
-            body, x, (params["layers"], windows), unroll=self.scan_unroll
-        )
+        # the scope holds the scan itself, not only its body: stacking the
+        # layers' saved activations and slicing them back out in the
+        # backward pass is the block stack's time too
+        with jax.named_scope("model/block"):
+            x, _ = jax.lax.scan(
+                body, x, (params["layers"], windows), unroll=self.scan_unroll
+            )
         return layer_norm(x, params["lnf_scale"], params["lnf_bias"], eps)
 
     def _cp_positions(self, L, attention_mask=None):
@@ -418,95 +424,99 @@ class GPTNeoModel:
     ):
         """One GPT-Neo block as a scan body over ``(layer, window)`` —
         shared by ``hidden`` (all layers) and ``stage_blocks`` (a
-        pipeline stage's sub-stack). ``collect_kv``: stack each layer's
+        pipeline stage's sub-stack), which run the scan under the device
+        scope ``model/block``; the halves carry ``model/attn`` and
+        ``model/mlp`` here. ``collect_kv``: stack each layer's
         K/V as scan outputs ([B, L, H, D] page-row layout) — the serving
         prefill's cache tap."""
         eps = self.config.layer_norm_epsilon
 
         def block(x, scanned):
             layer, window = scanned
-            h = layer_norm(x, layer["ln1_scale"], layer["ln1_bias"], eps)
-            # [D, 3, Dh/tp] local qkv thirds, flattened to one matmul
-            w_qkv = layer["w_qkv"]
-            qkv = h @ w_qkv.reshape(w_qkv.shape[0], -1)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = split_heads(q, n_heads)
-            k = split_heads(k, n_heads)
-            v = split_heads(v, n_heads)
-            # GPT-Neo quirk: no 1/sqrt(head_dim) scaling on the scores.
-            if cp:
-                attn = windowed_ring_attention(
-                    q, k, v, self.sequence_axis, window, positions,
-                    kv_positions_fn, scale=1.0,
-                )
-            elif fused:
-                from acco_tpu.ops.banded_attention import (
-                    banded_dot_product_attention,
-                    supports_banded_attention,
-                )
-                from acco_tpu.ops.fused_attention import (
-                    fused_dot_product_attention,
-                )
+            with jax.named_scope("model/attn"):
+                h = layer_norm(x, layer["ln1_scale"], layer["ln1_bias"], eps)
+                # [D, 3, Dh/tp] local qkv thirds, flattened to one matmul
+                w_qkv = layer["w_qkv"]
+                qkv = h @ w_qkv.reshape(w_qkv.shape[0], -1)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = split_heads(q, n_heads)
+                k = split_heads(k, n_heads)
+                v = split_heads(v, n_heads)
+                # GPT-Neo quirk: no 1/sqrt(head_dim) scaling on the scores.
+                if cp:
+                    attn = windowed_ring_attention(
+                        q, k, v, self.sequence_axis, window, positions,
+                        kv_positions_fn, scale=1.0,
+                    )
+                elif fused:
+                    from acco_tpu.ops.banded_attention import (
+                        banded_dot_product_attention,
+                        supports_banded_attention,
+                    )
+                    from acco_tpu.ops.fused_attention import (
+                        fused_dot_product_attention,
+                    )
 
-                L = q.shape[2]
-                W = self.config.window_size
-                if pad_mask is None and supports_banded_attention(
-                    L, self.config.head_dim, W
-                ):
-                    # The per-layer window is traced (one scanned body
-                    # serves all layers) but takes only two values: 0
-                    # (global) and the STATIC config window. Branch at
-                    # runtime; the local branch's banded kernel computes
-                    # only the [L, W+QB] key band instead of the full
-                    # [L, L] tile it would mask ~3/4 away — the window
-                    # layers are GPT-Neo's measured MFU gap vs Llama.
+                    L = q.shape[2]
+                    W = self.config.window_size
+                    if pad_mask is None and supports_banded_attention(
+                        L, self.config.head_dim, W
+                    ):
+                        # The per-layer window is traced (one scanned body
+                        # serves all layers) but takes only two values: 0
+                        # (global) and the STATIC config window. Branch at
+                        # runtime; the local branch's banded kernel computes
+                        # only the [L, W+QB] key band instead of the full
+                        # [L, L] tile it would mask ~3/4 away — the window
+                        # layers are GPT-Neo's measured MFU gap vs Llama.
+                        attn = jax.lax.cond(
+                            window == 0,
+                            lambda q, k, v: fused_dot_product_attention(
+                                q, k, v, window=0, scale=1.0
+                            ),
+                            lambda q, k, v: banded_dot_product_attention(
+                                q, k, v, window=W, scale=1.0
+                            ),
+                            q, k, v,
+                        )
+                    else:
+                        # padding masks (finetune) keep the one-kernel path:
+                        # the traced window rides into the kernel via SMEM;
+                        # the unscaled-score quirk is preserved, scale=1.0
+                        attn = fused_dot_product_attention(
+                            q, k, v, pad_mask=pad_mask, window=window, scale=1.0
+                        )
+                elif banded_local:
+                    # einsum plan, banded local layers: global layers keep
+                    # the measured einsum path, local layers skip the
+                    # out-of-window score work entirely (L=2048 — GPT-Neo's
+                    # max context, where 'auto' doesn't pick the full-tile
+                    # kernel — computes a 5.3x-narrower band instead)
+                    from acco_tpu.ops.banded_attention import (
+                        banded_dot_product_attention,
+                    )
+
                     attn = jax.lax.cond(
                         window == 0,
-                        lambda q, k, v: fused_dot_product_attention(
-                            q, k, v, window=0, scale=1.0
+                        lambda q, k, v: dot_product_attention(
+                            q, k, v, global_bias, scale=1.0
                         ),
                         lambda q, k, v: banded_dot_product_attention(
-                            q, k, v, window=W, scale=1.0
+                            q, k, v, window=self.config.window_size, scale=1.0
                         ),
                         q, k, v,
                     )
                 else:
-                    # padding masks (finetune) keep the one-kernel path:
-                    # the traced window rides into the kernel via SMEM;
-                    # the unscaled-score quirk is preserved, scale=1.0
-                    attn = fused_dot_product_attention(
-                        q, k, v, pad_mask=pad_mask, window=window, scale=1.0
-                    )
-            elif banded_local:
-                # einsum plan, banded local layers: global layers keep
-                # the measured einsum path, local layers skip the
-                # out-of-window score work entirely (L=2048 — GPT-Neo's
-                # max context, where 'auto' doesn't pick the full-tile
-                # kernel — computes a 5.3x-narrower band instead)
-                from acco_tpu.ops.banded_attention import (
-                    banded_dot_product_attention,
+                    bias = jnp.where(window == 0, global_bias, local_bias)
+                    attn = dot_product_attention(q, k, v, bias, scale=1.0)
+                # row-split wo: psum the partial, THEN the replicated bias
+                x = x + tp_psum(merge_heads(attn) @ layer["wo"]) + layer["wo_bias"]
+            with jax.named_scope("model/mlp"):
+                h = layer_norm(x, layer["ln2_scale"], layer["ln2_bias"], eps)
+                mlp = (
+                    gelu_new(h @ layer["w_fc"] + layer["b_fc"]) @ layer["w_proj"]
                 )
-
-                attn = jax.lax.cond(
-                    window == 0,
-                    lambda q, k, v: dot_product_attention(
-                        q, k, v, global_bias, scale=1.0
-                    ),
-                    lambda q, k, v: banded_dot_product_attention(
-                        q, k, v, window=self.config.window_size, scale=1.0
-                    ),
-                    q, k, v,
-                )
-            else:
-                bias = jnp.where(window == 0, global_bias, local_bias)
-                attn = dot_product_attention(q, k, v, bias, scale=1.0)
-            # row-split wo: psum the partial, THEN the replicated bias
-            x = x + tp_psum(merge_heads(attn) @ layer["wo"]) + layer["wo_bias"]
-            h = layer_norm(x, layer["ln2_scale"], layer["ln2_bias"], eps)
-            mlp = (
-                gelu_new(h @ layer["w_fc"] + layer["b_fc"]) @ layer["w_proj"]
-            )
-            out = x + tp_psum(mlp) + layer["b_proj"]
+                out = x + tp_psum(mlp) + layer["b_proj"]
             if collect_kv:
                 return out, (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
             return out, None
@@ -553,7 +563,8 @@ class GPTNeoModel:
             local_bias=attention_mask_bias(L, cfg.window_size, None),
             collect_kv=True,
         )
-        x, (k, v) = jax.lax.scan(body, x, (params["layers"], windows))
+        with jax.named_scope("model/block"):
+            x, (k, v) = jax.lax.scan(body, x, (params["layers"], windows))
         x = layer_norm(
             x, params["lnf_scale"], params["lnf_bias"], cfg.layer_norm_epsilon
         )
@@ -745,7 +756,10 @@ class GPTNeoModel:
             ),
             self.remat,
         )
-        x, _ = jax.lax.scan(body, x, (layers, windows), unroll=self.scan_unroll)
+        with jax.named_scope("model/block"):
+            x, _ = jax.lax.scan(
+                body, x, (layers, windows), unroll=self.scan_unroll
+            )
         return x
 
     def finalize(self, params: dict, x: jax.Array) -> jax.Array:

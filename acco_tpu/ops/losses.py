@@ -425,16 +425,17 @@ def model_ce(
         )
 
         h = model.hidden(params, ids, attention_mask)
-        head = model.lm_head(params)
-        if vocab_axis is not None:
-            return vocab_parallel_fused_ce_loss(
-                h, head, labels, vocab_axis, label_smoothing,
+        with jax.named_scope("model/lm_head_ce"):
+            head = model.lm_head(params)
+            if vocab_axis is not None:
+                return vocab_parallel_fused_ce_loss(
+                    h, head, labels, vocab_axis, label_smoothing,
+                    shift=shift, num_valid=num_valid, real_vocab=real_vocab,
+                )
+            return fused_ce_loss(
+                h, head, labels, label_smoothing,
                 shift=shift, num_valid=num_valid, real_vocab=real_vocab,
             )
-        return fused_ce_loss(
-            h, head, labels, label_smoothing,
-            shift=shift, num_valid=num_valid, real_vocab=real_vocab,
-        )
     if fused == "chunk":
         # The chunk form predates sharding/CP and has no shift=False,
         # num_valid, or vocab_axis plumbing; resolve_fused_loss never
@@ -456,18 +457,19 @@ def model_ce(
                 "use 'pallas' or the materialized path for "
                 "sharded/CP/vocab-padded losses"
             )
-        return chunked_causal_lm_loss(
-            model.hidden(params, ids, attention_mask),
-            model.lm_head(params),
-            labels,
-            label_smoothing,
-        )
+        h = model.hidden(params, ids, attention_mask)
+        with jax.named_scope("model/lm_head_ce"):
+            return chunked_causal_lm_loss(
+                h, model.lm_head(params), labels, label_smoothing
+            )
+    # the model's apply() names its own output projection model/lm_head_ce
     logits = model.apply(params, ids, attention_mask)
-    return causal_lm_loss(
-        logits, labels, label_smoothing,
-        shift=shift, num_valid=num_valid, vocab_axis=vocab_axis,
-        real_vocab=real_vocab,
-    )
+    with jax.named_scope("model/lm_head_ce"):
+        return causal_lm_loss(
+            logits, labels, label_smoothing,
+            shift=shift, num_valid=num_valid, vocab_axis=vocab_axis,
+            real_vocab=real_vocab,
+        )
 
 
 def token_nll(

@@ -460,8 +460,6 @@ class AccoTrainStep:
         probe cannot itself overflow. Must be called inside the
         shard_map body (axis names bound).
         """
-        probe = jnp.sum(grad_sum * 0.0)
-        local_bad = jnp.logical_not(jnp.isfinite(probe))
         axes = (
             self.shard_axes
             if isinstance(self.shard_axes, tuple)
@@ -470,8 +468,11 @@ class AccoTrainStep:
         if self.model_axis is not None:
             ma = self.model_axis
             axes = axes + (tuple(ma) if isinstance(ma, tuple) else (ma,))
-        bad = lax.psum(local_bad.astype(jnp.float32), axes)
-        return (jnp.isfinite(loss) & (bad == 0)).astype(jnp.float32)
+        with jax.named_scope("acco/guard"):
+            probe = jnp.sum(grad_sum * 0.0)
+            local_bad = jnp.logical_not(jnp.isfinite(probe))
+            bad = lax.psum(local_bad.astype(jnp.float32), axes)
+            return (jnp.isfinite(loss) & (bad == 0)).astype(jnp.float32)
 
     def seed_fn(self):
         """Compute-only round that fills the pending buffers before round 0.
@@ -550,7 +551,8 @@ class AccoTrainStep:
         # ---- communication branch: consume pending_grads ----
         raw_total = lax.psum(state.pending_count[0], DATA_AXIS)
         total = jnp.maximum(raw_total, 1.0)
-        lr = self.schedule(state.zero1.sched_grads)
+        with jax.named_scope("acco/optimizer"):
+            lr = self.schedule(state.zero1.sched_grads)
         upd = zero1_update_shard(
             state.pending_grads,
             state.zero1.opt,
@@ -599,18 +601,31 @@ class AccoTrainStep:
         # vectors — the measured guard overhead; nan_guard=False
         # compiles them out entirely.
         if ok is not None:
-            new_flat = jnp.where(ok, new_flat, state.flat_params)
+            with jax.named_scope("acco/guard"):
+                new_flat = jnp.where(ok, new_flat, state.flat_params)
             if isinstance(commit, bool):
                 commit_ok = ok if commit else False
             else:
                 commit_ok = jnp.logical_and(commit, ok)
         else:
             commit_ok = commit
-        opt_out = jax.tree.map(
-            lambda new, old: sel(commit_ok, new, old), new_opt, state.zero1.opt
-        )
-        sched_inc = total.astype(jnp.int32) if self.lr_grad_accounting else 1
-        sched_out = state.zero1.sched_grads + sel(commit_ok, sched_inc, 0)
+        # the selects below are the guard's where ok is data, and the
+        # speculative/commit selects of a parity-generic program otherwise
+        def select_scope():
+            if ok is not None:
+                return jax.named_scope("acco/guard")
+            return jax.named_scope("acco/cast")
+
+        with select_scope():
+            opt_out = jax.tree.map(
+                lambda new, old: sel(commit_ok, new, old),
+                new_opt,
+                state.zero1.opt,
+            )
+            sched_inc = (
+                total.astype(jnp.int32) if self.lr_grad_accounting else 1
+            )
+            sched_out = state.zero1.sched_grads + sel(commit_ok, sched_inc, 0)
 
         # ---- compute branch: grads at the current working params ----
         # Carry-in (the reference's zero-only-after-even-rounds
@@ -631,10 +646,13 @@ class AccoTrainStep:
             carry = is_even if pok is None else (
                 pok if isinstance(is_even, bool) else jnp.logical_and(is_even, pok)
             )
-            grad0 = jnp.where(
-                carry, state.pending_grads, jnp.zeros_like(state.pending_grads)
-            )
-            count0 = jnp.where(carry, state.pending_count[0], 0.0)
+            with select_scope():
+                grad0 = jnp.where(
+                    carry,
+                    state.pending_grads,
+                    jnp.zeros_like(state.pending_grads),
+                )
+                count0 = jnp.where(carry, state.pending_count[0], 0.0)
         block = MicrobatchBlock(ids, am, labels, valid[:, 0])
         grad_sum, count, loss_wsum = self._accumulate(
             state.flat_params, block, grad_init=grad0, count_init=count0

@@ -161,7 +161,10 @@ def make_flat_loss_fn(
         fused_loss = False
 
     def loss_fn(flat_params: jax.Array, batch: dict) -> jax.Array:
-        params = unravel(flat_params[:n_params])
+        # its transpose (the leaves' gradients written back into the flat
+        # vector) carries the same name in the backward pass
+        with jax.named_scope("acco/flat_unpack"):
+            params = unravel(flat_params[:n_params])
         # shared dispatch (ops.losses.model_ce — also both trainer
         # eval bodies), so train/eval numerics can never diverge
         from acco_tpu.ops.losses import model_ce
@@ -234,20 +237,23 @@ def accumulate_grads(
         return (grad_sum, count), loss
 
     n_acc = block.valid.shape[0]
-    if n_acc == 1:
-        # The flagship pretrain config runs one microbatch per half-round;
-        # a length-1 lax.scan still compiles to a while loop wrapping the
-        # whole fwd/bwd (time-neutral when measured, but the while op
-        # walls the body off from the round-level latency-hiding
-        # scheduler, which matters for the ring-collective overlap).
-        # Inline it.
-        (grad_sum, count), loss = micro(
-            (grad0, count0), jax.tree.map(lambda x: x[0], block)
-        )
-        return grad_sum, count, (loss * block.valid[0])
+    with jax.named_scope("acco/accumulate"):
+        if n_acc == 1:
+            # The flagship pretrain config runs one microbatch per
+            # half-round; a length-1 lax.scan still compiles to a while
+            # loop wrapping the whole fwd/bwd (time-neutral when measured,
+            # but the while op walls the body off from the round-level
+            # latency-hiding scheduler, which matters for the
+            # ring-collective overlap). Inline it.
+            (grad_sum, count), loss = micro(
+                (grad0, count0), jax.tree.map(lambda x: x[0], block)
+            )
+            return grad_sum, count, (loss * block.valid[0])
 
-    (grad_sum, count), losses = jax.lax.scan(micro, (grad0, count0), block)
-    return grad_sum, count, (losses * block.valid).sum()
+        (grad_sum, count), losses = jax.lax.scan(
+            micro, (grad0, count0), block
+        )
+        return grad_sum, count, (losses * block.valid).sum()
 
 
 def world_mean_loss(

@@ -286,7 +286,8 @@ class DDPTrainStep:
         sched_inc = (
             total.astype(jnp.int32) if self.lr_grad_accounting else jnp.int32(1)
         )
-        lr = self.schedule(state.zero1.sched_grads)
+        with jax.named_scope("acco/optimizer"):
+            lr = self.schedule(state.zero1.sched_grads)
         upd = zero1_update_shard(
             grad_sum,
             state.zero1.opt,
@@ -323,14 +324,15 @@ class DDPTrainStep:
             new_flat, new_opt, uh = upd
             ok, grad_norm = uh.ok, uh.grad_norm
             skipped = jnp.logical_not(ok)
-            new_flat = jnp.where(ok, new_flat, state.flat_params)
-            new_opt = jax.tree.map(
-                lambda new, old: jnp.where(ok, new, old),
-                new_opt,
-                state.zero1.opt,
-            )
-            sched_inc = jnp.where(ok, sched_inc, 0)
-            committed_inc = jnp.where(ok, raw_total, 0.0)
+            with jax.named_scope("acco/guard"):
+                new_flat = jnp.where(ok, new_flat, state.flat_params)
+                new_opt = jax.tree.map(
+                    lambda new, old: jnp.where(ok, new, old),
+                    new_opt,
+                    state.zero1.opt,
+                )
+                sched_inc = jnp.where(ok, sched_inc, 0)
+                committed_inc = jnp.where(ok, raw_total, 0.0)
             health_out = HealthState(
                 skipped_rounds=state.health.skipped_rounds
                 + skipped.astype(jnp.int32),

@@ -200,111 +200,118 @@ def zero1_update_shard(
             ring_all_gather,
             ring_reduce_scatter,
         )
-
-        grad_shard = ring_reduce_scatter(
-            flat_grads_local.astype(jnp.float32), axis_name
-        )
-    else:
-        grad_shard = lax.psum_scatter(
-            flat_grads_local.astype(jnp.float32), axis_name, tiled=True
-        )
-    divisor = grad_divisor.astype(jnp.float32)
-    if tp_axis is not None:
-        tp = lax.axis_size(tp_axis)  # axis tuples: product (pp x tp)
-        divisor = divisor * tp
-    grad_shard = grad_shard / divisor
-    if tp_axis is not None and n_repl > 0:
-        # replicated-prefix positions held by this dp(x sp) shard.
-        # Single model axis: one prefix [0:n_repl) psum'd over tp_axis.
-        # Composed pp x tp (ComposedLayout): the prefix splits in two —
-        # [0:n_repl_both) is replicated on BOTH axes (final norms, psum
-        # over the full tuple), [n_repl_both:n_repl) is outer-split but
-        # inner-replicated (per-stage norm scales, psum over inner only).
-        idx = flat_shard_index(axis_name)
-        repl_mask = _boundary_mask(idx, geom.shard_size, n_repl).astype(bool)
-        if inner_axis is None or n_repl_both >= n_repl:
-            synced = lax.psum(jnp.where(repl_mask, grad_shard, 0.0), tp_axis)
-            grad_shard = jnp.where(repl_mask, synced, grad_shard)
+    # Device scopes (telemetry.trace.DEVICE_SCOPES): each phase of the
+    # update carries a name in the compiled program, staging copies
+    # included, so a profile can tell the wire from the vector passes.
+    with jax.named_scope("acco/reduce_scatter"):
+        if use_ring:
+            grad_shard = ring_reduce_scatter(
+                flat_grads_local.astype(jnp.float32), axis_name
+            )
         else:
-            both_mask = _boundary_mask(
-                idx, geom.shard_size, n_repl_both
-            ).astype(bool)
-            inner_mask = repl_mask & ~both_mask
-            synced_both = lax.psum(
-                jnp.where(both_mask, grad_shard, 0.0), tp_axis
+            grad_shard = lax.psum_scatter(
+                flat_grads_local.astype(jnp.float32), axis_name, tiled=True
             )
-            synced_inner = lax.psum(
-                jnp.where(inner_mask, grad_shard, 0.0), inner_axis
-            )
-            grad_shard = jnp.where(
-                both_mask, synced_both,
-                jnp.where(inner_mask, synced_inner, grad_shard),
-            )
-    pad_mask = geom.shard_pad_mask(flat_shard_index(axis_name))
-    new_opt = adamw_shard_update(
-        opt_shard,
-        grad_shard,
-        lr=lr,
-        weight_decay=weight_decay,
-        beta1=beta1,
-        beta2=beta2,
-        eps=eps,
-        pad_mask=pad_mask,
-    )
-    if use_ring:
-        new_flat = ring_all_gather(new_opt.params.astype(out_dtype), axis_name)
-    else:
-        new_flat = lax.all_gather(
-            new_opt.params.astype(out_dtype), axis_name, tiled=True
+    with jax.named_scope("acco/optimizer"):
+        divisor = grad_divisor.astype(jnp.float32)
+        if tp_axis is not None:
+            tp = lax.axis_size(tp_axis)  # axis tuples: product (pp x tp)
+            divisor = divisor * tp
+        grad_shard = grad_shard / divisor
+        if tp_axis is not None and n_repl > 0:
+            # replicated-prefix positions held by this dp(x sp) shard.
+            # Single model axis: one prefix [0:n_repl) psum'd over tp_axis.
+            # Composed pp x tp (ComposedLayout): the prefix splits in two —
+            # [0:n_repl_both) is replicated on BOTH axes (final norms, psum
+            # over the full tuple), [n_repl_both:n_repl) is outer-split but
+            # inner-replicated (per-stage norm scales, psum over inner only).
+            idx = flat_shard_index(axis_name)
+            repl_mask = _boundary_mask(idx, geom.shard_size, n_repl).astype(bool)
+            if inner_axis is None or n_repl_both >= n_repl:
+                synced = lax.psum(jnp.where(repl_mask, grad_shard, 0.0), tp_axis)
+                grad_shard = jnp.where(repl_mask, synced, grad_shard)
+            else:
+                both_mask = _boundary_mask(
+                    idx, geom.shard_size, n_repl_both
+                ).astype(bool)
+                inner_mask = repl_mask & ~both_mask
+                synced_both = lax.psum(
+                    jnp.where(both_mask, grad_shard, 0.0), tp_axis
+                )
+                synced_inner = lax.psum(
+                    jnp.where(inner_mask, grad_shard, 0.0), inner_axis
+                )
+                grad_shard = jnp.where(
+                    both_mask, synced_both,
+                    jnp.where(inner_mask, synced_inner, grad_shard),
+                )
+        pad_mask = geom.shard_pad_mask(flat_shard_index(axis_name))
+        new_opt = adamw_shard_update(
+            opt_shard,
+            grad_shard,
+            lr=lr,
+            weight_decay=weight_decay,
+            beta1=beta1,
+            beta2=beta2,
+            eps=eps,
+            pad_mask=pad_mask,
         )
+    with jax.named_scope("acco/cast"):
+        new_shard = new_opt.params.astype(out_dtype)
+    with jax.named_scope("acco/all_gather"):
+        if use_ring:
+            new_flat = ring_all_gather(new_shard, axis_name)
+        else:
+            new_flat = lax.all_gather(new_shard, axis_name, tiled=True)
     if not with_health:
         return new_flat, new_opt
-    # Health signals, from buffers this update already touched: the
-    # shards partition the flat vector, so psum'ing per-shard sums of
-    # squares yields the global quantities. NaN/inf propagate through
-    # square+sum+psum, so a single nonfinite element anywhere in the
-    # global gradient or updated parameters makes its total nonfinite.
-    # Pad positions are excluded with where() (a multiply would keep
-    # NaN: x*0 is NaN for nonfinite x, and the ragged tail is the one
-    # place a structural nonfinite is harmless). One [2] psum — under
-    # tp each tp group's local vector is a disjoint piece of the model
-    # EXCEPT the replicated prefix, whose squared contribution is
-    # pre-divided by its replication factor (it appears on every tp
-    # shard, mirroring the sync above: [0:n_repl_both) on the full
-    # tuple, [n_repl_both:n_repl) on inner only) so the psum counts
-    # every element exactly once and grad_norm matches the
-    # single-device value. The division keeps NaN/inf propagation
-    # intact (nonfinite/k is nonfinite).
-    real = pad_mask > 0
-    grad_ss_v = jnp.square(jnp.where(real, grad_shard, 0.0))
-    param_ss_v = jnp.square(jnp.where(real, new_opt.params, 0.0))
-    if tp_axis is not None and n_repl > 0:
-        idx = flat_shard_index(axis_name)
-        repl_mask = _boundary_mask(idx, geom.shard_size, n_repl).astype(bool)
-        tp_size = jnp.float32(lax.axis_size(tp_axis))
-        if inner_axis is None or n_repl_both >= n_repl:
-            inv_repl = jnp.where(repl_mask, 1.0 / tp_size, 1.0)
-        else:
-            both_mask = _boundary_mask(
-                idx, geom.shard_size, n_repl_both
-            ).astype(bool)
-            inner_size = jnp.float32(lax.axis_size(inner_axis))
-            inv_repl = jnp.where(
-                both_mask, 1.0 / tp_size,
-                jnp.where(repl_mask & ~both_mask, 1.0 / inner_size, 1.0),
+    with jax.named_scope("acco/guard"):
+        # Health signals, from buffers this update already touched: the
+        # shards partition the flat vector, so psum'ing per-shard sums of
+        # squares yields the global quantities. NaN/inf propagate through
+        # square+sum+psum, so a single nonfinite element anywhere in the
+        # global gradient or updated parameters makes its total nonfinite.
+        # Pad positions are excluded with where() (a multiply would keep
+        # NaN: x*0 is NaN for nonfinite x, and the ragged tail is the one
+        # place a structural nonfinite is harmless). One [2] psum — under
+        # tp each tp group's local vector is a disjoint piece of the model
+        # EXCEPT the replicated prefix, whose squared contribution is
+        # pre-divided by its replication factor (it appears on every tp
+        # shard, mirroring the sync above: [0:n_repl_both) on the full
+        # tuple, [n_repl_both:n_repl) on inner only) so the psum counts
+        # every element exactly once and grad_norm matches the
+        # single-device value. The division keeps NaN/inf propagation
+        # intact (nonfinite/k is nonfinite).
+        real = pad_mask > 0
+        grad_ss_v = jnp.square(jnp.where(real, grad_shard, 0.0))
+        param_ss_v = jnp.square(jnp.where(real, new_opt.params, 0.0))
+        if tp_axis is not None and n_repl > 0:
+            idx = flat_shard_index(axis_name)
+            repl_mask = _boundary_mask(idx, geom.shard_size, n_repl).astype(bool)
+            tp_size = jnp.float32(lax.axis_size(tp_axis))
+            if inner_axis is None or n_repl_both >= n_repl:
+                inv_repl = jnp.where(repl_mask, 1.0 / tp_size, 1.0)
+            else:
+                both_mask = _boundary_mask(
+                    idx, geom.shard_size, n_repl_both
+                ).astype(bool)
+                inner_size = jnp.float32(lax.axis_size(inner_axis))
+                inv_repl = jnp.where(
+                    both_mask, 1.0 / tp_size,
+                    jnp.where(repl_mask & ~both_mask, 1.0 / inner_size, 1.0),
+                )
+            grad_ss_v = grad_ss_v * inv_repl
+            param_ss_v = param_ss_v * inv_repl
+        grad_ss = jnp.sum(grad_ss_v)
+        param_ss = jnp.sum(param_ss_v)
+        axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
+        if tp_axis is not None:
+            axes = axes + (
+                (tp_axis,) if isinstance(tp_axis, str) else tuple(tp_axis)
             )
-        grad_ss_v = grad_ss_v * inv_repl
-        param_ss_v = param_ss_v * inv_repl
-    grad_ss = jnp.sum(grad_ss_v)
-    param_ss = jnp.sum(param_ss_v)
-    axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    if tp_axis is not None:
-        axes = axes + (
-            (tp_axis,) if isinstance(tp_axis, str) else tuple(tp_axis)
-        )
-    totals = lax.psum(jnp.stack([grad_ss, param_ss]), axes)
-    grad_norm = jnp.sqrt(totals[0])
-    ok = jnp.isfinite(totals[0]) & jnp.isfinite(totals[1])
-    if max_grad_norm and max_grad_norm > 0:
-        ok = ok & (totals[0] <= jnp.float32(max_grad_norm) ** 2)
+        totals = lax.psum(jnp.stack([grad_ss, param_ss]), axes)
+        grad_norm = jnp.sqrt(totals[0])
+        ok = jnp.isfinite(totals[0]) & jnp.isfinite(totals[1])
+        if max_grad_norm and max_grad_norm > 0:
+            ok = ok & (totals[0] <= jnp.float32(max_grad_norm) ** 2)
     return new_flat, new_opt, UpdateHealth(ok=ok, grad_norm=grad_norm)

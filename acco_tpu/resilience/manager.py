@@ -41,6 +41,7 @@ import os
 import shutil
 import threading
 import time
+from contextlib import nullcontext
 from typing import Any, Callable, Optional
 
 from acco_tpu.telemetry import metrics
@@ -179,14 +180,14 @@ class CheckpointManager:
         # Blocks for the device->host snapshot only (async Orbax); the
         # donated round-state buffers are safe to reuse once this returns.
         t_snap = time.perf_counter()
-        ckptr.save(os.path.join(path, "state"), state, force=True)
-        snap_ms = (time.perf_counter() - t_snap) * 1e3
+        with (
+            self.tracer.span("ckpt/snapshot", cat="ckpt", path=path)
+            if self.tracer is not None
+            else nullcontext()
+        ):
+            ckptr.save(os.path.join(path, "state"), state, force=True)
         metrics.emit("ckpt_saves_total", 1)
-        metrics.emit("ckpt_snapshot_ms", snap_ms)
-        if self.tracer is not None:
-            self.tracer.complete_event(
-                "ckpt/snapshot", snap_ms, cat="ckpt", args={"path": path}
-            )
+        metrics.emit("ckpt_snapshot_ms", (time.perf_counter() - t_snap) * 1e3)
         if blocking:
             self._finalize(path, meta, extra_files)
             err, self._error = self._error, None

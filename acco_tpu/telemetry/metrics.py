@@ -12,12 +12,9 @@ kind, unit, help — and emitting an undeclared name raises
 same property statically over every ``metrics.emit(...)`` call site, so
 a typo'd metric name cannot reach main.
 
-Sinks (one source of names for every consumer):
-
-* ``scalar_row()`` — flat name->number dict for ``results.csv`` and the
-  bench JSON record (histograms project to their p50);
-* ``to_tensorboard(writer, step)`` — scalar tags under ``telemetry/``;
-* ``to_prometheus_text()`` — the serve ``/metrics`` exposition.
+Readers: ``value()`` / ``scalar()`` / ``quantile()`` / ``snapshot()``
+in process, and one sink, ``to_prometheus_text()`` — the serve
+``/metrics`` exposition.
 
 Zero dependencies, zero device syncs: values are plain Python numbers,
 emission is a locked dict update. jax is never imported here.
@@ -196,27 +193,8 @@ class MetricsRegistry:
         with self._lock:
             return self._values[name].quantile(q)
 
-    def scalar_row(
-        self, names: Optional[Iterable[str]] = None
-    ) -> Dict[str, float]:
-        """Flat dict for the CSV/JSON ledgers: one number per metric
-        (histogram -> p50); never-emitted metrics are omitted so ledger
-        schemas don't fill with empty columns."""
-        row: Dict[str, float] = {}
-        for name in names if names is not None else self.declared_names():
-            s = self.scalar(name)
-            if s is not None:
-                row[name] = s
-        return row
-
     def snapshot(self) -> Dict[str, Any]:
         return {name: self.value(name) for name in self.declared_names()}
-
-    def to_tensorboard(
-        self, writer, step: int, names: Optional[Iterable[str]] = None
-    ) -> None:
-        for name, value in self.scalar_row(names).items():
-            writer.add_scalar(f"telemetry/{name}", value, step)
 
     def to_prometheus_text(self, prefix: str = "acco_") -> str:
         """Prometheus text exposition format (version 0.0.4)."""
@@ -257,12 +235,6 @@ DECLARED: Tuple[MetricSpec, ...] = (
     # -- trainer round loop (acco_tpu/trainer.py) --
     _spec("train_rounds_total", COUNTER, "rounds",
           "round programs dispatched this process"),
-    _spec("train_round_wall_ms", HISTOGRAM, "ms",
-          "wall time between round dispatches (steady-state round time)"),
-    _spec("train_dispatch_ms", HISTOGRAM, "ms",
-          "host time to enqueue one round program (async dispatch)"),
-    _spec("train_loader_wait_ms", HISTOGRAM, "ms",
-          "train loop blocked on the prefetch queue per block"),
     _spec("train_log_sync_ms", HISTOGRAM, "ms",
           "the logging-boundary device_get (the one per-cadence sync)"),
     _spec("train_eval_ms", HISTOGRAM, "ms", "evaluate() wall per call"),
@@ -273,23 +245,6 @@ DECLARED: Tuple[MetricSpec, ...] = (
           "last boundary's global gradient norm"),
     _spec("train_grads_committed", GAUGE, "grads",
           "device-side committed-gradient counter at the last boundary"),
-    _spec("train_measured_round_ms", GAUGE, "ms",
-          "measured mean round wall time over the attribution windows"),
-    # -- step attribution (telemetry/attribution.py) --
-    _spec("attrib_loader_ms", GAUGE, "ms",
-          "per-round input-pipeline stall bucket"),
-    _spec("attrib_ckpt_ms", GAUGE, "ms",
-          "per-round checkpoint snapshot stall bucket"),
-    _spec("attrib_host_stall_ms", GAUGE, "ms",
-          "per-round other host stall bucket (log sync, eval)"),
-    _spec("attrib_compute_ms", GAUGE, "ms",
-          "per-round device compute (incl. hidden comm) bucket"),
-    _spec("attrib_exposed_comm_ms", GAUGE, "ms",
-          "per-round exposed (unoverlapped) communication bucket"),
-    _spec("measured_overlap_pct", GAUGE, "pct",
-          "measured fraction of comm hidden behind compute"),
-    _spec("overlap_divergence_pct", GAUGE, "pct",
-          "|measured - analytic| comm-hidden percentage points"),
     # -- checkpointing (resilience/manager.py; bench phase keys) --
     _spec("ckpt_saves_total", COUNTER, "saves", "checkpoints started"),
     _spec("ckpt_snapshot_ms", HISTOGRAM, "ms",

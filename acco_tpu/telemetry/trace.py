@@ -15,6 +15,14 @@ Design constraints, all load-bearing:
   never touches a jax array (it does not even import jax), so
   ``telemetry.enabled=false`` vs ``true`` differ by list appends only,
   and the host-lint sync gate proves the module adds no device fetch.
+* **the profiler's clock, by injection** — a caller may hand the tracer
+  an annotation factory (the trainer passes
+  ``jax.profiler.TraceAnnotation``); :meth:`Tracer.span` enters it around
+  the block, so while a ``jax.profiler`` trace is being captured every
+  span also lands on the profile's ``/host:CPU`` plane under the same
+  name, beside the device's ops and on their clock. No offset between
+  the two clocks is estimated anywhere. With no capture running an
+  annotation costs an atomic load.
 * **closed-world span names** — like the metrics registry (and the
   sharding rule engine before it), a span name must be declared in
   :data:`SPAN_NAMES` or recording raises. Free-form names would rot the
@@ -37,8 +45,8 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, List, Optional
+from contextlib import contextmanager, nullcontext
+from typing import Any, Callable, ContextManager, Dict, Iterator, List, Optional
 
 # The closed world of span/event names (runtime check here; static check
 # in analysis/metrics_gate.py). Categories group tracks in the viewer.
@@ -48,6 +56,7 @@ SPAN_NAMES = frozenset(
         "train/dispatch",        # host time to enqueue one round program
         "train/round",           # wall between dispatches (device window)
         "train/log_boundary_sync",  # the existing device_get at the cadence
+        "train/log_boundary_host",  # fence's return -> back for the next block
         "train/eval",            # evaluate() host+device wall
         "ckpt/snapshot",         # blocking device->host part of save()
         "ckpt/commit",           # background finalize (its own thread)
@@ -57,6 +66,29 @@ SPAN_NAMES = frozenset(
         "serve/request",         # submit -> finish of one GenRequest
     }
 )
+
+# The closed world of device scopes: every ``jax.named_scope`` literal in
+# acco_tpu/ is one of these (static check in analysis/metrics_gate.py).
+# A scope is metadata of the HLO (the instruction's ``op_name``), so the
+# compiled program is the same with and without it; the benchmark's
+# per-layer device metrics select ops by these names. Nested where the
+# code nests: model/* lies inside acco/accumulate, model/attn and
+# model/mlp inside model/block.
+DEVICE_SCOPES = (
+    "acco/accumulate",      # microbatch loop: forward + backward
+    "acco/flat_unpack",     # flat vector -> parameter leaves (and its transpose)
+    "acco/reduce_scatter",  # gradient reduce-scatter, ring or stock, staging included
+    "acco/all_gather",      # parameter all-gather, ring or stock, staging included
+    "acco/optimizer",       # AdamW on the shard + the learning-rate schedule
+    "acco/guard",           # grad norm, finiteness, the ok-selects, staged verdict
+    "acco/cast",            # f32 master -> working dtype, speculative/commit selects
+    "model/embed",          # token + position embedding
+    "model/block",          # one transformer block (attention + MLP inside)
+    "model/attn",           # qkv, attention, output projection
+    "model/mlp",            # the block's feed-forward half
+    "model/lm_head_ce",     # output projection + cross-entropy
+)
+
 
 # Categories whose event names are NOT closed-world (unbounded by
 # construction — e.g. pytest nodeids from the conftest recorder).
@@ -81,8 +113,10 @@ class Tracer:
         *,
         process_name: str = "acco",
         max_events: int = 200_000,
+        annotate: Optional[Callable[[str], ContextManager]] = None,
     ) -> None:
         self.enabled = bool(enabled)
+        self._annotate = annotate
         self.process_name = process_name
         self.max_events = int(max_events)
         self.dropped = 0
@@ -101,6 +135,10 @@ class Tracer:
     # -- recording -----------------------------------------------------------
 
     def _tid(self) -> int:
+        """Small stable id of the calling thread; a thread's first event
+        brings a thread-name metadata event with it. ``_append`` calls
+        this under the lock with room for one event, and counts again
+        afterwards."""
         ident = threading.get_ident()
         tid = self._tids.get(ident)
         if tid is None:
@@ -130,20 +168,29 @@ class Tracer:
             event.setdefault("pid", self._pid)
             if "tid" not in event:
                 event["tid"] = self._tid()
+                if len(self._events) >= self.max_events:
+                    self.dropped += 1  # the thread's name took the last slot
+                    return
             self._events.append(event)
 
     @contextmanager
     def span(
         self, name: str, cat: str = "host", **args: Any
-    ) -> Iterator[None]:
-        """Record the enclosed block as one complete event."""
+    ) -> Iterator[Dict[str, Any]]:
+        """Record the enclosed block as one complete event. Yields the
+        event's ``args``: what the block learns (a loss, a count) it may
+        put there before it exits."""
         if not self.enabled:
-            yield
+            yield args
             return
         self._check_name(name, cat)
+        annotation = (
+            self._annotate(name) if self._annotate is not None else nullcontext()
+        )
         ts = self.now_us()
         try:
-            yield
+            with annotation:
+                yield args
         finally:
             self._append(
                 {

@@ -27,6 +27,7 @@ ledger row at the end of training (`/root/reference/utils/logs_utils.py`).
 
 from __future__ import annotations
 
+import json
 import logging
 import os
 import time
@@ -57,13 +58,7 @@ from acco_tpu.resilience import (
     ShutdownHandler,
     TrainingHealthMonitor,
 )
-from acco_tpu.telemetry import (
-    StepAttribution,
-    Tracer,
-    attribution_report,
-    load_estimate_row,
-    metrics,
-)
+from acco_tpu.telemetry import DEVICE_SCOPES, Tracer, metrics, scope_table
 from acco_tpu.utils import logs as logs_utils
 from acco_tpu.utils.checkpoint import latest_checkpoint, restore_checkpoint
 
@@ -431,10 +426,12 @@ class DecoupledTrainer:
 
             # Observability (rank 0 writes, like the reference's rank gating).
             # Telemetry (acco_tpu/telemetry): span tracer + the global
-            # closed-world metrics registry + per-round step attribution.
-            # Host clocks only — enabled or disabled, telemetry adds ZERO
-            # host-device syncs (the module never imports jax; the
-            # host-lint sync gate holds it to that).
+            # closed-world metrics registry. Host clocks only — enabled
+            # or disabled, telemetry adds ZERO host-device syncs (the
+            # module never imports jax; the host-lint sync gate holds it
+            # to that). The annotation it is handed puts every span on
+            # the profiler's host plane too while train.profile_steps
+            # captures a trace: host spans and device ops on one clock.
             tel = _arg(args, "telemetry", None) or {}
             _tel = tel.get if hasattr(tel, "get") else (
                 lambda k, d=None: getattr(tel, k, d)
@@ -444,15 +441,11 @@ class DecoupledTrainer:
                 enabled=self.telemetry_enabled and self.rank == 0,
                 process_name=f"acco-{self.method}",
                 max_events=int(_tel("max_trace_events", 200_000)),
+                annotate=jax.profiler.TraceAnnotation,
             )
             self.trace_path = os.path.join(
                 self.run_dir, f"trace_{self.id_run}.json"
             )
-            self.overlap_divergence_pct = float(
-                _tel("overlap_divergence_pct", 25.0)
-            )
-            self._attribution = None  # created per train() call
-            self._attribution_report = None
             run_name = str(_arg(args, "run_name", self.method))
             self.writer = (
                 logs_utils.make_summary_writer(
@@ -963,11 +956,8 @@ class DecoupledTrainer:
     def _train(self) -> dict:
         t_beg = time.time()
         # Telemetry for this run: the span tracer (rank-0, Perfetto
-        # trace.json at the end) and a fresh per-round attribution
-        # accumulator whose windows close at the logging boundaries.
+        # trace.json at the end).
         tracer = self.tracer
-        attrib = StepAttribution()
-        self._attribution = attrib
         # Reuse the warmup's step object: its memoized round programs are
         # the ones the background threads compiled.
         step = (
@@ -997,11 +987,10 @@ class DecoupledTrainer:
         # past this line every program this run dispatches holds its
         # compiled executable, installed for direct AOT dispatch.
         t_wj = time.perf_counter()
-        self.join_warmup()
-        warmup_join_ms = (time.perf_counter() - t_wj) * 1e3
-        metrics.emit("train_warmup_join_ms", warmup_join_ms)
-        tracer.complete_event(
-            "compile/warmup_join", warmup_join_ms, cat="compile"
+        with tracer.span("compile/warmup_join", cat="compile"):
+            self.join_warmup()
+        metrics.emit(
+            "train_warmup_join_ms", (time.perf_counter() - t_wj) * 1e3
         )
 
         # Resume (framework improvement over the reference's save-only).
@@ -1113,17 +1102,15 @@ class DecoupledTrainer:
             # Parity-specialized round programs: the host knows the round
             # parity, so the speculative-rollback/zeroing selects over the
             # full flat vectors constant-fold out of each program.
-            round_fn_by_parity = {
-                True: step.program_callable("round_even", log=self.log),
-                False: step.program_callable("round_odd", log=self.log),
-            }
-            round_fn = None
+            round_programs = ("round_even", "round_odd")
         elif self.method == "dpu":
-            round_fn = step.program_callable("round", log=self.log)
-            round_fn_by_parity = None
+            round_programs = ("round",)
         else:
-            round_fn = step.program_callable("step", log=self.log)
-            round_fn_by_parity = None
+            round_programs = ("step",)
+        round_fns = {
+            name: step.program_callable(name, log=self.log)
+            for name in round_programs
+        }
 
         # Count bookkeeping: DDP/DPU commit one round's valid grads per
         # round; ACCO commits two half-rounds every odd round
@@ -1177,11 +1164,17 @@ class DecoupledTrainer:
         profile_after = 2 if self.method == "acco" else 1
         profile_dir = os.path.join(self.run_dir, "profile")
         profiling = False
+        profiled_rounds = None  # [first, last] round inside the capture
+        profiled_programs: list[str] = []  # the program of each such round
+        scope_table_path = (
+            self._write_scope_table(step, round_programs)
+            if profile_steps
+            else None
+        )
         t_last_round = time.time()
         round_wall_ms: list[float] = []
         rounds_this_run = 0  # run-local: resume restores rounds_done > 0
         interrupted = False
-        window_mark = 0  # round_wall_ms index of the open attribution window
         last_round_end_us = None  # tracer-clock end of the previous round
         # Construction to first dispatch: tokenisation, state init, the
         # compile warmup's join, the resume restore.
@@ -1220,24 +1213,28 @@ class DecoupledTrainer:
                 jax.block_until_ready(state)  # compile round fully done
                 jax.profiler.start_trace(profile_dir)
                 profiling = True
-            fn = (
-                round_fn_by_parity[round_idx_host % 2 == 0]
-                if round_fn_by_parity is not None
-                else round_fn
-            )
+                profiled_rounds = [rounds_done + 1, rounds_done + 1]
+            program_name = round_programs[round_idx_host % len(round_programs)]
+            fn = round_fns[program_name]
+            # The loop's host time is tiled by spans: loader/next_block,
+            # train/dispatch and, at a boundary, train/log_boundary_sync
+            # then train/log_boundary_host — so every idle gap of the
+            # device in a captured profile lies under a name.
             ts_round = tracer.now_us()
-            block = source.next_block()
-            ts_fetch = tracer.now_us()
-            if injector is not None and injector.pending:
-                # Chaos drill (fault_injection: in the config): poison
-                # the inputs/carried state between dispatches — the
-                # compiled programs are untouched, so the guard sees
-                # exactly what a real anomaly would produce.
-                state, block = injector.apply(rounds_this_run, state, block)
-            state, last_metrics = fn(state, block)
+            with tracer.span("loader/next_block", cat="train"):
+                block = source.next_block()
+            with tracer.span("train/dispatch", cat="train"):
+                if injector is not None and injector.pending:
+                    # Chaos drill (fault_injection: in the config): poison
+                    # the inputs/carried state between dispatches — the
+                    # compiled programs are untouched, so the guard sees
+                    # exactly what a real anomaly would produce.
+                    state, block = injector.apply(
+                        rounds_this_run, state, block
+                    )
+                state, last_metrics = fn(state, block)
             if first_metrics is None:
                 first_metrics = last_metrics
-            dispatch_ms = (tracer.now_us() - ts_fetch) / 1e3
             rounds_done += 1
             rounds_this_run += 1
             nb_com += 1
@@ -1249,14 +1246,7 @@ class DecoupledTrainer:
             wall_ms = (now - t_last_round) * 1e3
             round_wall_ms.append(wall_ms)
             t_last_round = now
-            # Per-round telemetry: host clocks captured above around work
-            # the loop already does — no device read is added anywhere.
-            attrib.note("loader", source.last_wait_ms)
-            attrib.note("host_stall", dispatch_ms)
             metrics.emit("train_rounds_total", 1)
-            metrics.emit("train_round_wall_ms", wall_ms)
-            metrics.emit("train_dispatch_ms", dispatch_ms)
-            metrics.emit("train_loader_wait_ms", source.last_wait_ms)
             if tracer.enabled:
                 end_us = tracer.now_us()
                 # the round span tiles the tracer clock edge-to-edge
@@ -1272,15 +1262,10 @@ class DecoupledTrainer:
                     cat="train", ts_us=start_us,
                     args={"round": rounds_done},
                 )
-                tracer.complete_event(
-                    "loader/next_block", (ts_fetch - ts_round) / 1e3,
-                    cat="train", ts_us=ts_round,
-                )
-                tracer.complete_event(
-                    "train/dispatch", dispatch_ms, cat="train",
-                    ts_us=ts_fetch,
-                )
                 last_round_end_us = end_us
+            if profiling:
+                profiled_rounds[1] = rounds_done
+                profiled_programs.append(program_name)
             if profiling and rounds_this_run >= profile_after + profile_steps:
                 jax.block_until_ready(state)
                 jax.profiler.stop_trace()
@@ -1299,118 +1284,125 @@ class DecoupledTrainer:
                 # Reconcile against the device-side committed-grad counter
                 # (exact under heterogeneous masks) — one lazy read at the
                 # logging cadence; dispatch stays async between boundaries.
-                # The watchdog's health counters ride the SAME fetch: the
-                # monitor adds no new blocking device read anywhere.
-                t_sync = time.perf_counter()
-                committed, health_host = jax.device_get(  # lint: host-sync-ok
-                    (state.zero1.grads_committed, state.health)
-                )
-                sync_ms = (time.perf_counter() - t_sync) * 1e3
-                metrics.emit("train_log_sync_ms", sync_ms)
-                tracer.complete_event(
-                    "train/log_boundary_sync", sync_ms, cat="train"
-                )
-                attrib.note("host_stall", sync_ms)
-                # That device_get is the sync fence: wall time since the
-                # last boundary is an honest device-inclusive measurement
-                # — close the attribution window on it.
-                n_since = len(round_wall_ms) - window_mark
-                if n_since > 0:
-                    attrib.boundary(
-                        n_since, sum(round_wall_ms[window_mark:])
+                # The watchdog's health counters, the loss and the grad
+                # norm ride the SAME fetch: one fence, no other blocking
+                # device read at the boundary, and everything the boundary
+                # learns is on its span.
+                with tracer.span(
+                    "train/log_boundary_sync", cat="train", round=rounds_done
+                ) as fence:
+                    t_sync = time.perf_counter()
+                    (
+                        committed, health_host, loss_host, grad_norm_host
+                    ) = jax.device_get(  # lint: host-sync-ok
+                        (
+                            state.zero1.grads_committed,
+                            state.health,
+                            last_metrics.loss,
+                            last_metrics.grad_norm,
+                        )
                     )
-                    window_mark = len(round_wall_ms)
-                count_grad_tot = float(committed)
-                final_loss = float(last_metrics.loss)
-                metrics.emit("train_loss", final_loss)
-                metrics.emit("train_grads_committed", float(committed))
-                log_epoch, t_last_epoch = logs_utils.print_training_evolution(
-                    self.log,
-                    nb_grad_local,
-                    nb_com,
-                    self.delta_step_for_log,
-                    self.rank,
-                    t_beg,
-                    t_last_epoch,
-                    final_loss,
-                    log_epoch,
-                )
-                logs_utils.log_to_tensorboard(
-                    self.writer,
-                    nb_step=int(count_grad_tot),
-                    nb_samples=int(count_grad_tot) * self.batch_size,
-                    rank=self.rank,
-                    loss=final_loss,
-                    eval_loss=None,
-                    t0=t_beg,
-                    delta_step_for_log=1,
-                    epoch=-1,
-                )
-                if self.nan_guard:
-                    self._last_consec_skipped = int(health_host.consec_skipped)
-                    metrics.emit(
-                        "train_grad_norm", float(last_metrics.grad_norm)
-                    )
-                    verdict = self._health_monitor.observe(
-                        grad_norm=float(last_metrics.grad_norm),
+                    sync_ms = (time.perf_counter() - t_sync) * 1e3
+                    final_loss = float(loss_host)
+                    grad_norm = float(grad_norm_host)
+                    fence.update(
                         loss=final_loss,
+                        grad_norm=grad_norm,
+                        committed=float(committed),
                         skipped_rounds=int(health_host.skipped_rounds),
-                        consec_skipped=int(health_host.consec_skipped),
                     )
-                    logs_utils.log_health_to_tensorboard(
+                # From the fence's return to the point where the loop goes
+                # back for the next block: the host work of the boundary.
+                with tracer.span("train/log_boundary_host", cat="train"):
+                    metrics.emit("train_log_sync_ms", sync_ms)
+                    count_grad_tot = float(committed)
+                    metrics.emit("train_loss", final_loss)
+                    metrics.emit("train_grads_committed", float(committed))
+                    log_epoch, t_last_epoch = logs_utils.print_training_evolution(
+                        self.log,
+                        nb_grad_local,
+                        nb_com,
+                        self.delta_step_for_log,
+                        self.rank,
+                        t_beg,
+                        t_last_epoch,
+                        final_loss,
+                        log_epoch,
+                    )
+                    logs_utils.log_to_tensorboard(
                         self.writer,
                         nb_step=int(count_grad_tot),
-                        grad_norm=float(last_metrics.grad_norm),
-                        skipped_rounds=int(health_host.skipped_rounds),
-                        consec_skipped=int(health_host.consec_skipped),
-                        rollbacks=self._rollbacks,
+                        nb_samples=int(count_grad_tot) * self.batch_size,
+                        rank=self.rank,
+                        loss=final_loss,
+                        eval_loss=None,
+                        t0=t_beg,
+                        delta_step_for_log=1,
+                        epoch=-1,
                     )
-                    if verdict.escalate:
-                        if not self.rollback_enabled:
-                            # Abort rather than continue: every round is
-                            # guard-skipped, and each boundary reconciles
-                            # count_grad_tot back to the frozen device
-                            # counter — the loop's exit condition can
-                            # never be met, so "keep going" means
-                            # spinning on no-op rounds forever.
-                            raise RuntimeError(
-                                f"watchdog: "
-                                f"{int(health_host.consec_skipped)} "
-                                "consecutive anomalous rounds and "
-                                "rollback=False — aborting (the guard "
-                                "froze params/optimizer at the last "
-                                "healthy commit; checkpoints on disk "
-                                "are unchanged)"
-                            )
-                        else:
-                            state, source, rb_meta = self._rollback(
-                                state, source
-                            )
-                            count_grad_tot = float(rb_meta["count_grad_tot"])
-                            rounds_done = int(rb_meta["rounds_done"])
-                            eval_mark = count_grad_tot
-                            if self.method in ("acco", "dpu"):
-                                round_idx_host = int(
-                                    jax.device_get(state.round_idx)  # lint: host-sync-ok
+                    if self.nan_guard:
+                        self._last_consec_skipped = int(health_host.consec_skipped)
+                        metrics.emit("train_grad_norm", grad_norm)
+                        verdict = self._health_monitor.observe(
+                            grad_norm=grad_norm,
+                            loss=final_loss,
+                            skipped_rounds=int(health_host.skipped_rounds),
+                            consec_skipped=int(health_host.consec_skipped),
+                        )
+                        logs_utils.log_health_to_tensorboard(
+                            self.writer,
+                            nb_step=int(count_grad_tot),
+                            grad_norm=grad_norm,
+                            skipped_rounds=int(health_host.skipped_rounds),
+                            consec_skipped=int(health_host.consec_skipped),
+                            rollbacks=self._rollbacks,
+                        )
+                        if verdict.escalate:
+                            if not self.rollback_enabled:
+                                # Abort rather than continue: every round is
+                                # guard-skipped, and each boundary reconciles
+                                # count_grad_tot back to the frozen device
+                                # counter — the loop's exit condition can
+                                # never be met, so "keep going" means
+                                # spinning on no-op rounds forever.
+                                raise RuntimeError(
+                                    f"watchdog: "
+                                    f"{int(health_host.consec_skipped)} "
+                                    "consecutive anomalous rounds and "
+                                    "rollback=False — aborting (the guard "
+                                    "froze params/optimizer at the last "
+                                    "healthy commit; checkpoints on disk "
+                                    "are unchanged)"
                                 )
-                            # re-anchor the log cadence to the restored
-                            # round count — otherwise health checks pause
-                            # until the run re-passes the old boundary
-                            log_epoch = (
-                                rounds_done * self.n_acc
-                            ) // self.delta_step_for_log
-                            continue
+                            else:
+                                state, source, rb_meta = self._rollback(
+                                    state, source
+                                )
+                                count_grad_tot = float(rb_meta["count_grad_tot"])
+                                rounds_done = int(rb_meta["rounds_done"])
+                                eval_mark = count_grad_tot
+                                if self.method in ("acco", "dpu"):
+                                    round_idx_host = int(
+                                        jax.device_get(state.round_idx)  # lint: host-sync-ok
+                                    )
+                                # re-anchor the log cadence to the restored
+                                # round count — otherwise health checks pause
+                                # until the run re-passes the old boundary
+                                log_epoch = (
+                                    rounds_done * self.n_acc
+                                ) // self.delta_step_for_log
+                                continue
 
             # Eval cadence is grad-count based, independent of log cadence
             # (reference: every eval_step grads, trainer_decoupled.py:525-531).
             if do_eval and eval_every and count_grad_tot - eval_mark >= eval_every:
                 eval_mark = count_grad_tot
                 t_ev = time.perf_counter()
-                eval_loss = self.evaluate(state.flat_params)
-                eval_ms = (time.perf_counter() - t_ev) * 1e3
-                metrics.emit("train_eval_ms", eval_ms)
-                tracer.complete_event("train/eval", eval_ms, cat="train")
-                attrib.note("host_stall", eval_ms)
+                with tracer.span("train/eval", cat="train"):
+                    eval_loss = self.evaluate(state.flat_params)
+                metrics.emit(
+                    "train_eval_ms", (time.perf_counter() - t_ev) * 1e3
+                )
                 final_loss = float(last_metrics.loss)
                 self.log.info(
                     "eval loss %.4f at %d grads", eval_loss, int(count_grad_tot)
@@ -1483,7 +1475,6 @@ class DecoupledTrainer:
         if profiling:  # nb_grad_tot reached before profile_steps rounds
             jax.block_until_ready(state)
             jax.profiler.stop_trace()
-        t_final_sync = time.perf_counter()
         health_final = (
             jax.device_get(state.health) if self.nan_guard else None
         )
@@ -1491,17 +1482,6 @@ class DecoupledTrainer:
             final_loss = float(last_metrics.loss)
             # Authoritative final count from the device-side counter.
             count_grad_tot = float(jax.device_get(state.zero1.grads_committed))
-        if health_final is not None or last_metrics is not None:
-            # That end-of-run fetch is the final sync fence — close the
-            # attribution window it drained (short runs may never cross
-            # a logging boundary, so this is their only window).
-            attrib.note(
-                "host_stall", (time.perf_counter() - t_final_sync) * 1e3
-            )
-            n_since = len(round_wall_ms) - window_mark
-            if n_since > 0:
-                attrib.boundary(n_since, sum(round_wall_ms[window_mark:]))
-                window_mark = len(round_wall_ms)
         total_time = time.time() - t_beg
         if do_save:
             if (
@@ -1557,54 +1537,6 @@ class DecoupledTrainer:
         if health_final is not None:
             health_row["skipped_rounds"] = int(health_final.skipped_rounds)
         health_row["rollbacks"] = self._rollbacks
-        # Step-attribution referee (ROADMAP item 3): the measured
-        # per-round decomposition, compared against step_estimate's
-        # analytic ESTIMATES.json prediction for this device count —
-        # attribution_report warns loudly when they diverge.
-        report = attribution_report(
-            attrib.summary(),
-            load_estimate_row(self.world_size),
-            divergence_pct=self.overlap_divergence_pct,
-            log=self.log,
-        )
-        self._attribution_report = report
-        if report is not None:
-            b = report["buckets_ms"]
-            metrics.emit_many({
-                "train_measured_round_ms": report["round_wall_ms"],
-                "attrib_loader_ms": b["loader_ms"],
-                "attrib_ckpt_ms": b["ckpt_ms"],
-                "attrib_host_stall_ms": b["host_stall_ms"],
-                "attrib_compute_ms": b["compute_ms"],
-                "attrib_exposed_comm_ms": b["exposed_comm_ms"],
-            })
-            self.log.info(
-                "step attribution over %d rounds (%d windows): round wall "
-                "%.2f ms = loader %.2f + ckpt %.2f + host %.2f + compute "
-                "%.2f + exposed comm %.2f (clamped %.2f ms)",
-                report["rounds"], report["windows"],
-                report["round_wall_ms"], b["loader_ms"], b["ckpt_ms"],
-                b["host_stall_ms"], b["compute_ms"], b["exposed_comm_ms"],
-                report["clamped_ms"],
-            )
-            if "measured_overlap_pct" in report:
-                metrics.emit(
-                    "measured_overlap_pct", report["measured_overlap_pct"]
-                )
-                metrics.emit(
-                    "overlap_divergence_pct",
-                    report["overlap_divergence_pct"],
-                )
-                # measured lane beside the analytic one in results.csv
-                health_row["measured_overlap_pct"] = report[
-                    "measured_overlap_pct"
-                ]
-                health_row["analytic_overlap_pct"] = report[
-                    "analytic_overlap_pct"
-                ]
-                health_row["overlap_divergence_pct"] = report[
-                    "overlap_divergence_pct"
-                ]
         if self.rank == 0:
             self._write_results(final_loss, total_time, extra=health_row)
             # Lists pair 1:1 per round executed IN THIS RUN (a resumed
@@ -1621,10 +1553,23 @@ class DecoupledTrainer:
                 tracer.write(
                     self.trace_path,
                     other_data={
-                        "attribution": report,
                         "method": self.method,
                         "world_size": self.world_size,
                         "id_run": self.id_run,
+                        # where a reader finds the device's side of the
+                        # same clock: the jax.profiler capture (None
+                        # without train.profile_steps), the rounds inside
+                        # it, and the scope names its ops carry
+                        "profile_dir": (
+                            profile_dir if profiled_rounds else None
+                        ),
+                        "profiled_rounds": profiled_rounds,
+                        "device_scopes": list(DEVICE_SCOPES),
+                        # the capture names an op by its instruction, not
+                        # by its scope: {program: {instruction: scope}},
+                        # and the program each captured round ran
+                        "scope_table": scope_table_path,
+                        "profiled_programs": profiled_programs,
                     },
                 )
                 self.log.info("telemetry trace -> %s", self.trace_path)
@@ -1659,9 +1604,6 @@ class DecoupledTrainer:
                 else 0
             ),
             "rollbacks": self._rollbacks,
-            # Measured per-round decomposition + overlap verdict (None
-            # when no attribution window closed — very short runs).
-            "attribution": report,
         }
 
     # -- eval ---------------------------------------------------------------
@@ -2100,7 +2042,6 @@ class DecoupledTrainer:
         t_beg: float,
         export_npz: bool = True,
     ):
-        t_save = time.perf_counter()
         count_grad_tot = int(count_grad_tot)
         meta = {
             "count_grad_tot": count_grad_tot,
@@ -2151,12 +2092,29 @@ class DecoupledTrainer:
                 path,
                 " (committing async)" if self.ckpt_manager.in_flight else "",
             )
-        if self._attribution is not None:
-            # the whole blocking extent (npz gather + Orbax snapshot, or
-            # the full commit when sync) is round-loop stall
-            self._attribution.note(
-                "ckpt", (time.perf_counter() - t_save) * 1e3
-            )
+
+    def _write_scope_table(self, step, programs) -> Optional[str]:
+        """``<run_dir>/device_scopes.json``: for each of the round
+        ``programs`` that is installed, ``{instruction name: device
+        scope}`` and the fusions that mix scopes, from the compiled
+        program's own text (telemetry.trace.scope_table). Written when a
+        profile will be captured: the TPU's profile names an op by its
+        instruction, and this is what says which scope (DEVICE_SCOPES)
+        the instruction lies in. None where no AOT program is installed
+        (no warmup), or on ranks that capture nothing."""
+        if self.rank != 0:
+            return None
+        tables = {
+            name: scope_table(step.compiled_programs[name].as_text())
+            for name in programs
+            if name in step.compiled_programs
+        }
+        if not tables:
+            return None
+        path = os.path.join(self.run_dir, "device_scopes.json")
+        with open(path, "w", encoding="utf-8") as f:
+            json.dump(tables, f)
+        return path
 
     def _export_flat_host(self, state) -> Optional[np.ndarray]:
         """Dense float32 param vector on host for the portable params.npz

@@ -516,11 +516,7 @@ def main() -> None:
     # REGISTRY.scalar, so a phase key the registry does not declare can
     # never reach BENCH_*.json — the same one-surface rule the trainer's
     # results.csv columns follow.
-    from acco_tpu.telemetry import (
-        load_estimate_row,
-        metrics,
-        split_device_residual,
-    )
+    from acco_tpu.telemetry import metrics
 
     if ckpt_sync_ms is not None:
         metrics.emit("ckpt_sync_stall_ms", ckpt_sync_ms)
@@ -528,18 +524,6 @@ def main() -> None:
         metrics.emit("ckpt_async_stall_ms", ckpt_async_ms)
     if guard_overhead_pct is not None:
         metrics.emit("guard_overhead_pct", guard_overhead_pct)
-    # Measured overlap efficiency beside the analytic estimate: split
-    # the measured (device-synced) round wall against the ESTIMATES.json
-    # row for this device count — None when no row matches (arbitrary
-    # meshes) or comm is zero.
-    _overlap_base_dt = acco_synced_dt if acco_synced_dt is not None else acco_dt
-    _split = split_device_residual(
-        _overlap_base_dt * 1e3, load_estimate_row(n_chips)
-    )
-    measured_overlap_pct = _split.get("measured_overlap_pct")
-    if measured_overlap_pct is not None:
-        measured_overlap_pct = round(measured_overlap_pct, 2)
-        metrics.emit("measured_overlap_pct", measured_overlap_pct)
     _reg = metrics.REGISTRY.scalar
 
     record = {
@@ -628,9 +612,6 @@ def main() -> None:
         "skipped_rounds": skipped_rounds,
         "chaos": chaos,
         "chaos_skipped_rounds": chaos_skipped,
-        # measured comm-hidden fraction (telemetry.split_device_residual
-        # over the synced round wall) beside the analytic est_* fields
-        "measured_overlap_pct": measured_overlap_pct,
         # AOT scheduled-HLO multi-chip estimate (tools/step_estimate.py /
         # ESTIMATES.md): the closest honest approximation of the
         # reference's multi-worker wall-clock claim one chip allows.
@@ -686,7 +667,6 @@ def main() -> None:
                 "compile_warm_ms": record["compile_warm_ms"],
                 "compile_cache_hits": record["compile_cache_hits"],
                 "guard_overhead_pct": record["guard_overhead_pct"],
-                "measured_overlap_pct": record["measured_overlap_pct"],
                 "skipped_rounds": record["skipped_rounds"],
                 "seq": seq,
                 "per_chip_batch": per_chip_bs,

@@ -104,6 +104,7 @@ def compile_cache_dir(tmp_path, monkeypatch):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_enable = jax.config.jax_enable_compilation_cache
+    prev_metadata = jax.config.jax_compilation_cache_include_metadata_in_key
     yield str(tmp_path / "compile-cache")
     # a trainer that was constructed but never train()ed leaves its
     # warmup threads compiling in the background; drain them so their
@@ -112,6 +113,9 @@ def compile_cache_dir(tmp_path, monkeypatch):
     drain_abandoned_compiles()
     jax.config.update("jax_compilation_cache_dir", prev_dir)
     jax.config.update("jax_enable_compilation_cache", prev_enable)
+    jax.config.update(
+        "jax_compilation_cache_include_metadata_in_key", prev_metadata
+    )
     cc.reset_cache()
 
 
@@ -352,3 +356,33 @@ def test_no_entry_point_makes_up_a_cache_path():
             for n, line in enumerate(f, 1):
                 if "cache" in line.lower() and suspects.search(line):
                     raise AssertionError(f"{rel}:{n}: {line.strip()}")
+
+
+def test_a_cache_hit_never_serves_another_sources_scope_names(compile_cache_dir):
+    """ISSUE 23: a named scope is metadata, and JAX's cache key leaves
+    metadata out by default — a program that differs from a cached one
+    only in its scopes would be served the cached executable, names and
+    all, and a profile would attribute its time to the other source's
+    scopes. ``setup_compilation_cache`` makes metadata part of the key."""
+    import contextlib
+
+    import jax.numpy as jnp
+
+    from acco_tpu.compile import setup_compilation_cache
+
+    setup_compilation_cache(compile_cache_dir)
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+
+    def program(scoped):
+        def f(x):
+            with (
+                jax.named_scope("acco/optimizer")
+                if scoped
+                else contextlib.nullcontext()
+            ):
+                return jnp.sin(x) * 2 + 1
+
+        return jax.jit(f).lower(jnp.ones((64, 64))).compile().as_text()
+
+    assert "acco/optimizer" not in program(scoped=False)  # fills the cache
+    assert "acco/optimizer" in program(scoped=True)

@@ -332,6 +332,27 @@ def test_metrics_gate_passes_on_clean_source():
     assert rep.ok and rep.checked == 2
 
 
+@pytest.mark.parametrize(
+    "call, findings",
+    [
+        ("jax.named_scope('acco/optimizer')", 0),
+        ("jax.named_scope('model/block')", 0),
+        ("jax.named_scope('acco/optimiser')", 1),  # misspelled
+        ("jax.named_scope('train/dispatch')", 1),  # a span, not a scope
+        ("jax.named_scope(name)", 1),  # not a literal: nothing checks it at runtime
+    ],
+)
+def test_metrics_gate_checks_named_scope_literals(call, findings):
+    """ISSUE 23: a ``jax.named_scope`` outside DEVICE_SCOPES is device
+    time no per-layer metric owns."""
+    from acco_tpu.analysis.metrics_gate import check_file
+
+    src = f"import jax\ndef f(name):\n    with {call}:\n        pass\n"
+    rep = check_file("inline.py", source=src)
+    assert rep.checked == 1
+    assert [f.rule for f in rep.findings] == ["undeclared-scope"] * findings
+
+
 def test_repo_metrics_gate_is_clean():
     """The enforced baseline: every literal telemetry name in the
     package, tools, and bench harness is declared — same walk

@@ -1,4 +1,4 @@
-"""Telemetry subsystem (ISSUE 19): registry, tracer, attribution, serve.
+"""Telemetry subsystem (ISSUE 19, 23): registry, tracer, serve.
 
 Proof obligations, all tier-1 fast:
 
@@ -6,10 +6,10 @@ Proof obligations, all tier-1 fast:
   declared surface round-trips through scalar/snapshot/Prometheus);
 - the tracer emits a **valid Chrome trace** (nonnegative durations,
   proper per-track nesting — checked by the same ``validate_trace`` the
-  smoke run uses) and its disabled form records nothing;
-- attribution **buckets sum to the measured round wall** by
-  construction, and the measured-vs-analytic overlap math matches a
-  hand-computed split;
+  smoke run uses), its disabled form records nothing, and a span
+  enters the **injected annotation** once (what puts the trainer's
+  spans on a ``jax.profiler`` capture's host plane) and carries what
+  its block put into the yielded args;
 - the serve ``/metrics`` endpoint scrapes as parseable Prometheus
   0.0.4 text with the scheduler's counters in it;
 - the **zero-added-syncs contract**: the telemetry package never
@@ -26,13 +26,11 @@ import urllib.request
 import pytest
 
 from acco_tpu.telemetry import (
+    DEVICE_SCOPES,
     SPAN_NAMES,
-    StepAttribution,
     Tracer,
     UndeclaredMetricError,
     UndeclaredSpanError,
-    attribution_report,
-    split_device_residual,
     validate_trace,
 )
 from acco_tpu.telemetry import test_duration_records as duration_records  # noqa: E501  (aliased so pytest does not collect it)
@@ -68,21 +66,21 @@ def test_gauge_last_write_wins_and_unset_reads_none():
     reg.emit("serve_slots_free", 4)
     reg.emit("serve_slots_free", 2)
     assert reg.scalar("serve_slots_free") == 2
-    # scalar_row omits the never-emitted names entirely
-    row = reg.scalar_row()
-    assert "serve_slots_free" in row and "serve_waiting" not in row
+    # the snapshot holds every declared name, never-emitted gauges as None
+    snap = reg.snapshot()
+    assert snap["serve_slots_free"] == 2 and snap["serve_waiting"] is None
 
 
 def test_histogram_p50_and_prometheus_text():
     reg = _registry()
     for v in (10.0, 20.0, 30.0, 40.0):
-        reg.emit("train_round_wall_ms", v)
-    p50 = reg.scalar("train_round_wall_ms")
+        reg.emit("train_log_sync_ms", v)
+    p50 = reg.scalar("train_log_sync_ms")
     assert 10.0 <= p50 <= 40.0
     text = reg.to_prometheus_text()
-    assert "# TYPE acco_train_round_wall_ms histogram" in text
-    assert 'acco_train_round_wall_ms_bucket{le="+Inf"} 4' in text
-    assert "acco_train_round_wall_ms_count 4" in text
+    assert "# TYPE acco_train_log_sync_ms histogram" in text
+    assert 'acco_train_log_sync_ms_bucket{le="+Inf"} 4' in text
+    assert "acco_train_log_sync_ms_count 4" in text
     # every exposition line is a comment or "name[{labels}] value"
     for line in text.strip().splitlines():
         if line.startswith("#"):
@@ -174,77 +172,102 @@ def test_test_duration_records_bridge():
     }
 
 
-# -- attribution: buckets sum to the wall ------------------------------------
-
-EST_ROW = {
-    "devices": 8,
-    "acco_est_ms": 100.0,
-    "acco_comm_ms": 40.0,
-    "acco_comm_exposed_ms": 10.0,   # analytic: 30 of 40 hidden
-    "acco_pct_comm_hidden": 75.0,
-}
+# -- tracer: the injected annotation and the yielded args ---------------------
 
 
-def test_buckets_sum_to_round_wall():
-    att = StepAttribution()
-    att.note("loader", 30.0)
-    att.note("ckpt", 10.0)
-    att.note("host_stall", 20.0)
-    att.boundary(n_rounds=2, wall_ms=500.0)
-    att.note("loader", 12.0)
-    att.boundary(n_rounds=1, wall_ms=260.0)
-    rep = attribution_report(att.summary(), EST_ROW)
-    total = sum(rep["buckets_ms"].values())
-    assert rep["bucket_sum_ms"] == pytest.approx(total)
-    # the acceptance identity: buckets == measured round wall (±5%)
-    assert total == pytest.approx(rep["round_wall_ms"], rel=0.05)
-    assert rep["rounds"] == 3 and rep["windows"] == 2
-    assert rep["clamped_ms"] == 0.0
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts enters and
+    exits per name."""
+
+    def __init__(self):
+        self.entered, self.exited = [], []
+
+    def __call__(self, name):
+        outer = self
+
+        class _Ctx:
+            def __enter__(self):
+                outer.entered.append(name)
+
+            def __exit__(self, *exc):
+                outer.exited.append(name)
+
+        return _Ctx()
 
 
-def test_measured_overlap_matches_hand_computation():
-    # residual 120 ms vs analytic compute-window 90 -> 30 ms exposed of
-    # 40 ms comm -> 25% exposed, 75% hidden (the analytic row's own
-    # number: zero divergence by construction)
-    split = split_device_residual(120.0, EST_ROW)
-    assert split["exposed_comm_ms"] == pytest.approx(30.0)
-    assert split["compute_ms"] == pytest.approx(90.0)
-    assert split["measured_overlap_pct"] == pytest.approx(25.0)
-    # fully inside the window: nothing exposed, 100% hidden
-    assert split_device_residual(80.0, EST_ROW)[
-        "measured_overlap_pct"] == pytest.approx(100.0)
-    # way past the window: exposure clamps at the comm total, 0% hidden
-    assert split_device_residual(1000.0, EST_ROW)[
-        "measured_overlap_pct"] == pytest.approx(0.0)
-    # no row (CPU smoke at an odd mesh size): split skipped entirely
-    assert "measured_overlap_pct" not in split_device_residual(120.0, None)
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_enters_the_injected_annotation_once_per_span(enabled):
+    notes = _Annotations()
+    tr = Tracer(enabled=enabled, annotate=notes)
+    with tr.span("train/round"):
+        with tr.span("train/dispatch"):
+            pass
+    with pytest.raises(RuntimeError):
+        with tr.span("loader/next_block"):
+            raise RuntimeError("the block failed")
+    # complete_event and instant are after-the-fact: never annotated
+    tr.complete_event("train/eval", 1.0)
+    tr.instant("ckpt/snapshot")
+    expected = (
+        ["train/round", "train/dispatch", "loader/next_block"]
+        if enabled
+        else []
+    )
+    assert notes.entered == expected
+    assert sorted(notes.exited) == sorted(expected)
+    spans = [e["name"] for e in tr.events() if e.get("ph") == "X"]
+    assert sorted(spans) == sorted(expected + (["train/eval"] if enabled else []))
 
 
-def test_divergence_warning_fires(caplog):
-    att = StepAttribution()
-    att.boundary(n_rounds=1, wall_ms=200.0)  # all residual -> exposed maxes
-    import logging
-
-    with caplog.at_level(logging.WARNING):
-        rep = attribution_report(att.summary(), EST_ROW, divergence_pct=25.0)
-    assert rep["diverged"]
-    assert any("OVERLAP DIVERGENCE" in r.message for r in caplog.records)
+def test_span_without_annotation_factory_still_records():
+    tr = Tracer()
+    with tr.span("train/dispatch"):
+        pass
+    assert [e["name"] for e in tr.events() if e["ph"] == "X"] == ["train/dispatch"]
 
 
-def test_host_buckets_overrun_is_clamped_and_reported():
-    att = StepAttribution()
-    att.note("loader", 999.0)  # more host stall than the window wall
-    att.boundary(n_rounds=1, wall_ms=100.0)
-    rep = attribution_report(att.summary(), None)
-    assert rep["clamped_ms"] == pytest.approx(899.0)
-    assert rep["buckets_ms"]["compute_ms"] == 0.0
+def test_span_yields_args_the_block_may_fill():
+    tr = Tracer()
+    with tr.span("train/log_boundary_sync", round=7) as fence:
+        fence.update(loss=2.5, committed=56.0)
+    with tr.span("train/log_boundary_host") as host:
+        assert host == {}
+    events = {e["name"]: e for e in tr.events() if e["ph"] == "X"}
+    assert events["train/log_boundary_sync"]["args"] == {
+        "round": 7, "loss": 2.5, "committed": 56.0,
+    }
+    assert "args" not in events["train/log_boundary_host"]
+    # a disabled tracer still hands the block a dict to fill
+    off = Tracer(enabled=False)
+    with off.span("train/log_boundary_sync") as fence:
+        fence["loss"] = 1.0
+    assert off.events() == []
 
 
-def test_empty_attribution_reports_none():
-    att = StepAttribution()
-    assert att.boundary(n_rounds=0, wall_ms=0.0) is None
-    assert att.summary() is None
-    assert attribution_report(None, EST_ROW) is None
+def test_thread_name_event_counts_against_the_bound():
+    """A new thread's name event used to land past ``max_events``."""
+    tr = Tracer(max_events=2)
+    tr.complete_event("train/dispatch", 0.001)  # name event + this one
+    assert len(tr.events()) == 2
+
+    def other_thread():
+        tr.complete_event("ckpt/commit", 0.001)
+
+    t = threading.Thread(target=other_thread)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    assert len(tr.events()) == 2 and tr.dropped == 1
+    # exactly one slot left: the thread's name takes it, its event drops
+    tr = Tracer(max_events=1)
+    tr.complete_event("train/dispatch", 0.001)
+    assert [e["ph"] for e in tr.events()] == ["M"] and tr.dropped == 1
+
+
+def test_device_scopes_are_declared_once_and_distinct():
+    assert len(DEVICE_SCOPES) == len(set(DEVICE_SCOPES))
+    assert all(isinstance(s, str) and "/" in s for s in DEVICE_SCOPES)
+    assert not set(DEVICE_SCOPES) & SPAN_NAMES
 
 
 # -- serve /metrics ----------------------------------------------------------

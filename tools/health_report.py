@@ -8,12 +8,6 @@ tool reads both back and prints one robustness table, so BENCH_* rounds
 can track guard overhead and skip/rollback behavior the same way they
 track tokens/sec — no JAX import, safe on any machine.
 
-The telemetry subsystem (acco_tpu/telemetry) adds measured-overlap
-columns to both ledgers: ``measured_overlap_pct`` /
-``analytic_overlap_pct`` / ``overlap_divergence_pct`` in results.csv
-and ``measured_overlap_pct`` in the bench record — surfaced here so
-overlap regressions show up next to the robustness counters.
-
 Usage::
 
     python tools/health_report.py                    # ./results.csv + BENCH_*.json
@@ -34,13 +28,11 @@ HEALTH_COLUMNS = (
     "rollbacks",
     "grad_norm_spikes",
     "grad_norm_drifts",
-    "measured_overlap_pct",
 )
 BENCH_FIELDS = (
     "guard_overhead_pct",
     "skipped_rounds",
     "chaos",
-    "measured_overlap_pct",
 )
 # serve_load records (tools/load_harness.py) carry the serving
 # robustness counters instead of the training ones
@@ -81,21 +73,19 @@ def report_results_csv(path: str) -> list[str]:
         )
         return lines
     lines.append(
-        "  {:<24} {:>7} {:>9} {:>6} {:>6} {:>9} {:>9}  {}".format(
+        "  {:<24} {:>7} {:>9} {:>6} {:>6}  {}".format(
             "id_run", "skipped", "rollback", "spike", "drift",
-            "overlap%", "analytic%", "method/bench"
+            "method/bench"
         )
     )
     for r in health_rows:
         lines.append(
-            "  {:<24} {:>7} {:>9} {:>6} {:>6} {:>9} {:>9}  {}".format(
+            "  {:<24} {:>7} {:>9} {:>6} {:>6}  {}".format(
                 _fmt(r.get("0_id_run"))[:24],
                 _fmt(r.get("skipped_rounds")),
                 _fmt(r.get("rollbacks")),
                 _fmt(r.get("grad_norm_spikes")),
                 _fmt(r.get("grad_norm_drifts")),
-                _fmt(r.get("measured_overlap_pct")),
-                _fmt(r.get("analytic_overlap_pct")),
                 _fmt(r.get("method_name") or r.get("bench")),
             )
         )

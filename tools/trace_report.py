@@ -1,17 +1,18 @@
 """Summarize a telemetry ``trace_*.json`` into one terminal report.
 
 Reads the Chrome/Perfetto trace a run wrote (``acco_tpu/telemetry``),
-validates it, and prints three tables:
+validates it, and prints two tables:
 
 1. **top spans** — per span name: count, total/mean/median/max wall, so
-   "where did the time go" has an answer without opening a viewer;
-2. **per-round buckets** — the run's attribution report (embedded under
-   ``otherData.attribution``): loader / ckpt / host_stall / compute /
-   exposed_comm per-round means, their sum vs the measured round wall;
-3. **measured vs analytic overlap** — the measured overlap efficiency
-   next to ``tools/step_estimate.py``'s analytic prediction for the same
-   device count, with the divergence that ``--ci``-style monitoring
-   would alarm on.
+   "where did the host's time go" has an answer without opening a viewer;
+2. **logging boundaries** — what each ``train/log_boundary_sync`` fence
+   learned (round, loss, grad norm, committed grads, skipped rounds) and
+   how long the fence and the host work after it took.
+
+Where the DEVICE's time went, and which host span each of its idle gaps
+lies under, is read from the ``jax.profiler`` capture the trace names
+under ``otherData.profile_dir`` (``train.profile_steps``), by the
+benchmark's reducers (``benchmark/reducers/``).
 
 Pure host-side: no jax import (the telemetry package is jax-free by
 contract), safe on any machine.
@@ -36,15 +37,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from acco_tpu.telemetry import validate_trace  # noqa: E402
-
-ATTRIB_BUCKETS = (
-    ("loader_ms", "loader"),
-    ("ckpt_ms", "ckpt"),
-    ("host_stall_ms", "host_stall"),
-    ("compute_ms", "compute"),
-    ("exposed_comm_ms", "exposed_comm"),
-)
-
 
 def newest_trace(root: str = REPO) -> str | None:
     paths = glob.glob(os.path.join(root, "outputs", "**", "trace_*.json"),
@@ -87,55 +79,44 @@ def span_table(events: list[dict], top: int) -> list[str]:
     return lines
 
 
-def attribution_table(attrib: dict | None) -> list[str]:
-    if not attrib:
-        return [
-            "per-round attribution: (absent — run predates the telemetry "
-            "subsystem, or telemetry was disabled)"
-        ]
-    rounds = attrib.get("rounds", 0)
-    wall = attrib.get("round_wall_ms")
-    buckets = attrib.get("buckets_ms") or {}
-    lines = [
-        f"per-round attribution ({rounds} rounds, "
-        f"{attrib.get('windows', 0)} boundary windows):",
-        "  {:<14} {:>12} {:>7}".format("bucket", "mean ms", "share"),
-    ]
-    for key, label in ATTRIB_BUCKETS:
-        v = buckets.get(key)
-        share = (
-            f"{100 * v / wall:.1f}%" if v is not None and wall else "-"
-        )
-        lines.append(
-            "  {:<14} {:>12} {:>7}".format(label, _fmt_ms(v), share)
-        )
-    lines.append(
-        "  {:<14} {:>12}   (measured round wall: {} ms, clamped: {} ms)"
-        .format(
-            "sum", _fmt_ms(attrib.get("bucket_sum_ms")), _fmt_ms(wall),
-            _fmt_ms(attrib.get("clamped_ms")),
-        )
+def boundary_table(events: list[dict], last: int = 8) -> list[str]:
+    """The last ``last`` logging boundaries: what the fence learned and
+    what the fence and the host work after it cost."""
+    fences = sorted(
+        (e for e in events
+         if e.get("ph") == "X" and e.get("name") == "train/log_boundary_sync"),
+        key=lambda e: e["ts"],
     )
-    return lines
-
-
-def overlap_table(attrib: dict | None) -> list[str]:
-    if not attrib or "measured_overlap_pct" not in attrib:
-        return [
-            "overlap: no measured-vs-analytic row (ESTIMATES.json lacks "
-            "this device count, or the run had no rounds)"
-        ]
+    hosts = sorted(
+        (e for e in events
+         if e.get("ph") == "X" and e.get("name") == "train/log_boundary_host"),
+        key=lambda e: e["ts"],
+    )
+    if not fences:
+        return ["logging boundaries: (none in this trace)"]
     lines = [
-        "overlap efficiency (measured vs analytic):",
-        "  measured : {:.2f}%".format(attrib["measured_overlap_pct"]),
-        "  analytic : {:.2f}%  (tools/step_estimate.py ESTIMATES.json)"
-        .format(attrib["analytic_overlap_pct"]),
-        "  diverge  : {:.2f} pts".format(attrib["overlap_divergence_pct"]),
+        f"logging boundaries (last {min(last, len(fences))} of {len(fences)}):",
+        "  {:>7} {:>10} {:>10} {:>10} {:>8} {:>9} {:>9}".format(
+            "round", "loss", "grad norm", "committed", "skipped",
+            "fence ms", "host ms"
+        ),
     ]
-    if attrib.get("diverged"):
+    for fence in fences[-last:]:
+        args = fence.get("args") or {}
+        end = fence["ts"] + fence.get("dur", 0.0)
+        # the boundary's host span begins where its fence ended
+        host = next((h for h in hosts if 0 <= h["ts"] - end < 1000.0), None)
+
+        def num(key, fmt):
+            return format(args[key], fmt) if key in args else "-"
+
         lines.append(
-            "  ** OVERLAP DIVERGENCE — the analytic model no longer "
-            "predicts this hardware; re-derive ESTIMATES.json **"
+            "  {:>7} {:>10} {:>10} {:>10} {:>8} {:>9} {:>9}".format(
+                num("round", "d"), num("loss", ".4f"), num("grad_norm", ".3f"),
+                num("committed", ".0f"), num("skipped_rounds", "d"),
+                _fmt_ms(fence.get("dur", 0.0) / 1e3),
+                _fmt_ms(host["dur"] / 1e3 if host else None),
+            )
         )
     return lines
 
@@ -159,9 +140,14 @@ def report(path: str, top: int = 12) -> list[str]:
     lines.append("")
     lines += span_table(events, top)
     lines.append("")
-    lines += attribution_table(other.get("attribution"))
-    lines.append("")
-    lines += overlap_table(other.get("attribution"))
+    lines += boundary_table(events)
+    if other.get("profile_dir"):
+        lines.append("")
+        lines.append(
+            "device profile of rounds {}: {}".format(
+                other.get("profiled_rounds"), other["profile_dir"]
+            )
+        )
     return lines
 
 
