@@ -24,29 +24,97 @@ from acco_tpu.parallel.ring_collectives import (
 WS = 8
 
 
-@pytest.mark.parametrize("chunk", [16, 17])  # even and odd shard splits
-def test_ring_matches_xla_collectives(eight_devices, chunk):
-    mesh = make_mesh()
+def _dp_mesh(n):
+    return make_mesh(devices=jax.devices()[:n])  # 1-D "dp" over n devices
+
+
+def _on_ring(body, mesh, x, out_specs):
+    fn = jax.jit(
+        jax.shard_map(
+            body, mesh=mesh, in_specs=(P("dp"),), out_specs=out_specs,
+            check_vma=False,
+        )
+    )
+    return fn(jax.device_put(x, NamedSharding(mesh, P("dp"))))
+
+
+# even, odd (ragged halves) and one-element chunks (an empty forward half)
+@pytest.mark.parametrize("chunk", [16, 17, 1])
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_ring_matches_xla_collectives(eight_devices, n, chunk):
+    """f32 reduce-scatter up to rounding, bf16 all-gather exactly."""
+    mesh = _dp_mesh(n)
     x = jnp.asarray(
-        np.random.default_rng(0).normal(size=(WS * WS * chunk,)), jnp.float32
+        np.random.default_rng(0).normal(size=(n * n * chunk,)), jnp.float32
     )
 
     def body(x):
         rs = ring_reduce_scatter(x, "dp")
         rs_ref = jax.lax.psum_scatter(x, "dp", tiled=True)
-        ag = ring_all_gather(rs_ref, "dp")
-        ag_ref = jax.lax.all_gather(rs_ref, "dp", tiled=True)
-        return rs - rs_ref, ag - ag_ref
+        shard = rs_ref.astype(jnp.bfloat16)
+        ag = ring_all_gather(shard, "dp")
+        ag_ref = jax.lax.all_gather(shard, "dp", tiled=True)
+        return rs - rs_ref, ag, ag_ref
 
-    fn = jax.jit(
-        jax.shard_map(
-            body, mesh=mesh, in_specs=(P("dp"),),
-            out_specs=(P("dp"), P("dp")), check_vma=False,
-        )
-    )
-    d_rs, d_ag = fn(jax.device_put(x, NamedSharding(mesh, P("dp"))))
+    d_rs, ag, ag_ref = _on_ring(body, mesh, x, (P("dp"), P("dp"), P("dp")))
     np.testing.assert_allclose(np.asarray(d_rs), 0.0, atol=2e-5)
-    np.testing.assert_array_equal(np.asarray(d_ag), 0.0)  # no math, exact
+    assert ag.dtype == ag_ref.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(ag), np.asarray(ag_ref))  # no math, exact
+
+
+def _replay_reduce_scatter(x, n):
+    """The ring's reduce-scatter in NumPy, hop for hop: ``x[d]`` is device
+    ``d``'s ``[n*S]`` addends; returns the ``[n, S]`` reduced chunks."""
+    S = x.shape[1] // n
+    half = S // 2
+    c = x.reshape(n, n, S)
+    dev = np.arange(n)
+    acc_f = c[dev, (dev - 1) % n, :half]
+    acc_b = c[dev, (dev + 1) % n, half:]
+    for k in range(1, n):
+        # device d receives what d-1 (forward) and d+1 (backward) held
+        acc_f = np.roll(acc_f, 1, axis=0) + c[dev, (dev - 1 - k) % n, :half]
+        acc_b = np.roll(acc_b, -1, axis=0) + c[dev, (dev + 1 + k) % n, half:]
+    return np.concatenate([acc_f, acc_b], axis=1)
+
+
+@pytest.mark.parametrize("chunk", [16, 17, 1])
+@pytest.mark.parametrize("n", [2, 4, 8])
+def test_ring_reduce_scatter_addition_order(eight_devices, n, chunk):
+    """Bit-equal to the replay: the order of the float additions is what a
+    run's loss depends on, so a rewrite of the bodies may not change it."""
+    mesh = _dp_mesh(n)
+    x = np.random.default_rng(n * 100 + chunk).normal(
+        size=(n, n * chunk)
+    ).astype(np.float32)
+    got = _on_ring(
+        lambda xl: ring_reduce_scatter(xl, "dp"), mesh,
+        jnp.asarray(x.reshape(-1)), P("dp"),
+    )
+    np.testing.assert_array_equal(
+        np.asarray(got).reshape(n, chunk), _replay_reduce_scatter(x, n)
+    )
+
+
+@pytest.mark.parametrize(
+    "collective, dtype, too_long, fits",  # a device's input, in elements
+    [
+        (ring_reduce_scatter, jnp.float32, 2**31, 2**31 - 4),
+        (ring_all_gather, jnp.bfloat16, 2**29, 2**29 - 1),  # x n = 4 gathered
+    ],
+)
+def test_ring_refuses_a_vector_past_int32_offsets(
+    eight_devices, collective, dtype, too_long, fits
+):
+    """Traced from shapes only: nothing that large is allocated."""
+    n = 4
+    sharded = jax.shard_map(
+        lambda v: collective(v, "dp"), mesh=_dp_mesh(n), in_specs=(P("dp"),),
+        out_specs=P("dp"), check_vma=False,
+    )
+    with pytest.raises(ValueError, match=str(2**31)):
+        jax.eval_shape(sharded, jax.ShapeDtypeStruct((n * too_long,), dtype))
+    jax.eval_shape(sharded, jax.ShapeDtypeStruct((n * fits,), dtype))
 
 
 def test_acco_round_ring_matches_xla(eight_devices):
