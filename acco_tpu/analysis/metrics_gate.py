@@ -20,7 +20,7 @@ a literal first argument is the contract):
   ``cat`` is a :data:`~acco_tpu.telemetry.trace.FREE_CATEGORIES` member
   (the conftest's pytest-nodeid events);
 - ``*.named_scope("name")`` (``jax.named_scope``) → must be one of
-  :data:`acco_tpu.telemetry.trace.DEVICE_SCOPES`: the benchmark's device
+  :data:`acco_tpu.telemetry.trace.ALL_DEVICE_SCOPES`: the benchmark's device
   metrics select ops by these names, so a scope outside the list is time
   no metric owns. Here a non-literal argument is a finding too: there is
   no runtime check behind this one.
@@ -40,7 +40,7 @@ from dataclasses import dataclass, field
 
 from acco_tpu.analysis.host_lint import DEFAULT_EXCLUDE_DIRS, Finding
 from acco_tpu.telemetry.metrics import REGISTRY
-from acco_tpu.telemetry.trace import DEVICE_SCOPES, FREE_CATEGORIES, SPAN_NAMES
+from acco_tpu.telemetry.trace import ALL_DEVICE_SCOPES, FREE_CATEGORIES, SPAN_NAMES
 
 METRIC_METHODS = {"emit"}
 METRIC_MANY_METHODS = {"emit_many"}
@@ -125,11 +125,11 @@ class _TelemetryCallVisitor(ast.NodeVisitor):
 
     def _check_scope(self, node: ast.Call, name: str | None) -> None:
         self.report.checked += 1
-        if name not in DEVICE_SCOPES:
+        if name not in ALL_DEVICE_SCOPES:
             self.report.findings.append(Finding(
                 self.path, node.lineno, "undeclared-scope",
                 f"named_scope of {name!r}, which is not a literal member of "
-                "telemetry.trace.DEVICE_SCOPES (closed world: declare it "
+                "telemetry.trace.ALL_DEVICE_SCOPES (closed world: declare it "
                 "there, and say in PERF.md which metric reads it)",
             ))
 

@@ -26,6 +26,9 @@ def normal_init(key: jax.Array, shape: tuple, stddev: float, dtype) -> jax.Array
     return (jax.random.normal(key, shape, jnp.float32) * stddev).astype(dtype)
 
 
+_MOE_DOTS = ("moe_gate", "moe_up", "moe_down")  # ops/moe.py's grouped matmuls
+
+
 def wrap_remat(block, remat):
     """Apply the configured rematerialisation mode to a scan block.
 
@@ -44,6 +47,10 @@ def wrap_remat(block, remat):
     without the names the backward re-traces and reruns the forward
     kernel once per layer purely to regenerate its residuals. On the
     einsum path the names never occur and the policy is unchanged.
+    Likewise the sparse experts' grouped matmuls (ops/moe.py: ``moe_gate``,
+    ``moe_up``, ``moe_down``): a grouped matmul through a kernel is a matmul
+    the stock dots policy does not recognise, and 'dots' means their outputs
+    are stored.
 
     Spellings are normalized through ops.attention.normalize_remat (the
     one normalizer every surface shares), so YAML/CLI forms like
@@ -57,7 +64,7 @@ def wrap_remat(block, remat):
         policy = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "attn_lse"
+                "attn_out", "attn_lse", *_MOE_DOTS
             ),
         )
         return jax.checkpoint(block, policy=policy)
@@ -69,7 +76,7 @@ def wrap_remat(block, remat):
         policy = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             jax.checkpoint_policies.save_only_these_names(
-                "attn_probs", "attn_out", "attn_lse"
+                "attn_probs", "attn_out", "attn_lse", *_MOE_DOTS
             ),
         )
         return jax.checkpoint(block, policy=policy)

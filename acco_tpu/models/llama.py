@@ -4,6 +4,15 @@ Capability parity with the reference's Llama finetuning path (HF
 ``LlamaForCausalLM``, `/root/reference/README.md:78-95`), designed
 TPU-first: stacked-layer ``lax.scan`` body, bfloat16 parameters, float32
 softmax, optional ``jax.checkpoint`` rematerialisation.
+
+The block carries two options that make it OLMoE's (``model_type: "olmoe"``,
+HF ``modeling_olmoe.py``): ``qk_norm`` (RMSNorm over the whole query and key
+projections, before the split into heads and RoPE) and ``num_experts`` > 0
+(the SwiGLU MLP becomes ``num_experts`` SwiGLU experts of width
+``intermediate_size``, ``num_experts_per_tok`` a token, dropless:
+ops/moe.py). With both off the block is the dense Llama block, bit for bit.
+An expert model trains data-parallel (dp, ZeRO-1) only; tp, pp, context
+parallelism and serving refuse it by name (sharding/tables.refuse_experts).
 """
 
 from __future__ import annotations
@@ -53,15 +62,44 @@ class LlamaConfig:
     tie_word_embeddings: bool = True
     bos_token_id: int = 50256
     eos_token_id: int = 50256
+    # OLMoE's two departures from the Llama block (module docstring)
+    qk_norm: bool = False
+    num_experts: int = 0  # 0 = the dense SwiGLU MLP
+    num_experts_per_tok: int = 0
+    norm_topk_prob: bool = False  # renormalise the top-k gates to sum to 1
+    router_aux_loss_coef: float = 0.0  # load-balancing loss
+    router_z_loss_coef: float = 0.0  # router z-loss
+
+    def __post_init__(self):
+        if self.num_experts and not 0 < self.num_experts_per_tok <= self.num_experts:
+            raise ValueError(
+                f"num_experts={self.num_experts} needs 0 < num_experts_per_tok <= "
+                f"num_experts, got {self.num_experts_per_tok}"
+            )
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
 
+    # Hugging Face's names for this repo's keys. A file may hold both: the
+    # repo's own key wins (a benchmark configuration keeps the published
+    # ``num_hidden_layers`` beside the ``num_layers`` it runs).
+    _HF_KEYS = {
+        "num_hidden_layers": "num_layers",
+        "num_attention_heads": "num_heads",
+        "num_key_value_heads": "num_kv_heads",
+    }
+
     @classmethod
     def from_json(cls, path: str) -> "LlamaConfig":
         with open(path) as f:
             raw = json.load(f)
+        unsupported = [k for k in ("attention_bias", "clip_qkv", "rope_scaling") if raw.get(k)]
+        if unsupported:
+            raise ValueError(f"{path}: {unsupported} set, which LlamaModel does not implement")
+        for hf_key, key in cls._HF_KEYS.items():
+            if hf_key in raw:
+                raw.setdefault(key, raw[hf_key])
         fields = {f.name for f in dataclasses.fields(cls)}
         return cls(**{k: v for k, v in raw.items() if k in fields and v is not None})
 
@@ -145,7 +183,12 @@ class LlamaModel:
             keys = jax.random.split(key, N)
             return jnp.stack([normal_init(k, shape, std, dt) for k in keys])
 
-        ks = jax.random.split(k_layers, 7)
+        # an expert model draws one more key (the router's), so its other
+        # leaves differ from a dense model's of the same seed; a dense
+        # model's stay what they were
+        E = cfg.num_experts
+        ks = jax.random.split(k_layers, 8 if E else 7)
+        mlp_in, mlp_out = ((E, D, F), (E, F, D)) if E else ((D, F), (F, D))
         params = {
             "wte": normal_init(k_emb, (self.padded_vocab, D), std, dt),
             "layers": {
@@ -155,12 +198,21 @@ class LlamaModel:
                 "wv": stack_init(ks[2], (D, Dkv)),
                 "wo": stack_init(ks[3], (D, D)),
                 "mlp_norm": jnp.ones((N, D), dt),
-                "w_gate": stack_init(ks[4], (D, F)),
-                "w_up": stack_init(ks[5], (D, F)),
-                "w_down": stack_init(ks[6], (F, D)),
+                "w_gate": stack_init(ks[4], mlp_in),
+                "w_up": stack_init(ks[5], mlp_in),
+                "w_down": stack_init(ks[6], mlp_out),
             },
             "final_norm": jnp.ones((D,), dt),
         }
+        if cfg.qk_norm:  # over the whole projection, not per head
+            params["layers"]["q_norm"] = jnp.ones((N, D), dt)
+            params["layers"]["k_norm"] = jnp.ones((N, Dkv), dt)
+        if E:
+            # [E, D], the layout of HF's gate.weight: a leaf whose last dim is
+            # the hidden size unpacks from the flat vector through the view
+            # the other leaves use; [D, E] makes XLA re-tile the whole vector
+            # as [*, 64], lane-padded to twice its size
+            params["layers"]["router"] = stack_init(ks[7], (E, D))
         if not cfg.tie_word_embeddings:
             params["lm_head"] = normal_init(
                 k_head, (D, self.padded_vocab), std, dt
@@ -204,14 +256,49 @@ class LlamaModel:
         params: dict,
         input_ids: jax.Array,  # [B, L] int32
         attention_mask: Optional[jax.Array] = None,  # [B, L] 1=real
+        *,
+        with_aux: bool = False,
     ) -> jax.Array:  # [B, L, V] float32 logits ([B, L, V/tp] local under tp)
-        x = self.hidden(params, input_ids, attention_mask)
-        return jnp.einsum(
-            "bld,dv->blv",
-            x,
-            self.lm_head(params),
-            preferred_element_type=jnp.float32,
+        x, aux = self.hidden(params, input_ids, attention_mask, with_aux=True)
+        with jax.named_scope("model/lm_head_ce"):
+            logits = jnp.einsum(
+                "bld,dv->blv",
+                x,
+                self.lm_head(params),
+                preferred_element_type=jnp.float32,
+            )
+        return (logits, aux) if with_aux else logits
+
+    # -- the objective's auxiliary terms ------------------------------------
+
+    @property
+    def has_aux_loss(self) -> bool:
+        """The objective holds more than the cross-entropy: ``hidden`` and
+        ``apply`` take ``with_aux=True`` and return the terms beside their
+        output, and :meth:`aux_loss` weighs them (ops/losses.model_ce)."""
+        return self.config.num_experts > 0
+
+    def aux_loss(self, aux: dict) -> jax.Array:
+        """What the auxiliary terms add to the cross-entropy."""
+        cfg = self.config
+        return (
+            cfg.router_aux_loss_coef * aux["moe_lb_loss"]
+            + cfg.router_z_loss_coef * aux["moe_z_loss"]
         )
+
+    @staticmethod
+    def _aux_terms(stats) -> dict:
+        """Scalars from the layers' per-sequence router statistics (leaves
+        ``[num_layers, B]``; None for a dense model): the two losses are means
+        over layers and sequences, the load is each sequence's most loaded
+        expert of any layer (1.0 = balanced), averaged over sequences."""
+        if stats is None:
+            return {}
+        return {
+            "moe_lb_loss": stats.lb_loss.mean(),
+            "moe_z_loss": stats.z_loss.mean(),
+            "moe_max_load": stats.max_load.max(axis=0).mean(),
+        }
 
     def lm_head(self, params: dict) -> jax.Array:
         """[D, V] output-projection matrix (wte transposed when tied);
@@ -223,18 +310,24 @@ class LlamaModel:
     def embed(self, params: dict, input_ids: jax.Array) -> jax.Array:
         """Token embedding lookup; vocab-parallel under ``tensor_axis``
         (layers.vocab_parallel_embed — the Megatron pattern)."""
-        if not self.tensor_axis:
-            return params["wte"][input_ids]
-        from acco_tpu.models.layers import vocab_parallel_embed
+        with jax.named_scope("model/embed"):
+            if not self.tensor_axis:
+                return params["wte"][input_ids]
+            from acco_tpu.models.layers import vocab_parallel_embed
 
-        return vocab_parallel_embed(params["wte"], input_ids, self.tensor_axis)
+            return vocab_parallel_embed(params["wte"], input_ids, self.tensor_axis)
 
     def hidden(
         self,
         params: dict,
         input_ids: jax.Array,  # [B, L] int32
         attention_mask: Optional[jax.Array] = None,  # [B, L] 1=real
+        *,
+        with_aux: bool = False,
     ) -> jax.Array:  # [B, L, D] final-norm hidden states, activation dtype
+        """``with_aux``: return ``(hidden, aux)``, ``aux`` the objective's
+        auxiliary terms as a dict of scalars (:meth:`_aux_terms`; empty for
+        a dense model)."""
         cfg = self.config
         L = input_ids.shape[1]  # ring: the device-local chunk length
         impl = resolve_attention_impl(
@@ -303,8 +396,15 @@ class LlamaModel:
             ),
             self.remat,
         )
-        x, _ = jax.lax.scan(body, x, params["layers"], unroll=self.scan_unroll)
-        return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        # the scope holds the scan itself, not only its body: stacking the
+        # layers' saved activations and slicing them back out in the
+        # backward pass is the block stack's time too
+        with jax.named_scope("model/block"):
+            x, stats = jax.lax.scan(
+                body, x, params["layers"], unroll=self.scan_unroll
+            )
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        return (x, self._aux_terms(stats)) if with_aux else x
 
     def _block_body(
         self, impl, attention_mask, cos, sin, bias, n_heads, n_kv, tp_psum,
@@ -318,36 +418,73 @@ class LlamaModel:
         cfg = self.config
 
         def block(x, layer):
-            h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
-            q = split_heads(h @ layer["wq"], n_heads)
-            k = split_heads(h @ layer["wk"], n_kv)
-            v = split_heads(h @ layer["wv"], n_kv)
-            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-            if impl == "fused":
-                from acco_tpu.ops.fused_attention import (
-                    fused_dot_product_attention,
-                )
+            with jax.named_scope("model/attn"):
+                h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
 
-                ctx = fused_dot_product_attention(q, k, v, attention_mask)
-            elif impl == "flash":
-                ctx = flash_dot_product_attention(q, k, v, attention_mask)
-            elif impl == "ring":
-                ctx = (
-                    zigzag_ring_attention(q, k, v, self.sequence_axis)
-                    if self.zigzag
-                    else ring_attention(q, k, v, self.sequence_axis)
-                )
-            else:
-                ctx = dot_product_attention(q, k, v, bias)
-            x = x + tp_psum(merge_heads(ctx) @ layer["wo"])
-            h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
-            mlp = (jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])) @ layer["w_down"]
-            out = x + tp_psum(mlp)
+                def projection(w, norm, heads):
+                    y = h @ layer[w]
+                    if norm is not None and cfg.qk_norm:
+                        # over the whole projection, before the heads split
+                        y = rms_norm(y, layer[norm], cfg.rms_norm_eps)
+                    return split_heads(y, heads)
+
+                q = projection("wq", "q_norm", n_heads)
+                k = projection("wk", "k_norm", n_kv)
+                v = projection("wv", None, n_kv)
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+                if impl == "fused":
+                    from acco_tpu.ops.fused_attention import (
+                        fused_dot_product_attention,
+                    )
+
+                    ctx = fused_dot_product_attention(q, k, v, attention_mask)
+                elif impl == "flash":
+                    ctx = flash_dot_product_attention(q, k, v, attention_mask)
+                elif impl == "ring":
+                    ctx = (
+                        zigzag_ring_attention(q, k, v, self.sequence_axis)
+                        if self.zigzag
+                        else ring_attention(q, k, v, self.sequence_axis)
+                    )
+                else:
+                    ctx = dot_product_attention(q, k, v, bias)
+                x = x + tp_psum(merge_heads(ctx) @ layer["wo"])
+            stats = None
+            with jax.named_scope("model/mlp"):
+                h = rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+                if cfg.num_experts:
+                    mlp, stats = self._expert_mlp(h, layer, attention_mask)
+                else:
+                    mlp = (jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])) @ layer["w_down"]
+                out = x + tp_psum(mlp)
             if collect_kv:
                 return out, (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
-            return out, None
+            return out, stats
 
         return block
+
+    def _expert_mlp(self, h, layer, attention_mask):
+        """The block's MLP half as sparse experts (ops/moe.py): float32
+        router over all experts, top-k, every assignment computed. Returns
+        ``([B, L, D], RouterStats)``."""
+        from acco_tpu.ops import moe
+
+        cfg = self.config
+        B, L, D = h.shape
+        with jax.named_scope("model/moe_router"):
+            gates, experts, stats = moe.route(
+                h, layer["router"], cfg.num_experts_per_tok, cfg.norm_topk_prob,
+                attention_mask,
+            )
+        k = cfg.num_experts_per_tok
+        out = moe.dropless_experts(
+            h.reshape(B * L, D),
+            gates.reshape(B * L, k),
+            experts.reshape(B * L, k),
+            layer["w_gate"], layer["w_up"], layer["w_down"],
+            platform=self.platform,
+        )
+        return out.reshape(B, L, D), stats
 
     # -- serving surface (acco_tpu/serve) -----------------------------------
 
@@ -358,6 +495,9 @@ class LlamaModel:
         return cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
 
     def _check_serve(self) -> None:
+        from acco_tpu.sharding.tables import refuse_experts
+
+        refuse_experts(self, "serve")
         if self.sequence_axis or self.tensor_axis:
             raise ValueError(
                 "the serving decode path is single-replica: build the "
@@ -392,7 +532,8 @@ class LlamaModel:
             "xla", None, cos, sin, bias, cfg.num_heads, cfg.num_kv_heads,
             lambda t: t, collect_kv=True,
         )
-        x, (k, v) = jax.lax.scan(body, x, params["layers"])
+        with jax.named_scope("model/block"):
+            x, (k, v) = jax.lax.scan(body, x, params["layers"])
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         logits = jnp.einsum(
             "bld,dv->blv", x, self.lm_head(params),
@@ -566,7 +707,8 @@ class LlamaModel:
             ),
             self.remat,
         )
-        x, _ = jax.lax.scan(body, x, layers, unroll=self.scan_unroll)
+        with jax.named_scope("model/block"):
+            x, _ = jax.lax.scan(body, x, layers, unroll=self.scan_unroll)
         return x
 
     def finalize(self, params: dict, x: jax.Array) -> jax.Array:

@@ -43,7 +43,12 @@ _PRESETS: dict[str, tuple[type, dict]] = {
     ),
 }
 
-_MODEL_TYPES = {"llama": (LlamaConfig, LlamaModel), "gpt_neo": (GPTNeoConfig, GPTNeoModel)}
+_MODEL_TYPES = {
+    "llama": (LlamaConfig, LlamaModel),
+    "gpt_neo": (GPTNeoConfig, GPTNeoModel),
+    # OLMoE is Llama's block with QK-norm and sparse experts (models/llama.py)
+    "olmoe": (LlamaConfig, LlamaModel),
+}
 
 
 def build_model(

@@ -412,30 +412,58 @@ def model_ce(
     real_vocab=None,
     num_valid=None,
     shift: bool = True,
+    with_terms: bool = False,
 ):
     """THE fused-vs-materialized CE dispatch, shared by the train path
     (parallel/common.make_flat_loss_fn) and both trainer eval bodies so
     their numerics can never diverge. ``fused`` must already have passed
     :func:`resolve_fused_loss`; ``vocab_axis`` selects the sharded
-    (tensor-parallel) forms."""
+    (tensor-parallel) forms.
+
+    Returns the objective: the cross-entropy, plus the model's auxiliary
+    terms where it has any (``model.has_aux_loss``: a model with experts adds
+    its load-balancing and router z-losses, ``model.aux_loss``).
+    ``with_terms=True`` returns ``(objective, terms)``, ``terms`` the dict of
+    those auxiliary scalars, unweighted (empty for a model that has none):
+    what the round programs carry to the logging boundary."""
+    has_aux = getattr(model, "has_aux_loss", False)
+
+    def forward(fn):
+        """``(output, terms)`` of ``model.hidden`` or ``model.apply``."""
+        if has_aux:
+            return fn(params, ids, attention_mask, with_aux=True)
+        return fn(params, ids, attention_mask), {}
+
+    ce, terms = _model_ce(
+        model, forward, params, labels, label_smoothing, fused,
+        vocab_axis, real_vocab, num_valid, shift,
+    )
+    loss = ce + model.aux_loss(terms) if terms else ce
+    return (loss, terms) if with_terms else loss
+
+
+def _model_ce(model, forward, params, labels, label_smoothing, fused,
+              vocab_axis, real_vocab, num_valid, shift):
+    """``(cross-entropy, the model's auxiliary terms)``, by the resolved
+    ``fused`` form."""
     if fused == "pallas":
         from acco_tpu.ops.fused_ce import (
             fused_ce_loss,
             vocab_parallel_fused_ce_loss,
         )
 
-        h = model.hidden(params, ids, attention_mask)
+        h, terms = forward(model.hidden)
         with jax.named_scope("model/lm_head_ce"):
             head = model.lm_head(params)
             if vocab_axis is not None:
                 return vocab_parallel_fused_ce_loss(
                     h, head, labels, vocab_axis, label_smoothing,
                     shift=shift, num_valid=num_valid, real_vocab=real_vocab,
-                )
+                ), terms
             return fused_ce_loss(
                 h, head, labels, label_smoothing,
                 shift=shift, num_valid=num_valid, real_vocab=real_vocab,
-            )
+            ), terms
     if fused == "chunk":
         # The chunk form predates sharding/CP and has no shift=False,
         # num_valid, or vocab_axis plumbing; resolve_fused_loss never
@@ -457,19 +485,19 @@ def model_ce(
                 "use 'pallas' or the materialized path for "
                 "sharded/CP/vocab-padded losses"
             )
-        h = model.hidden(params, ids, attention_mask)
+        h, terms = forward(model.hidden)
         with jax.named_scope("model/lm_head_ce"):
             return chunked_causal_lm_loss(
                 h, model.lm_head(params), labels, label_smoothing
-            )
+            ), terms
     # the model's apply() names its own output projection model/lm_head_ce
-    logits = model.apply(params, ids, attention_mask)
+    logits, terms = forward(model.apply)
     with jax.named_scope("model/lm_head_ce"):
         return causal_lm_loss(
             logits, labels, label_smoothing,
             shift=shift, num_valid=num_valid, vocab_axis=vocab_axis,
             real_vocab=real_vocab,
-        )
+        ), terms
 
 
 def token_nll(

@@ -76,6 +76,7 @@ from acco_tpu.parallel.common import (
     make_valid,
     shard_layout,
     world_mean_loss,
+    world_mean_terms,
 )
 from acco_tpu.parallel.mesh import DATA_AXIS
 from acco_tpu.parallel.zero1 import (
@@ -162,6 +163,9 @@ class AccoRoundMetrics(NamedTuple):
     # consumed (0.0 when nan_guard=False compiles the signals out)
     grad_norm: jax.Array
     skipped: jax.Array  # bool: the guard suppressed this round's commit
+    # world-means of the objective's auxiliary terms, by name
+    # (ops.losses.model_ce); empty where the loss is the cross-entropy alone
+    terms: dict = {}
 
 
 class AccoTrainStep:
@@ -393,6 +397,7 @@ class AccoTrainStep:
             fused_loss=self.fused_loss,
             n_vocab_shards=self.tp,
             const_len=self.const_len_batch,
+            with_terms=True,
         )
 
     def _accumulate(self, flat_params, block, grad_init=None, count_init=None):
@@ -494,7 +499,7 @@ class AccoTrainStep:
 
         def body(state: AccoState, ids, am, labels, valid):
             block = MicrobatchBlock(ids, am, labels, valid[:, 0])
-            grad_sum, count, loss_wsum = self._accumulate(
+            grad_sum, count, loss_wsum, _ = self._accumulate(
                 state.flat_params, block
             )
             loss = world_mean_loss(loss_wsum, block.valid, DATA_AXIS, self.seq_axis)
@@ -654,7 +659,7 @@ class AccoTrainStep:
                 )
                 count0 = jnp.where(carry, state.pending_count[0], 0.0)
         block = MicrobatchBlock(ids, am, labels, valid[:, 0])
-        grad_sum, count, loss_wsum = self._accumulate(
+        grad_sum, count, loss_wsum, terms_wsum = self._accumulate(
             state.flat_params, block, grad_init=grad0, count_init=count0
         )
 
@@ -700,6 +705,9 @@ class AccoTrainStep:
             is_real_update=jnp.bool_(commit_ok),
             grad_norm=grad_norm,
             skipped=skipped,
+            terms=world_mean_terms(
+                terms_wsum, block.valid, DATA_AXIS, self.seq_axis
+            ),
         )
         return new_state, metrics
 
@@ -727,7 +735,7 @@ class AccoTrainStep:
             in_specs=(self.state_specs(),) + batch_specs(DATA_AXIS, self.seq_axis),
             out_specs=(
                 self.state_specs(),
-                AccoRoundMetrics(P(), P(), P(), P(), P(), P()),
+                AccoRoundMetrics(P(), P(), P(), P(), P(), P(), P()),
             ),
             check_vma=False,
         )

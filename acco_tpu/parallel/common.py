@@ -109,8 +109,14 @@ def make_flat_loss_fn(
     fused_loss: "bool | str" = False,  # False | 'auto' | 'chunk' | 'pallas'
     n_vocab_shards: int = 1,
     const_len: bool = False,
+    with_terms: bool = False,
 ) -> Callable[[jax.Array, dict], jax.Array]:
     """Loss as a function of the (padded) flat parameter vector.
+
+    ``with_terms``: the function returns ``(loss, terms)``, ``terms`` the
+    dict of the objective's auxiliary scalars (ops.losses.model_ce; empty
+    for a model whose objective is the cross-entropy alone): the form
+    :func:`accumulate_grads` takes.
 
     ``fused_loss``: compute the lm-head matmul + cross-entropy without
     materializing the [B, L, V] float32 logits ('pallas' composes with
@@ -181,6 +187,7 @@ def make_flat_loss_fn(
                 am, batch["labels"],
                 label_smoothing=label_smoothing, fused=fused_loss,
                 vocab_axis=vp_axis, real_vocab=real_vocab,
+                with_terms=with_terms,
             )
         # CP: pre-shifted local label chunk; this shard contributes its
         # PARTIAL — local nll sum over the psum'd global count — so the
@@ -192,23 +199,27 @@ def make_flat_loss_fn(
             model, params, batch["input_ids"], None, targets,
             label_smoothing=label_smoothing, fused=fused_loss,
             vocab_axis=vp_axis, real_vocab=real_vocab,
-            num_valid=num_valid, shift=False,
+            num_valid=num_valid, shift=False, with_terms=with_terms,
         )
 
     return loss_fn
 
 
 def accumulate_grads(
-    loss_fn: Callable[[jax.Array, dict], jax.Array],
+    loss_fn: Callable[[jax.Array, dict], tuple[jax.Array, dict]],
     flat_params: jax.Array,  # [padded] param dtype
     block: MicrobatchBlock,
     grad_init: Optional[jax.Array] = None,  # [padded] float32 carry-in
     count_init: Optional[jax.Array] = None,  # scalar float32 carry-in
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Scan the block, returning (grad_sum f32, count, loss_weighted_sum).
+) -> tuple[jax.Array, jax.Array, jax.Array, dict]:
+    """Scan the block, returning (grad_sum f32, count, loss_weighted_sum,
+    terms_weighted_sum).
 
-    ``loss_weighted_sum`` is ``sum(loss_i * valid_i)`` over this block's
-    microbatches; callers divide by the *all-reduced* valid count so masked
+    ``loss_fn`` returns ``(loss, terms)`` (make_flat_loss_fn's
+    ``with_terms=True``). ``loss_weighted_sum`` is ``sum(loss_i * valid_i)``
+    over this block's microbatches, ``terms_weighted_sum`` the same of each
+    auxiliary term; callers divide by the *all-reduced* valid count
+    (:func:`world_mean_loss`) so masked
     (heterogeneous-worker) microbatches never bias logged loss curves.
     ``grad_init``/``count_init`` express the reference's
     accumulate-on-top-of-previous-half-round behavior
@@ -222,7 +233,7 @@ def accumulate_grads(
     )
     count0 = count_init if count_init is not None else jnp.zeros((), jnp.float32)
 
-    value_and_grad = jax.value_and_grad(loss_fn)
+    value_and_grad = jax.value_and_grad(loss_fn, has_aux=True)
 
     def micro(carry, xs):
         grad_sum, count = carry
@@ -231,10 +242,13 @@ def accumulate_grads(
             "attention_mask": xs.attention_mask,
             "labels": xs.labels,
         }
-        loss, g = value_and_grad(flat_params, batch)
+        (loss, terms), g = value_and_grad(flat_params, batch)
         grad_sum = grad_sum + g.astype(jnp.float32) * xs.valid
         count = count + xs.valid
-        return (grad_sum, count), loss
+        return (grad_sum, count), (loss, terms)
+
+    def weighted(per_microbatch, valid):
+        return jax.tree.map(lambda t: (t * valid).sum(), per_microbatch)
 
     n_acc = block.valid.shape[0]
     with jax.named_scope("acco/accumulate"):
@@ -245,15 +259,21 @@ def accumulate_grads(
             # but the while op walls the body off from the round-level
             # latency-hiding scheduler, which matters for the
             # ring-collective overlap). Inline it.
-            (grad_sum, count), loss = micro(
+            (grad_sum, count), (loss, terms) = micro(
                 (grad0, count0), jax.tree.map(lambda x: x[0], block)
             )
-            return grad_sum, count, (loss * block.valid[0])
+            return (
+                grad_sum, count, loss * block.valid[0],
+                weighted(terms, block.valid[0]),
+            )
 
-        (grad_sum, count), losses = jax.lax.scan(
+        (grad_sum, count), (losses, terms) = jax.lax.scan(
             micro, (grad0, count0), block
         )
-        return grad_sum, count, (losses * block.valid).sum()
+        return (
+            grad_sum, count, (losses * block.valid).sum(),
+            weighted(terms, block.valid),
+        )
 
 
 def world_mean_loss(
@@ -274,6 +294,20 @@ def world_mean_loss(
     total_loss = jax.lax.psum(loss_weighted_sum, loss_axes)
     total_valid = jax.lax.psum(valid.sum(), axis_name)
     return total_loss / jnp.maximum(total_valid, 1.0)
+
+
+def world_mean_terms(
+    terms_weighted_sum: dict,
+    valid: jax.Array,
+    axis_name: str,
+    seq_axis: Optional[str] = None,
+) -> dict:
+    """:func:`world_mean_loss` of each of the objective's auxiliary terms
+    (accumulate_grads' fourth result); nothing for an empty dict."""
+    return jax.tree.map(
+        lambda t: world_mean_loss(t, valid, axis_name, seq_axis),
+        terms_weighted_sum,
+    )
 
 
 def prep_cp_leaves(ids, am, labels, seq_axis, mesh, model):

@@ -34,6 +34,7 @@ from acco_tpu.parallel.common import (
     make_valid,
     shard_layout,
     world_mean_loss,
+    world_mean_terms,
 )
 from acco_tpu.parallel.mesh import DATA_AXIS
 from acco_tpu.parallel.zero1 import ShardGeometry, Zero1State, init_zero1_state, zero1_update_shard
@@ -57,6 +58,9 @@ class StepMetrics(NamedTuple):
     # (0.0 when nan_guard=False compiles the signals out)
     grad_norm: jax.Array
     skipped: jax.Array  # bool: the guard suppressed this step's commit
+    # world-means of the objective's auxiliary terms, by name
+    # (ops.losses.model_ce); empty where the loss is the cross-entropy alone
+    terms: dict = {}
 
 
 class DDPTrainStep:
@@ -255,7 +259,7 @@ class DDPTrainStep:
                 make_pp_loss_fn,
             )
 
-            grad_sum, count, loss_wsum = accumulate_grads_pipelined(
+            grad_sum, count, loss_wsum, terms_wsum = accumulate_grads_pipelined(
                 make_pp_loss_fn(
                     self.model, self.tp_layout, self.pipeline_axis,
                     self.label_smoothing,
@@ -277,8 +281,9 @@ class DDPTrainStep:
                 fused_loss=self.fused_loss,
                 n_vocab_shards=self.tp,
                 const_len=self.const_len_batch,
+                with_terms=True,
             )
-            grad_sum, count, loss_wsum = accumulate_grads(
+            grad_sum, count, loss_wsum, terms_wsum = accumulate_grads(
                 loss_fn, state.flat_params, block
             )
         raw_total = lax.psum(count, DATA_AXIS)
@@ -362,6 +367,9 @@ class DDPTrainStep:
             grads_this_step=raw_total,
             grad_norm=grad_norm,
             skipped=skipped,
+            terms=world_mean_terms(
+                terms_wsum, block.valid, DATA_AXIS, self.seq_axis
+            ),
         )
         return new_state, metrics
 
@@ -378,7 +386,7 @@ class DDPTrainStep:
             self._body,
             mesh=self.mesh,
             in_specs=(self.state_specs(),) + batch_specs(DATA_AXIS, self.seq_axis),
-            out_specs=(self.state_specs(), StepMetrics(P(), P(), P(), P(), P())),
+            out_specs=(self.state_specs(), StepMetrics(P(), P(), P(), P(), P(), P())),
             check_vma=False,
         )
 

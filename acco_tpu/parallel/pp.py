@@ -243,8 +243,9 @@ def accumulate_grads_pipelined(
     """Pipelined analogue of ``common.accumulate_grads``: one
     value-and-grad over the whole block (the pipeline scan inside
     ``loss_fn`` is the accumulation loop). Returns the same
-    ``(grad_sum f32, count, loss_weighted_sum)`` triple, honoring the
-    ACCO half-round carry-ins."""
+    ``(grad_sum f32, count, loss_weighted_sum, terms_weighted_sum)``,
+    honoring the ACCO half-round carry-ins; the terms are empty (a model
+    whose objective has auxiliary terms is refused under pp)."""
 
     def wsum_loss(flat, batch):
         loss_wsum, _ = loss_fn(flat, batch)
@@ -267,4 +268,4 @@ def accumulate_grads_pipelined(
         grad_sum = grad_sum + grad_init
     if count_init is not None:
         count = count + count_init
-    return grad_sum, count, loss_wsum
+    return grad_sum, count, loss_wsum, {}

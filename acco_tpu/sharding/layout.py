@@ -33,6 +33,11 @@ def shard_layout(
     :func:`acco_tpu.sharding.tables.train_state_table`, which generates
     every PartitionSpec downstream.
     """
+    from acco_tpu.sharding.tables import refuse_experts
+
+    for axis, kind in ((tensor_axis, "tp"), (pipeline_axis, "pp"), (seq_axis, "sp")):
+        if axis is not None:
+            refuse_experts(model, kind)
     if pipeline_axis is not None:
         if not hasattr(model, "pp_param_specs"):
             raise ValueError(

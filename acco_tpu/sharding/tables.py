@@ -247,6 +247,32 @@ def model_family(model: Any) -> str:
     )
 
 
+_NOT_FOR_EXPERTS = {
+    "tp": "tensor parallelism (train.tp > 1)",
+    "pp": "pipeline parallelism (pp > 1)",
+    "sp": "context parallelism (sp > 1)",
+    "serve": "serving (serve.py: prefill / decode)",
+}
+
+
+def refuse_experts(model: Any, kind: str) -> None:
+    """A model with sparse experts trains data-parallel (dp, ZeRO-1 over the
+    flat vector) and nothing else: raise for ``kind`` ("tp" | "pp" | "sp" |
+    "serve") and name what is missing; a model without experts passes. No
+    table here holds a rule for the expert leaves, and no code path
+    dispatches tokens between chips."""
+    n_experts = getattr(model.config, "num_experts", 0)
+    if n_experts:
+        raise ShardingRuleError(
+            f"{_NOT_FOR_EXPERTS[kind]} is not supported for a model with experts "
+            f"({type(model).__name__}, num_experts={n_experts}): "
+            "acco_tpu/sharding/tables.py has no rule for the expert leaves "
+            "(layers/router [L, E, D]; layers/w_gate, w_up, w_down [L, E, ...]) "
+            "and there is no expert-parallel dispatch (all-to-all). Run it "
+            "data-parallel: train.mesh_shape={dp: N}, ZeRO-1 shards the state"
+        )
+
+
 def model_param_table(model: Any, kind: str, axis: Optional[str] = None) -> RuleTable:
     """Rule table for a model instance (family + tie inferred)."""
     tied = bool(getattr(model.config, "tie_word_embeddings", True))

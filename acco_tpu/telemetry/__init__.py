@@ -31,7 +31,9 @@ from acco_tpu.telemetry.metrics import (
 )
 from acco_tpu.telemetry.scopes import innermost_scope, scope_table
 from acco_tpu.telemetry.trace import (
+    ALL_DEVICE_SCOPES,
     DEVICE_SCOPES,
+    EXPERT_DEVICE_SCOPES,
     SPAN_NAMES,
     Tracer,
     UndeclaredSpanError,
@@ -45,7 +47,9 @@ __all__ = [
     "MetricSpec",
     "MetricsRegistry",
     "UndeclaredMetricError",
+    "ALL_DEVICE_SCOPES",
     "DEVICE_SCOPES",
+    "EXPERT_DEVICE_SCOPES",
     "SPAN_NAMES",
     "Tracer",
     "UndeclaredSpanError",

@@ -245,6 +245,15 @@ DECLARED: Tuple[MetricSpec, ...] = (
           "last boundary's global gradient norm"),
     _spec("train_grads_committed", GAUGE, "grads",
           "device-side committed-gradient counter at the last boundary"),
+    # the objective's auxiliary terms of a model with experts (models/llama.py
+    # _aux_terms): the trainer emits "train_" + each term's name
+    _spec("train_moe_lb_loss", GAUGE, "loss",
+          "last boundary's load-balancing loss, unweighted (1.0 = uniform router)"),
+    _spec("train_moe_z_loss", GAUGE, "loss",
+          "last boundary's router z-loss, unweighted"),
+    _spec("train_moe_max_load", GAUGE, "ratio",
+          "most loaded expert's share of a sequence's assignments x num_experts "
+          "(1.0 = balanced)"),
     # -- checkpointing (resilience/manager.py; bench phase keys) --
     _spec("ckpt_saves_total", COUNTER, "saves", "checkpoints started"),
     _spec("ckpt_snapshot_ms", HISTOGRAM, "ms",

@@ -1,7 +1,7 @@
 """Which device scope each instruction of a compiled program lies in.
 
 The round programs and the model wrap their phases in ``jax.named_scope``
-(:data:`~acco_tpu.telemetry.trace.DEVICE_SCOPES`); the name lands in the
+(:data:`~acco_tpu.telemetry.trace.ALL_DEVICE_SCOPES`); the name lands in the
 ``op_name`` metadata of the HLO instructions traced under it. A TPU profile
 names each op by its instruction but does not carry that metadata, so a reader
 of the profile needs a table from instruction to scope: :func:`scope_table`
@@ -17,7 +17,7 @@ import re
 from collections import Counter
 from typing import Any, Dict, List, NamedTuple
 
-from acco_tpu.telemetry.trace import DEVICE_SCOPES
+from acco_tpu.telemetry.trace import ALL_DEVICE_SCOPES
 
 # The scopes that only hold others: an op under model/block/model/mlp is
 # the MLP's, and one under both is no mix of two layers' code.
@@ -28,13 +28,13 @@ _PLUMBING = frozenset({"parameter", "get-tuple-element", "tuple", "constant", "b
 
 
 def innermost_scope(op_name: str) -> str:
-    """The scope of ``DEVICE_SCOPES`` that an instruction's ``op_name``
+    """The scope of ``ALL_DEVICE_SCOPES`` that an instruction's ``op_name``
     names last, ``""`` where it names none. Scopes nest left to right and
     transforms wrap them (``.../acco/accumulate/
     transpose(jvp(model/embed))/scatter-add``), so the last one named is
     the innermost."""
     best, at = "", -1
-    for scope in DEVICE_SCOPES:
+    for scope in ALL_DEVICE_SCOPES:
         i = op_name.rfind(scope)
         if i > at:
             best, at = scope, i

@@ -89,6 +89,19 @@ DEVICE_SCOPES = (
     "model/lm_head_ce",     # output projection + cross-entropy
 )
 
+# The scopes only an expert model's programs carry (ops/moe.py), nested
+# inside model/mlp. Apart from DEVICE_SCOPES because the benchmark holds
+# that list to the scope metrics every cell reports
+# (tests/benchmark/test_bench_hostplane.py); these three have metrics of
+# their own in the cell that runs an expert model. Every reader of scope
+# names goes by ALL_DEVICE_SCOPES.
+EXPERT_DEVICE_SCOPES = (
+    "model/moe_router",     # router matmul, softmax, top-k, auxiliary statistics
+    "model/moe_dispatch",   # sort, permutation into expert order and back, weighting
+    "model/moe_experts",    # the experts' grouped matmuls and SwiGLU
+)
+ALL_DEVICE_SCOPES = DEVICE_SCOPES + EXPERT_DEVICE_SCOPES
+
 
 # Categories whose event names are NOT closed-world (unbounded by
 # construction — e.g. pytest nodeids from the conftest recorder).

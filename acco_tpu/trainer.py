@@ -58,7 +58,7 @@ from acco_tpu.resilience import (
     ShutdownHandler,
     TrainingHealthMonitor,
 )
-from acco_tpu.telemetry import DEVICE_SCOPES, Tracer, metrics, scope_table
+from acco_tpu.telemetry import ALL_DEVICE_SCOPES, Tracer, metrics, scope_table
 from acco_tpu.utils import logs as logs_utils
 from acco_tpu.utils.checkpoint import latest_checkpoint, restore_checkpoint
 
@@ -981,6 +981,9 @@ class DecoupledTrainer:
         else:
             params = self.model.init(jax.random.PRNGKey(self.seed))
         state = step.init_state(params)
+        # the state holds its own copies: the leaves would stay on the
+        # device for the whole run (2 B a parameter: 1.2 GiB of a 626M model)
+        del params
 
         # Join the background AOT warmup (started at construction and
         # overlapped with tokenize / loader setup / state init above):
@@ -1293,23 +1296,29 @@ class DecoupledTrainer:
                 ) as fence:
                     t_sync = time.perf_counter()
                     (
-                        committed, health_host, loss_host, grad_norm_host
+                        committed, health_host, loss_host, grad_norm_host,
+                        terms_host,
                     ) = jax.device_get(  # lint: host-sync-ok
                         (
                             state.zero1.grads_committed,
                             state.health,
                             last_metrics.loss,
                             last_metrics.grad_norm,
+                            last_metrics.terms,
                         )
                     )
                     sync_ms = (time.perf_counter() - t_sync) * 1e3
                     final_loss = float(loss_host)
                     grad_norm = float(grad_norm_host)
+                    # the objective's auxiliary terms, by the model's names
+                    # (none for a model whose loss is the cross-entropy)
+                    loss_terms = {k: float(v) for k, v in terms_host.items()}
                     fence.update(
                         loss=final_loss,
                         grad_norm=grad_norm,
                         committed=float(committed),
                         skipped_rounds=int(health_host.skipped_rounds),
+                        **loss_terms,
                     )
                 # From the fence's return to the point where the loop goes
                 # back for the next block: the host work of the boundary.
@@ -1318,6 +1327,8 @@ class DecoupledTrainer:
                     count_grad_tot = float(committed)
                     metrics.emit("train_loss", final_loss)
                     metrics.emit("train_grads_committed", float(committed))
+                    for name, value in loss_terms.items():
+                        metrics.emit("train_" + name, value)
                     log_epoch, t_last_epoch = logs_utils.print_training_evolution(
                         self.log,
                         nb_grad_local,
@@ -1564,7 +1575,7 @@ class DecoupledTrainer:
                             profile_dir if profiled_rounds else None
                         ),
                         "profiled_rounds": profiled_rounds,
-                        "device_scopes": list(DEVICE_SCOPES),
+                        "device_scopes": list(ALL_DEVICE_SCOPES),
                         # the capture names an op by its instruction, not
                         # by its scope: {program: {instruction: scope}},
                         # and the program each captured round ran

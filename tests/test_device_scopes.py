@@ -1,6 +1,7 @@
 """Device scopes (ISSUE 23): every name of ``DEVICE_SCOPES`` is on the ops of
-the round programs the benchmark's cells run, and a scope is metadata only:
-the lowered computation is the same with and without it."""
+the round programs the benchmark's cells run (GPT-Neo's; ``EXPERT_DEVICE_SCOPES``
+on an expert model's, ISSUE 25), and a scope is metadata only: the
+lowered computation is the same with and without it."""
 
 from __future__ import annotations
 
@@ -12,26 +13,38 @@ import jax.numpy as jnp
 import pytest
 
 from acco_tpu.models.gpt_neo import GPTNeoConfig, GPTNeoModel
+from acco_tpu.models.llama import LlamaConfig, LlamaModel
 from acco_tpu.ops.schedules import get_schedule
 from acco_tpu.parallel.acco import AccoTrainStep
 from acco_tpu.parallel.ddp import DDPTrainStep
 from acco_tpu.parallel.mesh import make_mesh
-from acco_tpu.telemetry import DEVICE_SCOPES
+from acco_tpu.telemetry import ALL_DEVICE_SCOPES, DEVICE_SCOPES, EXPERT_DEVICE_SCOPES
 
 CFG = GPTNeoConfig(
     vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
     max_position_embeddings=32, window_size=8,
     attention_layers=["global", "local"],
 )
+EXPERTS = LlamaConfig(
+    vocab_size=64, hidden_size=32, intermediate_size=16, num_layers=2, num_heads=2,
+    num_kv_heads=2, max_position_embeddings=32, tie_word_embeddings=False, qk_norm=True,
+    num_experts=4, num_experts_per_tok=2, router_aux_loss_coef=0.01, router_z_loss_coef=0.001,
+)
 SEQ, PER_DEVICE = 16, 2
+MOE_SCOPES = EXPERT_DEVICE_SCOPES
 
 
-def _lower(kind: str):
+def _lower(kind: str, experts: bool = False):
     """The tiny round program of ``kind``, lowered for the CPU mesh: ACCO's
     even (speculative) round or DDP's step, manual ring, guard on: the
-    programs of the benchmark's cells."""
+    programs of the benchmark's cells. ``experts``: of the expert model
+    (``LlamaModel`` with OLMoE's block) in place of GPT-Neo."""
     mesh = make_mesh()
-    model = GPTNeoModel(CFG, param_dtype=jnp.bfloat16)
+    model = (
+        LlamaModel(EXPERTS, param_dtype=jnp.bfloat16)
+        if experts
+        else GPTNeoModel(CFG, param_dtype=jnp.bfloat16)
+    )
     kwargs = dict(
         weight_decay=0.1, beta1=0.9, beta2=0.95, label_smoothing=0.0,
         param_dtype=jnp.bfloat16, comm_impl="ring",
@@ -61,25 +74,42 @@ def _lower(kind: str):
 @pytest.fixture(scope="module")
 def op_names(eight_devices):
     """``{program: set of op_name metadata strings of its compiled HLO}``."""
+    def names(lowered):
+        return set(re.findall(r'op_name="([^"]+)"', lowered.compile().as_text()))
+
     return {
-        kind: set(
-            re.findall(r'op_name="([^"]+)"', _lower(kind).compile().as_text())
-        )
-        for kind in ("acco_even", "ddp")
+        "acco_even": names(_lower("acco_even")),
+        "ddp": names(_lower("ddp")),
+        "experts_acco_even": names(_lower("acco_even", experts=True)),
     }
+
+
+def _carries(names, scope) -> bool:
+    return any(f"/{scope}/" in f"/{n}/" or f"({scope})" in n for n in names)
 
 
 @pytest.mark.parametrize("kind", ["acco_even", "ddp"])
 @pytest.mark.parametrize("scope", DEVICE_SCOPES)
 def test_every_device_scope_names_ops_of_the_round_program(op_names, scope, kind):
-    hits = [n for n in op_names[kind] if f"/{scope}/" in f"/{n}/" or f"({scope})" in n]
-    assert hits, f"no op of the {kind} program carries {scope!r}"
+    assert _carries(op_names[kind], scope), f"no op of the {kind} program carries {scope!r}"
+
+
+@pytest.mark.parametrize("scope", [s for s in ALL_DEVICE_SCOPES if s.startswith("model/")])
+def test_every_model_scope_names_ops_of_an_expert_models_round(op_names, scope):
+    """The Llama path carries the ``model/*`` scopes GPT-Neo has, and an
+    expert block the three ``model/moe_*`` scopes inside ``model/mlp``."""
+    assert len(MOE_SCOPES) == 3
+    assert _carries(op_names["experts_acco_even"], scope), scope
+    if scope in MOE_SCOPES:
+        assert not _carries(op_names["acco_even"], scope)
+        inside = [n for n in op_names["experts_acco_even"] if scope in n]
+        assert inside and all("model/mlp/" + scope in n for n in inside)
 
 
 def test_the_backward_pass_carries_the_forward_scopes_name(op_names):
     """A reader selects forward and backward with one name: JAX wraps the
     scope in the transform, ``transpose(jvp(acco/flat_unpack))``."""
-    for names in op_names.values():
+    for kind, names in op_names.items():
         assert any("transpose(jvp(acco/flat_unpack))" in n for n in names)
         assert any("transpose(jvp(model/lm_head_ce))" in n for n in names)
         assert any(
@@ -93,12 +123,19 @@ def test_the_backward_pass_carries_the_forward_scopes_name(op_names):
 
 def test_innermost_scope_is_the_last_one_named(op_names):
     """What lets a regex per scope partition the ops: only acco/accumulate
-    and model/block have scopes nested in them."""
-    outer = {"acco/accumulate", "model/block"}
-    for names in op_names.values():
+    and model/block have scopes nested in them, and in an expert block
+    model/mlp, which holds the three model/moe_* scopes."""
+    for kind, names in op_names.items():
+        outer = {"acco/accumulate", "model/block"}
+        if kind.startswith("experts"):
+            outer.add("model/mlp")
         for n in names:
-            found = [s for s in DEVICE_SCOPES if s in n and s not in outer]
+            found = [s for s in ALL_DEVICE_SCOPES if s in n and s not in outer]
             assert len(found) <= 1, n
+            if len(found) == 1 and kind.startswith("experts"):
+                from acco_tpu.telemetry.scopes import innermost_scope
+
+                assert innermost_scope(n) == found[0]
 
 
 @pytest.mark.parametrize("kind", ["acco_even", "acco_odd", "ddp"])
@@ -216,5 +253,7 @@ def test_scope_table_of_a_compiled_round_program(eight_devices):
     table = scope_table(_lower("ddp").compile().as_text())
     owners = set(table["scopes"].values())
     assert owners == set(DEVICE_SCOPES)
+    experts = scope_table(_lower("ddp", experts=True).compile().as_text())
+    assert set(experts["scopes"].values()) == set(ALL_DEVICE_SCOPES)
     for fusion, mix in table["mixed"].items():
         assert len(mix) > 1 and not {"acco/accumulate", "model/block"} & set(mix)

@@ -180,6 +180,51 @@ def _llama_fused_remat(remat):
     return _program(loss, 0, [params, ((2, 128), jnp.int32)])
 
 
+def _moe_experts(shape):
+    """The experts' half of a block (ops/moe.py): dispatch, the three
+    grouped matmuls through the megablox kernel, combine."""
+    import jax.numpy as jnp
+
+    from acco_tpu.ops.moe import dropless_experts
+
+    T, K, E, D, F = shape
+
+    def loss(h, gates, w_gate, w_up, w_down, experts):
+        out = dropless_experts(h, gates, experts, w_gate, w_up, w_down, platform="tpu")
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    return _program(
+        loss, (0, 1, 2, 3, 4),
+        [((T, D), jnp.bfloat16), ((T, K), jnp.float32), ((E, D, F), jnp.bfloat16),
+         ((E, D, F), jnp.bfloat16), ((E, F, D), jnp.bfloat16), ((T, K), jnp.int32)],
+    )
+
+
+def _olmoe_layer(remat):
+    """One OLMoE layer at the published widths and 4096 positions (a small
+    vocabulary: the head is not the point), as the benchmark's cell runs it:
+    stock flash attention, QK-norm, 64 experts top-8."""
+    import jax
+    import jax.numpy as jnp
+
+    from acco_tpu.models.llama import LlamaConfig, LlamaModel
+
+    model = LlamaModel(
+        LlamaConfig(
+            vocab_size=512, hidden_size=2048, num_layers=1, num_heads=16, num_kv_heads=16,
+            intermediate_size=1024, max_position_embeddings=4096, tie_word_embeddings=False,
+            qk_norm=True, num_experts=64, num_experts_per_tok=8,
+        ),
+        param_dtype=jnp.bfloat16, remat=remat, attention="auto", platform="tpu",
+    )
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
+
+    def loss(params, ids):
+        return jnp.mean(model.apply(params, ids).astype(jnp.float32) ** 2)
+
+    return _program(loss, 0, [params, ((1, 4096), jnp.int32)])
+
+
 # -- cases: name -> (builder, args, kwargs, what the result must satisfy)
 #   mosaic: exact number of Mosaic custom calls, or None for "at least one"
 #   absent: a buffer that must not exist in the executable
@@ -228,6 +273,15 @@ CASES = {
     # policy lost the names and every layer's forward kernel runs twice.
     "llama_fused_remat_dots": (_llama_fused_remat, ("dots",), {}, dict(mosaic=2)),
     "llama_fused_remat_off": (_llama_fused_remat, (False,), {}, dict(mosaic=2)),
+    # sparse experts (ops/moe.py), [tokens, experts a token, experts, hidden, width]:
+    # three grouped matmuls forward, two gradient matmuls each backward
+    "moe_experts_olmoe": (_moe_experts, ((4096, 8, 64, 2048, 1024),), {}, dict(mosaic=9)),
+    # the 'dots' policy saves the grouped matmuls' named outputs (moe_gate,
+    # moe_up, moe_down): 9 grouped-matmul kernels and, for the stock flash
+    # kernel whose outputs carry no name, forward twice and its two backward
+    # kernels. Full remat runs the three forward grouped matmuls again.
+    "olmoe_layer_remat_dots": (_olmoe_layer, ("dots",), {}, dict(mosaic=13)),
+    "olmoe_layer_remat_full": (_olmoe_layer, (True,), {}, dict(mosaic=16)),
 }
 
 

@@ -111,7 +111,7 @@ def test_acco_count_bookkeeping(eight_devices, tmp_path):
     import glob
     import json
 
-    from acco_tpu.telemetry import DEVICE_SCOPES, validate_trace
+    from acco_tpu.telemetry import ALL_DEVICE_SCOPES, validate_trace
 
     paths = glob.glob(str(tmp_path / "trace_*.json"))
     assert len(paths) == 1, paths
@@ -142,7 +142,7 @@ def test_acco_count_bookkeeping(eight_devices, tmp_path):
     # what a reader of the profile needs, named in the trace; no capture
     # ran here, so no directory
     other = trace["otherData"]
-    assert other["device_scopes"] == list(DEVICE_SCOPES)
+    assert other["device_scopes"] == list(ALL_DEVICE_SCOPES)
     assert other["profile_dir"] is None and other["profiled_rounds"] is None
     assert "attribution" not in other and "attribution" not in summary
 

@@ -102,8 +102,8 @@ def build(model_json: str, n_devices: int, dp: int, tp: int, seq: int, bs: int,
     import dataclasses
     import json as _json
 
-    from acco_tpu.models.gpt_neo import GPTNeoConfig, GPTNeoModel
-    from acco_tpu.models.registry import _PRESETS
+    from acco_tpu.models.gpt_neo import GPTNeoConfig
+    from acco_tpu.models.registry import _MODEL_TYPES, _PRESETS
 
     tensor_axis = "tp" if tp > 1 else None
     pipeline_axis = "pp" if pp > 1 else None
@@ -114,11 +114,7 @@ def build(model_json: str, n_devices: int, dp: int, tp: int, seq: int, bs: int,
     else:
         with open(model_json) as f:
             mtype = _json.load(f).get("model_type", "gpt_neo")
-        cfg_cls, model_cls = (
-            (LlamaConfig, LlamaModel)
-            if mtype == "llama"
-            else (GPTNeoConfig, GPTNeoModel)
-        )
+        cfg_cls, model_cls = _MODEL_TYPES[mtype]
         cfg = cfg_cls.from_json(model_json)
     if seq > cfg.max_position_embeddings:
         cfg = dataclasses.replace(cfg, max_position_embeddings=seq)
