@@ -558,6 +558,25 @@ class AccoTrainStep:
         total = jnp.maximum(raw_total, 1.0)
         with jax.named_scope("acco/optimizer"):
             lr = self.schedule(state.zero1.sched_grads)
+        # Speculative rollback, functionally: keep the old optimizer state
+        # on even rounds (reference's snapshot/restore, :79-84,113-126).
+        commit = (
+            not speculative
+            if isinstance(speculative, bool)
+            else jnp.logical_not(speculative)
+        )
+        # In-program anomaly guard (nan_guard): an unhealthy update
+        # (nonfinite or over-threshold grads, nonfinite new params) is a
+        # bit-exact no-op — the working params stay put on EVERY parity (a
+        # poisoned θ̃ would send the next half-round's compute off a
+        # cliff before any host-side check could even see it — the
+        # speculative half-step of the ISSUE's motivation), and the
+        # optimizer commit additionally requires health.
+        # zero1_update_shard applies both to what it returns (a committing
+        # round pays one read-only pass over the shard for the verdict; a
+        # speculative one a select over the bf16 shard); the scalars
+        # below follow the same predicate. nan_guard=False compiles the
+        # guard out entirely.
         upd = zero1_update_shard(
             state.pending_grads,
             state.zero1.opt,
@@ -581,38 +600,19 @@ class AccoTrainStep:
             ),
             with_health=self.nan_guard,
             max_grad_norm=self.guard_max_grad_norm,
+            commit=commit,
+            old_flat=state.flat_params,
         )
         if self.nan_guard:
-            new_flat, new_opt, uh = upd
+            new_flat, opt_out, uh = upd
             ok, grad_norm = uh.ok, uh.grad_norm
-        else:
-            new_flat, new_opt = upd
-            ok, grad_norm = None, jnp.float32(0.0)
-        # Speculative rollback, functionally: keep the old optimizer state
-        # on even rounds (reference's snapshot/restore, :79-84,113-126).
-        commit = (
-            not speculative
-            if isinstance(speculative, bool)
-            else jnp.logical_not(speculative)
-        )
-        # In-program anomaly guard: an unhealthy update (nonfinite or
-        # over-threshold grads, nonfinite new params) is a bit-exact
-        # no-op — the working params stay put on EVERY parity (a
-        # poisoned θ̃ would send the next half-round's compute off a
-        # cliff before any host-side check could even see it — the
-        # speculative half-step of the ISSUE's motivation), and the
-        # optimizer commit additionally requires health. These selects
-        # are traced (ok is data), so they cost one pass over the flat
-        # vectors — the measured guard overhead; nan_guard=False
-        # compiles them out entirely.
-        if ok is not None:
-            with jax.named_scope("acco/guard"):
-                new_flat = jnp.where(ok, new_flat, state.flat_params)
             if isinstance(commit, bool):
                 commit_ok = ok if commit else False
             else:
                 commit_ok = jnp.logical_and(commit, ok)
         else:
+            new_flat, opt_out = upd
+            ok, grad_norm = None, jnp.float32(0.0)
             commit_ok = commit
         # the selects below are the guard's where ok is data, and the
         # speculative/commit selects of a parity-generic program otherwise
@@ -622,11 +622,6 @@ class AccoTrainStep:
             return jax.named_scope("acco/cast")
 
         with select_scope():
-            opt_out = jax.tree.map(
-                lambda new, old: sel(commit_ok, new, old),
-                new_opt,
-                state.zero1.opt,
-            )
             sched_inc = (
                 total.astype(jnp.int32) if self.lr_grad_accounting else 1
             )

@@ -316,6 +316,7 @@ class DDPTrainStep:
             ),
             with_health=self.nan_guard,
             max_grad_norm=self.guard_max_grad_norm,
+            old_flat=state.flat_params,
         )
         loss_out = world_mean_loss(
             loss_wsum, block.valid, DATA_AXIS, self.seq_axis
@@ -323,19 +324,14 @@ class DDPTrainStep:
         if self.nan_guard:
             # In-program anomaly guard: an unhealthy update (nonfinite
             # or over-threshold grads, nonfinite new params) commits
-            # NOTHING — params, opt moments, Adam step count, the LR
-            # schedule, and the committed-grads counter are all the old
+            # NOTHING — params, opt moments, Adam step count
+            # (zero1_update_shard returns the old ones), the LR schedule
+            # and the committed-grads counter (here) are all the old
             # values, bit-exactly, selected on-device with no host sync.
             new_flat, new_opt, uh = upd
             ok, grad_norm = uh.ok, uh.grad_norm
             skipped = jnp.logical_not(ok)
             with jax.named_scope("acco/guard"):
-                new_flat = jnp.where(ok, new_flat, state.flat_params)
-                new_opt = jax.tree.map(
-                    lambda new, old: jnp.where(ok, new, old),
-                    new_opt,
-                    state.zero1.opt,
-                )
                 sched_inc = jnp.where(ok, sched_inc, 0)
                 committed_inc = jnp.where(ok, raw_total, 0.0)
             health_out = HealthState(

@@ -69,9 +69,10 @@ class UpdateHealth(NamedTuple):
     - ``ok`` bool scalar, replicated — the update is safe to commit:
       the count-averaged global gradient and the updated parameter
       shard are both finite, and (when a cap is set) the global grad
-      norm is under it. The round programs guard their commit on this:
-      ``jnp.where(ok, new, old)`` makes an anomalous round a bit-exact
-      on-device no-op with no host involvement.
+      norm is under it. ``zero1_update_shard`` has already applied it to
+      what it returns (an anomalous update is a bit-exact on-device no-op
+      with no host involvement); the round programs gate their scalar
+      counters on it.
     - ``grad_norm`` float32 scalar, replicated — global L2 norm of the
       count-averaged gradient (the host monitor's spike/drift signal,
       already fetched lazily with the round metrics).
@@ -132,6 +133,21 @@ def flat_shard_index(axis_name) -> jax.Array:
     return idx
 
 
+def _own_shard(flat: jax.Array, shard_index, shard_size: int) -> jax.Array:
+    """This device's ``[shard_size]`` slice of a local ``[n * shard_size]``
+    vector, in the tiled order all_gather writes. Addressed in the vector's
+    own 1-D layout (a ``[n, S]`` view would re-tile it); past int32 element
+    offsets, by row."""
+    n = flat.shape[0] // shard_size
+    if n == 1:
+        return flat
+    if flat.shape[0] < 2**31:
+        return lax.dynamic_slice(flat, (shard_index * shard_size,), (shard_size,))
+    return lax.dynamic_index_in_dim(
+        flat.reshape(n, shard_size), shard_index, keepdims=False
+    )
+
+
 def zero1_update_shard(
     flat_grads_local: jax.Array,  # [padded_size] per-device UNREDUCED grad sum
     opt_shard: AdamWState,  # local [S] view inside shard_map
@@ -151,11 +167,13 @@ def zero1_update_shard(
     inner_axis: str | None = None,
     with_health: bool = False,
     max_grad_norm: float = 0.0,
+    commit=True,
+    old_flat: jax.Array | None = None,
 ) -> tuple:
-    """One sharded AdamW step. MUST run inside shard_map over ``axis_name``
-    (a mesh axis or an axis tuple — with context parallelism the optimizer
-    shards over (dp, sp) jointly, and the psum in the scatter is also what
-    sums the sequence shards' partial gradients).
+    """One sharded AdamW step, committed. MUST run inside shard_map over
+    ``axis_name`` (a mesh axis or an axis tuple — with context parallelism
+    the optimizer shards over (dp, sp) jointly, and the psum in the scatter
+    is also what sums the sequence shards' partial gradients).
 
     reduce-scatter(SUM) -> average by grad count -> AdamW on the fp32 shard
     -> all-gather updated params: the exact collective sequence of
@@ -176,24 +194,49 @@ def zero1_update_shard(
     (first ``n_repl`` flat positions) additionally psums over tp, making
     its update identical on every tp shard.
 
-    Health guard (``with_health=True``): additionally returns an
-    :class:`UpdateHealth` third element. The signals are computed from
-    data the update already materializes — the averaged gradient shard's
-    sum of squares and the updated fp32 parameter shard's — combined in
-    ONE extra [2]-element psum over the shard axes (plus the tp axis when
-    set), so the guard adds no host sync and negligible device time.
+    ``commit`` (Python bool, or traced in a parity-generic round): whether
+    the optimizer state takes this update — ACCO's speculative rounds pass
+    False and keep the old state (the reference's snapshot/restore,
+    `trainer_decoupled.py:79-84,113-126`); the returned flat vector is the
+    updated one on every parity.
+
+    Health guard (``with_health=True``, needs ``old_flat``: the local
+    ``[padded_size]`` working vector the round started from): additionally
+    returns an :class:`UpdateHealth`, and an anomalous update commits
+    nothing — state AND flat vector are the old ones, bit-exactly. The
+    verdict is the averaged gradient shard's sum of squares and the
+    updated fp32 parameter shard's, combined in ONE [2]-element psum over
+    the shard axes (plus the tp axis when set): no host sync.
     ``max_grad_norm > 0`` also flags finite-but-spiked gradients whose
     global L2 norm exceeds the cap (a static compile-time threshold; the
     adaptive spike/drift classification lives on the host,
-    resilience/watchdog.py). The caller owns applying the verdict
-    (``jnp.where(ok, new, old)``): this function always computes the
-    tentative update.
+    resilience/watchdog.py). The verdict reduces over the *updated*
+    parameters, so nothing may be overwritten before a whole pass has
+    finished. What that costs, per shard of S elements:
 
-    Returns ``(new_flat_params [padded_size] in out_dtype, new opt
-    shard)``, plus the :class:`UpdateHealth` when ``with_health``.
+    - a committing round decides first and writes once: a read-only pass
+      computes the update in registers and emits the two sums (16S bytes
+      read); one write pass, the taken branch of a ``lax.cond`` on the
+      verdict, then recomputes it and writes ``p, mu, nu`` and the
+      ``out_dtype`` shard (16S read, 14S written); the other branch hands
+      back the old state and this shard of the old vector. No tentative
+      copy of the state exists.
+    - a speculative round (static ``commit=False``) writes no state, so the
+      tentative ``out_dtype`` shard and one select over it are the cheaper
+      shape (16S + 2S, then 6S).
+
+    Either way the all-gather carries a shard that is already the answer:
+    the gathered slices of the old vector ARE the old vector, on ACCO's
+    odd round too, where it is the speculative one and not ``cast(p)``.
+
+    Returns ``(flat_params [padded_size] in out_dtype, opt shard)``, plus
+    the :class:`UpdateHealth` when ``with_health``. The caller gates its
+    own scalars (LR schedule, committed-grads counter) on ``commit & ok``.
     """
     if comm_impl not in ("xla", "ring"):
         raise ValueError(f"comm_impl must be 'xla' or 'ring', got {comm_impl!r}")
+    if with_health and old_flat is None:
+        raise ValueError("with_health=True needs old_flat: a skipped update returns it")
     use_ring = comm_impl == "ring" and isinstance(axis_name, str)
     if use_ring:
         from acco_tpu.parallel.ring_collectives import (
@@ -205,70 +248,71 @@ def zero1_update_shard(
     # included, so a profile can tell the wire from the vector passes.
     with jax.named_scope("acco/reduce_scatter"):
         if use_ring:
-            grad_shard = ring_reduce_scatter(
+            grad_sum = ring_reduce_scatter(
                 flat_grads_local.astype(jnp.float32), axis_name
             )
         else:
-            grad_shard = lax.psum_scatter(
+            grad_sum = lax.psum_scatter(
                 flat_grads_local.astype(jnp.float32), axis_name, tiled=True
             )
+    idx = flat_shard_index(axis_name)
     with jax.named_scope("acco/optimizer"):
         divisor = grad_divisor.astype(jnp.float32)
         if tp_axis is not None:
             tp = lax.axis_size(tp_axis)  # axis tuples: product (pp x tp)
             divisor = divisor * tp
-        grad_shard = grad_shard / divisor
+        # replicated-prefix position ranges [lo, hi) of the flat vector and
+        # the model axes each is replicated over.
+        # Single model axis: one prefix [0:n_repl) psum'd over tp_axis.
+        # Composed pp x tp (ComposedLayout): the prefix splits in two —
+        # [0:n_repl_both) is replicated on BOTH axes (final norms, psum
+        # over the full tuple), [n_repl_both:n_repl) is outer-split but
+        # inner-replicated (per-stage norm scales, psum over inner only).
+        repl = []
         if tp_axis is not None and n_repl > 0:
-            # replicated-prefix positions held by this dp(x sp) shard.
-            # Single model axis: one prefix [0:n_repl) psum'd over tp_axis.
-            # Composed pp x tp (ComposedLayout): the prefix splits in two —
-            # [0:n_repl_both) is replicated on BOTH axes (final norms, psum
-            # over the full tuple), [n_repl_both:n_repl) is outer-split but
-            # inner-replicated (per-stage norm scales, psum over inner only).
-            idx = flat_shard_index(axis_name)
-            repl_mask = _boundary_mask(idx, geom.shard_size, n_repl).astype(bool)
             if inner_axis is None or n_repl_both >= n_repl:
-                synced = lax.psum(jnp.where(repl_mask, grad_shard, 0.0), tp_axis)
-                grad_shard = jnp.where(repl_mask, synced, grad_shard)
+                repl = [(0, n_repl, tp_axis)]
             else:
-                both_mask = _boundary_mask(
-                    idx, geom.shard_size, n_repl_both
-                ).astype(bool)
-                inner_mask = repl_mask & ~both_mask
-                synced_both = lax.psum(
-                    jnp.where(both_mask, grad_shard, 0.0), tp_axis
-                )
-                synced_inner = lax.psum(
-                    jnp.where(inner_mask, grad_shard, 0.0), inner_axis
-                )
-                grad_shard = jnp.where(
-                    both_mask, synced_both,
-                    jnp.where(inner_mask, synced_inner, grad_shard),
-                )
-        pad_mask = geom.shard_pad_mask(flat_shard_index(axis_name))
-        new_opt = adamw_shard_update(
-            opt_shard,
+                repl = [(0, n_repl_both, tp_axis), (n_repl_both, n_repl, inner_axis)]
+
+    def repl_masks():
+        """``repl``'s ranges as [S] bool masks of this dp(x sp) shard. Built
+        where they are used: closed over by a ``cond`` branch, a mask would
+        be an operand, i.e. a buffer."""
+        def below(boundary):
+            return _boundary_mask(idx, geom.shard_size, boundary).astype(bool)
+
+        return [below(hi) & ~below(lo) if lo else below(hi) for lo, hi, _ in repl]
+
+    with jax.named_scope("acco/optimizer"):
+        synced = tuple(
+            lax.psum(jnp.where(mask, grad_sum / divisor, 0.0), axes)
+            for mask, (_, _, axes) in zip(repl_masks(), repl)
+        )
+
+    def averaged(grad_sum, synced):
+        """The count-averaged gradient shard, replicated prefix synced:
+        elementwise in its arguments, so each pass that needs it fuses it."""
+        g = grad_sum / divisor
+        for mask, s in reversed(list(zip(repl_masks(), synced))):
+            g = jnp.where(mask, s, g)
+        return g
+
+    def update(grad_shard, opt):
+        return adamw_shard_update(
+            opt,
             grad_shard,
             lr=lr,
             weight_decay=weight_decay,
             beta1=beta1,
             beta2=beta2,
             eps=eps,
-            pad_mask=pad_mask,
+            pad_mask=geom.shard_pad_mask(idx),
         )
-    with jax.named_scope("acco/cast"):
-        new_shard = new_opt.params.astype(out_dtype)
-    with jax.named_scope("acco/all_gather"):
-        if use_ring:
-            new_flat = ring_all_gather(new_shard, axis_name)
-        else:
-            new_flat = lax.all_gather(new_shard, axis_name, tiled=True)
-    if not with_health:
-        return new_flat, new_opt
-    with jax.named_scope("acco/guard"):
-        # Health signals, from buffers this update already touched: the
-        # shards partition the flat vector, so psum'ing per-shard sums of
-        # squares yields the global quantities. NaN/inf propagate through
+
+    def verdict(grad_shard, new_params) -> UpdateHealth:
+        # The shards partition the flat vector, so psum'ing per-shard sums
+        # of squares yields the global quantities. NaN/inf propagate through
         # square+sum+psum, so a single nonfinite element anywhere in the
         # global gradient or updated parameters makes its total nonfinite.
         # Pad positions are excluded with where() (a multiply would keep
@@ -276,42 +320,90 @@ def zero1_update_shard(
         # place a structural nonfinite is harmless). One [2] psum — under
         # tp each tp group's local vector is a disjoint piece of the model
         # EXCEPT the replicated prefix, whose squared contribution is
-        # pre-divided by its replication factor (it appears on every tp
-        # shard, mirroring the sync above: [0:n_repl_both) on the full
-        # tuple, [n_repl_both:n_repl) on inner only) so the psum counts
-        # every element exactly once and grad_norm matches the
-        # single-device value. The division keeps NaN/inf propagation
-        # intact (nonfinite/k is nonfinite).
-        real = pad_mask > 0
+        # pre-divided by its replication factor (it appears on every shard
+        # of the axes it was synced over above) so the psum counts every
+        # element exactly once and grad_norm matches the single-device
+        # value. The division keeps NaN/inf propagation intact
+        # (nonfinite/k is nonfinite).
+        real = geom.shard_pad_mask(idx) > 0
         grad_ss_v = jnp.square(jnp.where(real, grad_shard, 0.0))
-        param_ss_v = jnp.square(jnp.where(real, new_opt.params, 0.0))
-        if tp_axis is not None and n_repl > 0:
-            idx = flat_shard_index(axis_name)
-            repl_mask = _boundary_mask(idx, geom.shard_size, n_repl).astype(bool)
-            tp_size = jnp.float32(lax.axis_size(tp_axis))
-            if inner_axis is None or n_repl_both >= n_repl:
-                inv_repl = jnp.where(repl_mask, 1.0 / tp_size, 1.0)
-            else:
-                both_mask = _boundary_mask(
-                    idx, geom.shard_size, n_repl_both
-                ).astype(bool)
-                inner_size = jnp.float32(lax.axis_size(inner_axis))
+        param_ss_v = jnp.square(jnp.where(real, new_params, 0.0))
+        if repl:
+            inv_repl = 1.0
+            for mask, (_, _, axes) in reversed(list(zip(repl_masks(), repl))):
                 inv_repl = jnp.where(
-                    both_mask, 1.0 / tp_size,
-                    jnp.where(repl_mask & ~both_mask, 1.0 / inner_size, 1.0),
+                    mask, 1.0 / jnp.float32(lax.axis_size(axes)), inv_repl
                 )
             grad_ss_v = grad_ss_v * inv_repl
             param_ss_v = param_ss_v * inv_repl
-        grad_ss = jnp.sum(grad_ss_v)
-        param_ss = jnp.sum(param_ss_v)
         axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
         if tp_axis is not None:
             axes = axes + (
                 (tp_axis,) if isinstance(tp_axis, str) else tuple(tp_axis)
             )
-        totals = lax.psum(jnp.stack([grad_ss, param_ss]), axes)
-        grad_norm = jnp.sqrt(totals[0])
+        totals = lax.psum(
+            jnp.stack([jnp.sum(grad_ss_v), jnp.sum(param_ss_v)]), axes
+        )
         ok = jnp.isfinite(totals[0]) & jnp.isfinite(totals[1])
         if max_grad_norm and max_grad_norm > 0:
             ok = ok & (totals[0] <= jnp.float32(max_grad_norm) ** 2)
-    return new_flat, new_opt, UpdateHealth(ok=ok, grad_norm=grad_norm)
+        return UpdateHealth(ok=ok, grad_norm=jnp.sqrt(totals[0]))
+
+    def gather(shard):
+        with jax.named_scope("acco/all_gather"):
+            if use_ring:
+                return ring_all_gather(shard, axis_name)
+            return lax.all_gather(shard, axis_name, tiled=True)
+
+    def select(pred, new, old):
+        """Per-leaf commit select; a Python bool picks at trace time."""
+        if isinstance(pred, bool):
+            return new if pred else old
+        return jax.tree.map(lambda n, o: jnp.where(pred, n, o), new, old)
+
+    if not with_health:
+        with jax.named_scope("acco/optimizer"):
+            new_opt = update(averaged(grad_sum, synced), opt_shard)
+        with jax.named_scope("acco/cast"):
+            new_shard = new_opt.params.astype(out_dtype)
+            # a parity-generic round's speculative/commit selects
+            opt_out = select(commit, new_opt, opt_shard)
+        return gather(new_shard), opt_out
+
+    old_shard = _own_shard(old_flat, idx, geom.shard_size)
+    if isinstance(commit, bool) and not commit:
+        # static: a parity-specialized speculative round writes no state
+        with jax.named_scope("acco/optimizer"):
+            grad_shard = averaged(grad_sum, synced)
+            new_params = update(grad_shard, opt_shard).params
+        with jax.named_scope("acco/cast"):
+            new_shard = new_params.astype(out_dtype)
+        with jax.named_scope("acco/guard"):
+            health = verdict(grad_shard, new_params)
+            new_shard = jnp.where(health.ok, new_shard, old_shard)
+        return gather(new_shard), opt_shard, health
+
+    with jax.named_scope("acco/guard"):
+        grad_shard = averaged(grad_sum, synced)
+        health = verdict(grad_shard, update(grad_shard, opt_shard).params)
+
+    # The write pass is the taken branch of a conditional on the scalar
+    # verdict, not a select per leaf: compiled for the chip, the branch is
+    # the unguarded update's own single fusion (no select, the old vector
+    # not even read) and the other branch forwards its operands with no
+    # copy, where XLA split the bf16 select off a where()-gated write. A
+    # branch's operands are buffers: a gradient that arrives as a fused
+    # expression (DDP's concatenation, the ring's two halves) is written
+    # out once by the verdict pass.
+    def write(grad_sum, synced, opt, old_shard):
+        new = update(averaged(grad_sum, synced), opt)
+        return select(commit, new, opt), new.params.astype(out_dtype)
+
+    def keep(grad_sum, synced, opt, old_shard):
+        return opt, old_shard
+
+    with jax.named_scope("acco/optimizer"):
+        opt_out, new_shard = lax.cond(
+            health.ok, write, keep, grad_sum, synced, opt_shard, old_shard
+        )
+    return gather(new_shard), opt_out, health

@@ -100,7 +100,7 @@ def scope_table(hlo_text: str) -> Dict[str, Any]:
     - ``mixed``: ``{fusion name: [scopes]}`` for the fusions whose fused
       instructions lie in more than one scope, the scopes that only hold
       others (``acco/accumulate``, ``model/block``) not counted. XLA
-      fuses across scopes (AdamW with the guard's select after it); the
+      fuses across scopes (a speculative round's AdamW with the guard's norms); the
       fusion's own ``op_name``, the one ``scopes`` goes by, is one of
       them.
     """

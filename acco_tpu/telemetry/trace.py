@@ -79,8 +79,8 @@ DEVICE_SCOPES = (
     "acco/flat_unpack",     # flat vector -> parameter leaves (and its transpose)
     "acco/reduce_scatter",  # gradient reduce-scatter, ring or stock, staging included
     "acco/all_gather",      # parameter all-gather, ring or stock, staging included
-    "acco/optimizer",       # AdamW on the shard + the learning-rate schedule
-    "acco/guard",           # grad norm, finiteness, the ok-selects, staged verdict
+    "acco/optimizer",       # AdamW's write pass over the shard + the learning-rate schedule
+    "acco/guard",           # the verdict pass (grad norm, finiteness), a speculative round's select, staged verdict
     "acco/cast",            # f32 master -> working dtype, speculative/commit selects
     "model/embed",          # token + position embedding
     "model/block",          # one transformer block (attention + MLP inside)

@@ -91,6 +91,11 @@ def _carries(names, scope) -> bool:
 @pytest.mark.parametrize("kind", ["acco_even", "ddp"])
 @pytest.mark.parametrize("scope", DEVICE_SCOPES)
 def test_every_device_scope_names_ops_of_the_round_program(op_names, scope, kind):
+    if (scope, kind) == ("acco/cast", "ddp"):
+        # a guarded committing program (DDP's every step) casts inside its
+        # one write pass, which is acco/optimizer's: no pass of its own
+        assert not _carries(op_names[kind], scope)
+        return
     assert _carries(op_names[kind], scope), f"no op of the {kind} program carries {scope!r}"
 
 
@@ -247,13 +252,14 @@ def test_scope_table_names_each_instructions_innermost_scope():
 
 def test_scope_table_of_a_compiled_round_program(eight_devices):
     """On the real text: every scope owns some instruction of the DDP
-    step but the two that only contain others."""
+    step, but ``acco/cast``: a guarded committing program casts inside
+    ``acco/optimizer``'s one write pass."""
     from acco_tpu.telemetry import scope_table
 
     table = scope_table(_lower("ddp").compile().as_text())
     owners = set(table["scopes"].values())
-    assert owners == set(DEVICE_SCOPES)
+    assert owners == set(DEVICE_SCOPES) - {"acco/cast"}
     experts = scope_table(_lower("ddp", experts=True).compile().as_text())
-    assert set(experts["scopes"].values()) == set(ALL_DEVICE_SCOPES)
+    assert set(experts["scopes"].values()) == set(ALL_DEVICE_SCOPES) - {"acco/cast"}
     for fusion, mix in table["mixed"].items():
         assert len(mix) > 1 and not {"acco/accumulate", "model/block"} & set(mix)

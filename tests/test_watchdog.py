@@ -23,6 +23,7 @@ import logging
 import os
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -118,17 +119,32 @@ def _assert_guard_noop(np_before, state_after, metrics):
 # -- guard unit tests: the in-program no-op ---------------------------------
 
 
-@pytest.mark.parametrize("parity", [True, False], ids=["even", "odd"])
-def test_acco_nan_pending_skips_bitexact(eight_devices, parity):
+def _acco_program(step, program):
+    """``even`` / ``odd``: the parity-specialized round; ``generic-*``: the
+    one program that reads its parity from ``state.round_idx``."""
+    even = program.endswith("even")
+    return step.round_fn(parity=None if program.startswith("generic") else even)
+
+
+ACCO_PROGRAMS = ["even", "odd", "generic-even", "generic-odd"]
+
+
+@pytest.mark.parametrize("program", ACCO_PROGRAMS)
+def test_acco_nan_pending_skips_bitexact(eight_devices, program):
     """NaN in the consumed pending gradients: BOTH ACCO half-round
-    programs commit nothing — even rounds keep θ (no poisoned estimate
-    for the next half-round to compute against), odd rounds keep θ and
-    the optimizer state, bit-exactly."""
+    programs (and the parity-generic one on either parity) commit
+    nothing — even rounds keep θ (no poisoned estimate for the next
+    half-round to compute against), odd rounds keep the optimizer state
+    and the working vector they started from, bit-exactly: that vector
+    is the speculative θ̃ of the even round before, NOT cast(θ), and a
+    skipped commit must hand back the gathered slices of it."""
     step, state = _make("acco")
     state, _ = step.seed_fn()(state, _batch(1))
-    if not parity:  # advance one healthy even round so parity matches
+    if program.endswith("odd"):  # one healthy even round so parity matches
         state, _ = step.round_fn(parity=True)(state, _batch(2))
     before = _snap(state)
+    if program.endswith("odd"):
+        assert not np.array_equal(before.flat_params, before.zero1.opt.params)
     # Poison the staged grads AND record the verdict the staging path
     # would have recorded (pending_ok=0) — the organic pipeline version
     # of this (verdict set by the program itself) is
@@ -142,13 +158,154 @@ def test_acco_nan_pending_skips_bitexact(eight_devices, parity):
             ),
         ),
     )
-    new_state, m = step.round_fn(parity=parity)(poisoned, _batch(3))
+    new_state, m = _acco_program(step, program)(poisoned, _batch(3))
     _assert_guard_noop(before, new_state, m)
     assert not np.isfinite(float(m.grad_norm))
     assert int(new_state.health.consec_skipped) == 1
     # the data pipeline moved on: fresh (finite) grads are staged (the
     # even round's carry-in decontamination refuses the flagged grads)
     assert np.isfinite(np.asarray(jax.device_get(new_state.pending_grads))).all()
+
+
+def _round_program(program):
+    """(guarded step, unguarded step, the round under test of each, a state
+    on that round's parity) for ``ddp``, ``dpu`` or ``acco-<ACCO_PROGRAMS>``."""
+    mode, _, acco_program = program.partition("-")
+    step, state = _make(mode)
+    plain, _ = _make(mode, nan_guard=False)
+    if mode == "ddp":
+        return step, plain, step.step_fn(), plain.step_fn(), state
+    state, _ = step.seed_fn()(state, _batch(1))
+    if mode == "dpu":
+        return step, plain, step.round_fn(), plain.round_fn(), state
+    if acco_program.endswith("odd"):
+        state, _ = step.round_fn(parity=True)(state, _batch(2))
+    return (
+        step, plain, _acco_program(step, acco_program),
+        _acco_program(plain, acco_program), state,
+    )
+
+
+ROUND_PROGRAMS = ["ddp", "dpu"] + [f"acco-{p}" for p in ACCO_PROGRAMS]
+
+
+@pytest.mark.parametrize("program", ROUND_PROGRAMS)
+def test_healthy_round_commits_the_unguarded_update(eight_devices, program):
+    """The guard decides before it writes; what it then writes on a
+    healthy round is the unguarded program's update, every schedule and
+    parity: the same working vector, moments and parameters (to the last
+    bit but one: the CPU backend contracts multiply-adds as its fusions
+    fall, and the two programs fuse differently) and the same counters."""
+    step, plain, fn, plain_fn, state = _round_program(program)
+    before = _snap(state)
+    got, m = fn(_put(step, before), _batch(3))
+    want, _ = plain_fn(_put(plain, before), _batch(3))
+    assert not bool(m.skipped) and np.isfinite(float(m.grad_norm))
+    commits = not program.endswith("even")
+    assert bool(getattr(m, "is_real_update", True)) == commits
+    np.testing.assert_array_max_ulp(
+        np.asarray(got.flat_params), np.asarray(want.flat_params), 1
+    )
+    assert not np.array_equal(np.asarray(got.flat_params), before.flat_params)
+    for a, b in zip(jax.tree.leaves(got.zero1), jax.tree.leaves(want.zero1)):
+        if a.dtype == jnp.float32 and a.ndim:
+            np.testing.assert_array_max_ulp(np.asarray(a), np.asarray(b), 1)
+        else:  # Adam step count, LR-schedule and committed-grads counters
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(got.zero1.opt.count) == int(before.zero1.opt.count) + commits
+
+
+_PASS_THROUGH = ("dynamic_slice", "slice", "squeeze", "reshape", "convert_element_type")
+
+
+def _flat_state_selects(jaxpr, state_vars, gathered=(), in_cond=False):
+    """Every ``select_n`` of a (closed) jaxpr and the jaxprs it calls, as
+    ``(takes a flat-state leaf, takes the all-gather's result, predicate
+    is one scalar broadcast, sits in a cond branch)``. ``state_vars``:
+    the jaxpr's variables that are the working vector, the fp32
+    parameters or a moment, or a slice or cast of one."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    state_vars, gathered = set(state_vars), set(gathered)
+    made_by = {v: e for e in jaxpr.eqns for v in e.outvars}
+
+    def among(var, group):  # literals are unhashable, and never state
+        return not isinstance(var, jax.extend.core.Literal) and var in group
+
+    found = []
+    for eqn in jaxpr.eqns:
+        prim = eqn.primitive.name
+        if prim == "select_n":
+            pred, cases = eqn.invars[0], eqn.invars[1:]
+            maker = made_by.get(pred)
+            scalar = pred.aval.shape == () or (
+                maker is not None
+                and maker.primitive.name == "broadcast_in_dim"
+                and maker.invars[0].aval.shape == ()
+            )
+            found.append((
+                any(among(c, state_vars) for c in cases),
+                any(among(c, gathered) for c in cases),
+                scalar,
+                in_cond,
+            ))
+        if prim == "all_gather":
+            gathered.update(eqn.outvars)
+        elif prim in _PASS_THROUGH and among(eqn.invars[0], state_vars):
+            state_vars.update(eqn.outvars)
+        elif prim in _PASS_THROUGH and among(eqn.invars[0], gathered):
+            gathered.update(eqn.outvars)
+        operands = eqn.invars[1:] if prim == "cond" else eqn.invars
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if not hasattr(inner, "eqns"):
+                    continue
+                pairs = (  # a loop's carry is lined up differently: no flat state in it
+                    list(zip(operands, inner.invars))
+                    if len(inner.invars) == len(operands) else []
+                )
+                found += _flat_state_selects(
+                    inner,
+                    [i for o, i in pairs if among(o, state_vars)],
+                    [i for o, i in pairs if among(o, gathered)],
+                    in_cond or prim == "cond",
+                )
+    return found
+
+
+@pytest.mark.parametrize("program", ROUND_PROGRAMS)
+def test_round_programs_select_the_flat_state_once(eight_devices, program):
+    """Structure of the lowered round programs (the jaxpr, which the CPU
+    backend shares with the chip; what XLA fuses from it is read on the
+    chip): nothing is selected after the all-gather — the gathered shard
+    is already the answer; every select that takes the working vector,
+    the parameters or a moment goes by ONE scalar verdict, never by an
+    elementwise mask; and a committing program has such selects only
+    inside the conditional that is its single write pass (the
+    parity-specialized ones have none at all: the branch IS the
+    choice)."""
+    step, _, fn, _, state = _round_program(program)
+    closed = jax.make_jaxpr(fn)(state, _batch(3))
+    paths = [
+        jax.tree_util.keystr(path)
+        for path, _ in jax.tree_util.tree_flatten_with_path(state)[0]
+    ]
+    flat_state = [
+        v for v, path in zip(closed.jaxpr.invars, paths)
+        if path.endswith(("flat_params", "opt.params", "opt.mu", "opt.nu"))
+    ]
+    assert len(flat_state) == 4
+    selects = _flat_state_selects(closed, flat_state)
+    on_state = [s for s in selects if s[0]]
+    assert selects and not any(after_gather for _, after_gather, _, _ in selects)
+    assert all(scalar for _, _, scalar, _ in on_state)
+    if program.endswith("even") and not program.startswith("acco-generic"):
+        # speculative: one select, on the shard, before the gather
+        assert [in_cond for _, _, _, in_cond in on_state] == [False]
+    elif "generic" in program:  # the traced commit chooses inside the write
+        assert on_state and all(in_cond for _, _, _, in_cond in on_state)
+    else:
+        assert on_state == []
 
 
 def test_dpu_nan_pending_skips_bitexact(eight_devices):

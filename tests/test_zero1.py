@@ -9,7 +9,11 @@ from jax.sharding import PartitionSpec as P
 from acco_tpu.ops.adamw import AdamWState, init_adamw_state
 from acco_tpu.ops.schedules import get_schedule
 from acco_tpu.parallel.mesh import make_mesh
-from acco_tpu.parallel.zero1 import ShardGeometry, zero1_update_shard
+from acco_tpu.parallel.zero1 import (
+    ShardGeometry,
+    UpdateHealth,
+    zero1_update_shard,
+)
 
 WD, B1, B2, EPS = 0.1, 0.9, 0.95, 1e-8
 
@@ -120,6 +124,130 @@ def test_padding_positions_stay_zero(eight_devices):
     new_flat, new_opt = stepper(opt0, grads, jnp.float32(0.1))
     assert np.all(np.asarray(new_flat)[37:] == 0.0)
     assert np.all(np.asarray(new_opt.mu)[37:] == 0.0)
+
+
+# -- the guarded commit: verdict first, then one write -----------------------
+# zero1_update_shard applies the guard's verdict to what it returns. What it
+# must return is what the formula it replaced returned: the unguarded
+# (tentative) update where the verdict is ok, the old state and the old flat
+# vector, bit for bit, where it is not.
+
+N_G = 37  # ragged over 4 shards, so the pad mask is live
+
+
+def _guard_inputs(case, ws, tp, geom):
+    """Per-device unreduced gradients, optimizer state and the working
+    vector (deliberately NOT cast(params): ACCO's odd round starts from
+    the speculative one) for one health case."""
+    rng = np.random.default_rng(7)
+    n_dev, Pp = ws * tp, geom.padded_size
+    grads = rng.normal(size=(n_dev, Pp)).astype(np.float32)
+    params = rng.normal(size=(tp, Pp)).astype(np.float32)
+    mu = 0.1 * rng.normal(size=(tp, Pp)).astype(np.float32)
+    nu = np.abs(0.01 * rng.normal(size=(tp, Pp))).astype(np.float32)
+    pad = np.arange(Pp) >= N_G
+    for a in (params, mu, nu):
+        a[:, pad] = 0.0
+    old_flat = (params + 0.25).astype(np.float32)
+    if case == "nonfinite_grad":
+        grads[n_dev - 1, 3] = np.nan
+    elif case == "overflow_params":  # finite grads, the UPDATE overflows
+        mu[0, 5] = 3e38
+        nu[0, 5] = 1e-30
+    elif case == "over_cap":
+        grads *= 1e3
+    opt = AdamWState(
+        params=jnp.asarray(params.reshape(-1)),
+        mu=jnp.asarray(mu.reshape(-1)),
+        nu=jnp.asarray(nu.reshape(-1)),
+        count=jnp.int32(3),
+    )
+    return jnp.asarray(grads.reshape(-1)), opt, jnp.asarray(old_flat.reshape(-1))
+
+
+@pytest.mark.parametrize("tp", [1, 2], ids=["dp", "dp-x-tp"])
+@pytest.mark.parametrize("ws", [1, 4])
+@pytest.mark.parametrize(
+    "case", ["healthy", "nonfinite_grad", "overflow_params", "over_cap"]
+)
+@pytest.mark.parametrize(
+    "program", ["even", "odd", "generic-even", "generic-odd"]
+)
+def test_guarded_commit_is_the_old_formula(eight_devices, program, case, ws, tp):
+    """``where(ok, tentative, old)`` on every leaf, without the tentative
+    copy: ACCO's speculative round (static commit=False), the committing
+    round of ACCO / DPU / DDP (static True), and the parity-generic
+    program (traced), at one shard and four, alone and inside a tp group
+    whose replicated prefix is synced before the verdict."""
+    geom = ShardGeometry(N_G, ws)
+    grads, opt, old_flat = _guard_inputs(case, ws, tp, geom)
+    if tp > 1:
+        mesh = make_mesh({"tp": tp, "dp": ws}, devices=eight_devices[: tp * ws])
+        tp_kw = dict(tp_axis="tp", n_repl=6)
+        shard_spec = P(("tp", "dp"))  # one [S] slice a device
+        flat_spec = P("tp")  # the local [padded] vector, replicated over dp
+    else:
+        mesh = make_mesh({"dp": ws}, devices=eight_devices[:ws])
+        tp_kw, shard_spec, flat_spec = {}, P("dp"), P()
+    opt_spec = AdamWState(shard_spec, shard_spec, shard_spec, count=P())
+    commits = program in ("odd", "generic-odd")
+
+    def run(with_health, commit):
+        def body(grads, opt, old_flat, parity):
+            c = (parity == 1) if commit is None else commit
+            return zero1_update_shard(
+                grads, opt, jnp.float32(ws), jnp.float32(1e-2), geom, WD, B1,
+                B2, EPS, out_dtype=jnp.bfloat16, with_health=with_health,
+                max_grad_norm=50.0, commit=c,
+                old_flat=old_flat.astype(jnp.bfloat16), **tp_kw,
+            )
+
+        out_specs = (flat_spec, opt_spec) + (
+            (UpdateHealth(P(), P()),) if with_health else ()
+        )
+        return jax.jit(
+            jax.shard_map(
+                body, mesh=mesh,
+                in_specs=(shard_spec, opt_spec, flat_spec, P()),
+                out_specs=out_specs, check_vma=False,
+            )
+        )(grads, opt, old_flat, jnp.int32(commits))
+
+    flat, new_opt, health = run(
+        True, None if program.startswith("generic") else commits
+    )
+    tentative_flat, tentative_opt = run(False, True)
+
+    assert bool(health.ok) == (case == "healthy")
+    if case == "healthy":
+        want_flat = tentative_flat
+        want_opt = tentative_opt if commits else opt
+        g = np.asarray(grads).reshape(tp, ws, -1).sum(1) / (ws * tp)
+        if tp > 1:  # the replicated prefix is summed over tp, counted once
+            g[:, :6] = g[:, :6].sum(0)
+            g[1:, :6] = 0.0
+        np.testing.assert_allclose(
+            float(health.grad_norm),
+            np.sqrt(np.square(g[:, :N_G]).sum()),
+            rtol=1e-5,
+        )
+    else:
+        want_flat, want_opt = old_flat.astype(jnp.bfloat16), opt
+        assert np.isfinite(float(health.grad_norm)) == (case != "nonfinite_grad")
+    # A skipped update is the old state bit for bit. A committed one is the
+    # unguarded program's to the last bit but one: the CPU backend contracts
+    # a multiply and an add into one FMA or not as its loop fusion falls,
+    # and the two programs fuse differently.
+    ulp = 1 if case == "healthy" else 0
+    np.testing.assert_allclose(
+        np.asarray(flat, np.float32), np.asarray(want_flat, np.float32),
+        rtol=ulp * 2.0**-7, atol=0,
+    )
+    for got, want in zip(jax.tree.leaves(new_opt), jax.tree.leaves(want_opt)):
+        if ulp and got.dtype == jnp.float32:
+            np.testing.assert_array_max_ulp(np.asarray(got), np.asarray(want), ulp)
+        else:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 class TestSchedules:

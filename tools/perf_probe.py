@@ -105,7 +105,7 @@ def main():
     from acco_tpu.ops.schedules import get_schedule
     from acco_tpu.parallel.acco import AccoTrainStep
     from acco_tpu.parallel.mesh import DATA_AXIS, make_mesh
-    from acco_tpu.parallel.zero1 import zero1_update_shard
+    from acco_tpu.parallel.zero1 import UpdateHealth, zero1_update_shard
 
     mesh = make_mesh({DATA_AXIS: jax.device_count()})
     step = AccoTrainStep(
@@ -117,24 +117,26 @@ def main():
     opt_specs = jax.tree.map(lambda _: shard, state.zero1.opt)
     opt_specs = opt_specs._replace(count=P())
 
-    def opt_only(pending, opt):
+    # the guarded commit as a DDP / DPU / odd ACCO round runs it: the
+    # verdict pass, then the one write pass
+    def opt_only(pending, opt, flat):
         return zero1_update_shard(
             pending, opt, jnp.float32(8.0), jnp.float32(6e-4), step.geom,
             0.1, 0.9, 0.95, 1e-8, step.shard_axes, jnp.bfloat16,
+            with_health=True, old_flat=flat,
         )
 
     ofn = jax.jit(
         jax.shard_map(
             opt_only,
             mesh=mesh,
-            in_specs=(shard, opt_specs),
-            out_specs=(P(), opt_specs),
+            in_specs=(shard, opt_specs, P()),
+            out_specs=(P(), opt_specs, UpdateHealth(P(), P())),
             check_vma=False,
         )
     )
-    print(
-        f"zero1 opt update    : {timeit(ofn, state.pending_grads, state.zero1.opt):8.2f} ms"
-    )
+    ms = timeit(ofn, state.pending_grads, state.zero1.opt, state.flat_params)
+    print(f"zero1 guarded commit: {ms:8.2f} ms")
 
 
 if __name__ == "__main__":
