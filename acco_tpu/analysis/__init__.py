@@ -27,7 +27,7 @@ the gates walk; :mod:`~acco_tpu.analysis.slow_markers` audits the
 tier-1 time budget. ``tools/lint.py --ci`` is the single entry point;
 ``tests/test_lint_gates.py`` proves each analyzer fails on its seeded
 violation. HLO parsing lives in :mod:`~acco_tpu.analysis.hlo`, shared
-with ``tools/overlap_hlo.py`` and ``tools/step_estimate.py``.
+with ``tools/overlap_hlo.py``.
 """
 
 from acco_tpu.analysis.host_lint import Finding, lint_file, lint_paths  # noqa: F401

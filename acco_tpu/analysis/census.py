@@ -1,12 +1,13 @@
 """Collective census: every byte on the wire must be accounted for.
 
-``tools/step_estimate.py`` models the round's communication analytically
-— the gradient path moves exactly one reduce-scatter of fp32 gradients
-plus one all-gather of param-dtype params per round,
-``(ns-1)/ns · Pp · (4 + itemsize)`` bytes on the wire however the
-collectives are spelled (ring ppermutes, async native ops, or blocking
-pairs). This gate diffs each compiled program's *measured* census
-(op count + wire bytes from the scheduled entry) against that model, so
+The round's communication has a closed form
+(:func:`acco_tpu.analysis.programs.ring_comm_bytes`): the gradient path
+moves exactly one reduce-scatter of fp32 gradients plus one all-gather
+of param-dtype params per round, ``(ns-1)/ns · Pp · (4 + itemsize)``
+bytes on the wire however the collectives are spelled (ring ppermutes,
+async native ops, or blocking pairs). This gate diffs each compiled
+program's census (op count + wire bytes from the scheduled entry)
+against that model, so
 an accidental extra all-reduce — a psum left in a loss path, a
 re-gather of params someone adds in a refactor — fails CI with a byte
 count instead of silently shipping a 2x comm regression.

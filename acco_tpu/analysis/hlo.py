@@ -3,10 +3,8 @@
 Every structural claim the paper leans on — async overlap, buffer
 donation, bytes-on-wire, dtype placement — is checked against either the
 optimized *scheduled* HLO text (``compiled.as_text()``) or the
-executable's module header. Three tools used to carry their own copies
-of this parsing (``tools/overlap_hlo.py``, ``tools/step_estimate.py``,
-and ad-hoc greps); this module is the single implementation they and the
-``acco_tpu.analysis`` gate suite now share.
+executable's module header. This module is the single implementation
+``tools/overlap_hlo.py`` and the ``acco_tpu.analysis`` gate suite share.
 
 Scheduled-HLO conventions this parser relies on (stable across the
 jaxlib CPU and libtpu backends in this image):
@@ -50,16 +48,7 @@ NUMPY_TO_HLO = {
 SHAPE_RE = re.compile(r"\b(" + "|".join(DTYPE_BYTES) + r")\[([\d,]*)\]")
 DEF_RE = re.compile(r"^\s*(%?[\w.-]+)\s*=\s*(.*)$")
 OPERAND_RE = re.compile(r"%[\w.-]+")
-CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 GROUPS_RE = re.compile(r"replica_groups=\{?\{([\d,]+)\}")
-
-# Ops that cost nothing in a schedule walk (metadata / aliasing / control).
-FREE_OPS = {
-    "parameter", "constant", "get-tuple-element", "tuple", "bitcast",
-    "after-all", "partition-id", "replica-id", "domain", "opt-barrier",
-    "bitcast-convert", "rng-get-and-update-state", "add-dependency",
-    "custom-call",  # annotations (Sharding etc.); kernels special-cased
-}
 
 COLLECTIVE_KINDS = (
     "all-gather", "reduce-scatter", "all-reduce", "collective-permute",
@@ -169,63 +158,9 @@ def operands(rhs: str, type_end: int) -> list[str]:
     return []
 
 
-def comp_shapes(lines: list[str]) -> dict[str, tuple]:
-    """name -> result shape tuple (first shape in the def) per computation."""
-    shapes = {}
-    for line in lines:
-        dm = DEF_RE.match(line)
-        if not dm:
-            continue
-        m = SHAPE_RE.search(dm.group(2))
-        if m:
-            shapes[dm.group(1).lstrip("%")] = tuple(
-                int(d) for d in m.group(2).split(",") if d
-            )
-    return shapes
-
-
-def dot_flops(line: str, shapes: dict[str, tuple]) -> int:
-    """2 * result_elems * K for one dot line; shapes maps names defined in
-    the same computation to their result shape tuples."""
-    dm = DEF_RE.match(line)
-    rhs = dm.group(2)
-    op, type_end = parse_op(rhs)
-    _rb, re_ = result_bytes_elems(rhs, type_end)
-    cm = CONTRACT_RE.search(rhs)
-    if not cm:
-        return 2 * re_  # degenerate
-    dims = [int(d) for d in cm.group(1).split(",") if d]
-    args = operands(rhs, type_end)
-    lhs_shape = shapes.get(args[0]) if args else None
-    if not lhs_shape:
-        return 2 * re_
-    k = 1
-    for d in dims:
-        if d < len(lhs_shape):
-            k *= lhs_shape[d]
-    return 2 * re_ * k
-
-
-def computation_flops(comps: dict[str, list[str]]) -> dict[str, int]:
-    """Total dot/conv FLOPs inside each non-entry computation (fusion
-    bodies). Convolutions don't occur in these models; dots dominate."""
-    flops = {}
-    for name, lines in comps.items():
-        if name == "ENTRY":
-            continue
-        shapes = comp_shapes(lines)
-        total = 0
-        for line in lines:
-            if re.search(r"=\s*[^=]*\bdot\(", line):
-                total += dot_flops(line, shapes)
-        flops[name] = total
-    return flops
-
-
 # -- executable metadata (module header) -------------------------------------
 
 
-_ALIAS_HEADER_RE = re.compile(r"input_output_alias=\{(.*?)\}\s*(?:,|$)")
 _ALIAS_ENTRY_RE = re.compile(
     r"\{([\d,\s]*)\}:\s*\((\d+)\s*,\s*\{[\d,\s]*\}\s*,\s*([\w-]+)\)"
 )
@@ -324,9 +259,6 @@ _COLL_START_RE = re.compile(
     r"\b(" + "|".join(COLLECTIVE_KINDS) + r")-start\b"
 )
 _COLL_DONE_RE = re.compile(r"\b(" + "|".join(COLLECTIVE_KINDS) + r")-done\b")
-_COLL_BLOCK_RE = re.compile(
-    r"=\s*[^=]*\b(" + "|".join(COLLECTIVE_KINDS) + r")\("
-)
 
 
 @dataclass

@@ -30,6 +30,16 @@ check — the closed world still catches them on first execution; this
 gate exists so the *spelled-out* names, the overwhelmingly common case,
 fail the PR instead of the run. Pure stdlib AST, no jax import (the
 telemetry package itself is jax-free by contract).
+
+The gate fires the other way too (:func:`check_repo`): a name in
+``DECLARED`` that no call site under :data:`PRODUCTION_PATHS` emits is an
+``orphaned-metric`` — a measurement nothing takes. A call site emits a
+name when it spells it: as the literal, as either branch of a conditional
+expression of literals, as a literal key of ``emit_many``'s dict, or as
+``"prefix_" + key`` where the prefix is a literal and the rest of the
+declared name is spelled as a dict literal's key somewhere in the walk
+(the trainer's ``"train_" + name`` over ``LlamaModel._aux_terms``' keys).
+An orphan is deleted from ``DECLARED``, never exempted here.
 """
 
 from __future__ import annotations
@@ -47,11 +57,29 @@ METRIC_MANY_METHODS = {"emit_many"}
 SPAN_METHODS = {"span", "complete_event", "instant"}
 SCOPE_METHODS = {"named_scope"}
 
+# What "the tree emits a metric" means: the program, its tools and its
+# entry points. Tests and fixtures emit to exercise the registry, the
+# benchmark reads the registry; neither keeps a declaration alive.
+PRODUCTION_PATHS = ("acco_tpu", "tools", "main.py", "serve.py", "chip_smoke.py")
+
 
 @dataclass
 class MetricsGateReport:
     findings: list[Finding] = field(default_factory=list)
     checked: int = 0  # literal-named call sites resolved
+    emitted: set[str] = field(default_factory=set)  # metric names spelled at an emit
+    prefixes: set[str] = field(default_factory=set)  # emit("prefix_" + expr, ...)
+    dict_keys: set[str] = field(default_factory=set)  # every dict literal's str keys
+
+    def orphaned(self, declared) -> list[str]:
+        """Declared names no walked call site emits (module docstring)."""
+        return sorted(
+            name for name in declared
+            if name not in self.emitted and not any(
+                name.startswith(p) and name[len(p):] in self.dict_keys
+                for p in self.prefixes
+            )
+        )
 
     @property
     def ok(self) -> bool:
@@ -61,10 +89,10 @@ class MetricsGateReport:
         if self.ok:
             return (
                 f"{self.checked} literal telemetry call sites, "
-                "all names declared"
+                "all names declared, every declared metric emitted"
             )
         return (
-            f"{len(self.findings)} undeclared name(s) across "
+            f"{len(self.findings)} finding(s) across "
             f"{self.checked} literal call sites"
         )
 
@@ -82,6 +110,15 @@ def _literal_str(node: ast.AST | None) -> str | None:
     if isinstance(node, ast.Constant) and isinstance(node.value, str):
         return node.value
     return None
+
+
+def _literal_names(node: ast.AST | None) -> list[str]:
+    """The names an emit's first argument spells: a literal, or a
+    conditional expression whose branches are (``"a" if c else "b"``)."""
+    if isinstance(node, ast.IfExp):
+        return _literal_names(node.body) + _literal_names(node.orelse)
+    name = _literal_str(node)
+    return [] if name is None else [name]
 
 
 def _span_cat(node: ast.Call) -> str | None:
@@ -105,6 +142,7 @@ class _TelemetryCallVisitor(ast.NodeVisitor):
 
     def _check_metric(self, node: ast.Call, name: str) -> None:
         self.report.checked += 1
+        self.report.emitted.add(name)
         if name not in self.declared:
             self.report.findings.append(Finding(
                 self.path, node.lineno, "undeclared-metric",
@@ -136,9 +174,13 @@ class _TelemetryCallVisitor(ast.NodeVisitor):
     def visit_Call(self, node: ast.Call) -> None:
         meth = _method_name(node)
         if meth in METRIC_METHODS and node.args:
-            name = _literal_str(node.args[0])
-            if name is not None:
+            arg = node.args[0]
+            for name in _literal_names(arg):
                 self._check_metric(node, name)
+            if isinstance(arg, ast.BinOp) and isinstance(arg.op, ast.Add):
+                prefix = _literal_str(arg.left)
+                if prefix:
+                    self.report.prefixes.add(prefix)
         elif meth in METRIC_MANY_METHODS and node.args:
             arg = node.args[0]
             if isinstance(arg, ast.Dict):
@@ -154,6 +196,13 @@ class _TelemetryCallVisitor(ast.NodeVisitor):
                     self._check_span(node, name)
         elif meth in SCOPE_METHODS and node.args:
             self._check_scope(node, _literal_str(node.args[0]))
+        self.generic_visit(node)
+
+    def visit_Dict(self, node: ast.Dict) -> None:
+        for key in node.keys:
+            name = _literal_str(key)
+            if name is not None:
+                self.report.dict_keys.add(name)
         self.generic_visit(node)
 
 
@@ -194,4 +243,20 @@ def check_paths(
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     check_file(os.path.join(dirpath, fn), report=report)
+    return report
+
+
+def check_repo(repo_root: str) -> MetricsGateReport:
+    """Both directions over :data:`PRODUCTION_PATHS`: every spelled name is
+    declared, and every declared name is spelled at some emit."""
+    report = check_paths([
+        os.path.join(repo_root, rel) for rel in PRODUCTION_PATHS
+    ])
+    for name in report.orphaned(REGISTRY.declared_names()):
+        report.findings.append(Finding(
+            "acco_tpu/telemetry/metrics.py", 0, "orphaned-metric",
+            f"{name!r} is declared in DECLARED and no call site under "
+            f"{', '.join(PRODUCTION_PATHS)} emits it: delete the declaration "
+            "(or emit the metric where it is measured)",
+        ))
     return report

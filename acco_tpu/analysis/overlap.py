@@ -20,9 +20,10 @@ The verdict (unchanged from overlap_hlo, which now delegates here):
   coverage is not achievable nor required).
 
 Known baseline: at dp=32 this libtpu's device-count async gate refuses
-to form pairs at all (65 blocking collectives, 0% hidden —
-ESTIMATES.json), so the dp=32 gate is recorded as an EXPECTED failure
-until ROADMAP item 1 lands; ``tools/lint.py --overlap`` encodes that.
+to form pairs from XLA's own collectives (every one compiles blocking;
+``tests/test_ring_canary.py`` watches for the day that changes), so the
+dp=32 gate is recorded as an EXPECTED failure under ``comm_impl='xla'``;
+``tools/lint.py --overlap`` encodes that.
 """
 
 from __future__ import annotations

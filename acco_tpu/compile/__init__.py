@@ -12,8 +12,8 @@ compilation a one-time cost instead of a per-launch one:
   background threads from abstract avals, overlapped with dataset and
   state setup, instead of lazily inside the timed loop.
 
-Entry points: ``setup_compilation_cache`` (main.py, tests/conftest.py,
-bench.py), ``CompileWarmup``/``warmup_programs`` (trainer,
+Entry points: ``setup_compilation_cache`` (main.py, tests/conftest.py),
+``CompileWarmup``/``warmup_programs`` (trainer,
 tools/compile_report.py), ``cache_stats``/``CacheStatsWindow``
 (observability and the cache-key stability tests),
 ``attribute_cache_events`` (exact per-program hit/miss attribution for

@@ -6,9 +6,8 @@ loop at its first call, serially, with the TPU idle the whole time. XLA
 releases the GIL during compilation, so the programs can instead be
 lowered and compiled CONCURRENTLY on background threads at trainer
 construction, overlapped with dataset tokenization, loader setup, and
-state init (measured on the CPU mesh: 3 round programs compile in ~55%
-of their serial wall time; on a pod the compile minutes hide entirely
-under corpus tokenization).
+state init. What that buys a launch on the chip is in the benchmark's
+``setup_s`` and ``compile_lower_s`` (PERF.md §3; ROADMAP S7).
 
 The warmup compiles from *abstract* inputs (``jax.ShapeDtypeStruct`` +
 ``NamedSharding`` — no state allocation, no data), via the steps'
@@ -16,9 +15,8 @@ The warmup compiles from *abstract* inputs (``jax.ShapeDtypeStruct`` +
 result is not installed into jit's dispatch cache (jax keeps AOT and
 just-in-time paths separate), so the first real call still goes through
 compilation — but it is served from the persistent compilation cache
-(cache.py) the warmup just populated: a disk deserialization, ~10x
-faster than the compile, and the trainer's startup path never blocks on
-XLA.
+(cache.py) the warmup just populated: a disk deserialization in place
+of a compile, and the trainer's startup path never blocks on XLA.
 
 Failure policy: a warmup error NEVER fails training — the same program
 will be compiled lazily at first call and raise there if genuinely

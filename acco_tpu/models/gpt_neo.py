@@ -132,20 +132,15 @@ class GPTNeoModel:
         if impl == "ring" and not sequence_axis:
             raise ValueError("attention='ring' requires sequence_axis")
         if impl == "flash":
-            # A deliberate, data-backed decision rather than a gap:
-            # GPT-Neo's context ceiling is 2048 (config here: 1024) —
-            # below the measured v5e flash crossover
-            # (resolve_attention_impl: XLA's einsum path wins up to 2k
-            # tokens, 62.3k vs 47.2k tok/s/chip at 1024). Block-sparse
-            # window masking was also measured directly, not assumed away:
-            # splash-attention LocalMask at the exact pretrain shape
-            # (B8 H12 L1024 D64, window 256; tools/attn_probe.py) runs
-            # 5.50 ms f+b vs 5.73 for the masked einsum and 5.18 for
-            # splash-causal — the 256-token band is too narrow relative
-            # to MXU-efficient block sizes (512) to skip any whole block,
-            # so the "sparse" kernel does causal work plus masking
-            # overhead. At every length this architecture supports, the
-            # XLA path wins.
+            # The rule: the stock flash kernel is refused for this
+            # family, whose context ends at 2048 and whose window of 256
+            # is narrower than the kernel's 512-token blocks, so a
+            # block-sparse mask could skip no whole block and the kernel
+            # would do causal work plus masking. No ledger line holds a
+            # timing of it against the einsum or the banded kernel
+            # (unmeasured; ROADMAP S1): the refusal keeps a path nobody
+            # has run on this family out of a user's reach, and the
+            # message below words it more strongly than the evidence.
             raise ValueError(
                 "GPT-Neo's alternating local-sliding-window layers use the "
                 "XLA attention path by design: its max context (2048) is "
@@ -156,17 +151,16 @@ class GPTNeoModel:
                 "'ring' with sequence_axis for context parallelism)"
             )
         # 'fused' (the bespoke full-tile VMEM kernel, ops/fused_attention)
-        # is the exception to the above: it has none of the online-softmax
-        # block machinery the measured stock kernels lose to, carries the
+        # is the exception to the above: it has none of the stock kernel's
+        # online-softmax block machinery, carries the
         # sliding window as a traced SMEM scalar (so the one scanned layer
         # body still serves both layer kinds), and removes the [B,H,L,L]
         # score HBM traffic entirely. 'auto' resolves to it per shape.
         # Local layers additionally dispatch (lax.cond in _block_body) to
         # the BANDED kernel (ops/banded_attention): QB=128 q-row blocks
-        # against only their nprev+1 in-window key blocks — unlike the
-        # measured splash LocalMask above, its band unit is far below 512
-        # so a 256-token window genuinely skips ~(L-W-QB)/L of the score
-        # work instead of masking it.
+        # against only their nprev+1 in-window key blocks: its band unit
+        # is far below the stock kernel's 512, so a 256-token window skips
+        # ~(L-W-QB)/L of the score work instead of masking it.
         self.attention = impl
         self.config = config
         self.param_dtype = param_dtype
@@ -374,8 +368,8 @@ class GPTNeoModel:
         Returns ``(fused, banded_local, global_bias, local_bias)``.
         ``banded_local`` extends the banded window kernel to the EINSUM
         plan: at L=2048 — GPT-Neo's max context — 'auto' resolves the
-        *global* layers to the measured einsum path (the full-tile
-        kernel is unmeasured there), but the local layers' einsum still
+        *global* layers to the einsum (the full-tile kernel is unmeasured
+        there: ROADMAP S1), but the local layers' einsum still
         computes the whole [L, L] it masks ~(L-W)/L away; the banded
         kernel (no L wall, parity-tested) replaces just those. Requires
         mask-free batches (const-len) and a TPU (or the interpreter
@@ -467,8 +461,7 @@ class GPTNeoModel:
                         # (global) and the STATIC config window. Branch at
                         # runtime; the local branch's banded kernel computes
                         # only the [L, W+QB] key band instead of the full
-                        # [L, L] tile it would mask ~3/4 away — the window
-                        # layers are GPT-Neo's measured MFU gap vs Llama.
+                        # [L, L] tile it would mask ~3/4 away.
                         attn = jax.lax.cond(
                             window == 0,
                             lambda q, k, v: fused_dot_product_attention(
@@ -488,7 +481,7 @@ class GPTNeoModel:
                         )
                 elif banded_local:
                     # einsum plan, banded local layers: global layers keep
-                    # the measured einsum path, local layers skip the
+                    # the einsum, local layers skip the
                     # out-of-window score work entirely (L=2048 — GPT-Neo's
                     # max context, where 'auto' doesn't pick the full-tile
                     # kernel — computes a 5.3x-narrower band instead)

@@ -37,13 +37,14 @@ def wrap_remat(block, remat):
     policy (projection/MLP matmul outputs stored, attention scores and
     elementwise recomputed); ``'dots+probs'`` — dots plus the bf16
     attention probabilities (ops/attention.py names them), trading
-    ~B*H*L^2*2 bytes of storage per layer for the backward not re-paying
-    the float32 score/softmax HBM stream — the einsum path's dominant
-    traffic (BASELINE.md roofline). Anything else is a config error.
+    ~B*H*L^2*2 bytes of storage per layer for the backward not recomputing
+    the float32 scores and softmax of the einsum path. Whether that trade
+    wins a round is unmeasured: no benchmark cell sets it (ROADMAP D2).
+    Anything else is a config error.
 
     The 'dots' policy additionally saves the fused attention kernel's
-    named outputs (attn_out + attn_lse, ops/fused_attention.py —
-    ~13 MB/layer at the flagship shape): a pallas_call is not a dot, so
+    named outputs (attn_out + attn_lse, ops/fused_attention.py): a
+    pallas_call is not a dot, so
     without the names the backward re-traces and reruns the forward
     kernel once per layer purely to regenerate its residuals. On the
     einsum path the names never occur and the policy is unchanged.
@@ -55,7 +56,7 @@ def wrap_remat(block, remat):
     Spellings are normalized through ops.attention.normalize_remat (the
     one normalizer every surface shares), so YAML/CLI forms like
     ``remat: 1`` / ``train.remat=0`` / ``'true'`` work here exactly as
-    they do in bench.py and the proof tools.
+    they do in the proof tools.
     """
     from acco_tpu.ops.attention import normalize_remat
 

@@ -14,8 +14,9 @@ Softmax runs in float32; the QK and PV contractions stay in the activation
 dtype (bfloat16 on TPU) so they hit the MXU.
 :func:`flash_dot_product_attention` is the fused O(L)-memory alternative
 (JAX's bundled Pallas TPU flash kernel) behind the same call contract;
-:func:`resolve_attention_impl` picks between them from measured v5e
-crossover data.
+:func:`resolve_attention_impl` picks between them by a rule on
+platform, length and remat policy whose thresholds are unmeasured
+(ROADMAP S1).
 """
 
 from __future__ import annotations
@@ -62,45 +63,35 @@ def resolve_attention_impl(
 ) -> str:
     """Resolve an attention-impl request to 'xla', 'flash', or 'fused'.
 
-    'fused' is the bespoke full-tile VMEM kernel
-    (ops/fused_attention.py): on TPU, 'auto' picks it whenever the shape
-    fits its VMEM envelope (``head_dim`` known, L ≤ 2048, aligned) — it
-    removes the [B, H, L, L] HBM score traffic that BASELINE.md's
-    roofline proves is the einsum dataflow's binding constraint, without
-    the stock flash kernel's online-softmax block machinery that loses
-    at these lengths.
+    ``impl``: 'fused' / 'flash' / 'xla' force. 'auto' (the
+    ``use_pallas_attention: auto`` config default) applies this rule:
 
-    For shapes outside the fused envelope, ``impl``: 'flash'/'xla'
-    force; 'auto' (the ``use_pallas_attention: auto`` config default)
-    picks from crossover data measured on a v5e at Llama-125M train
-    shapes (ACCO round, tok/s/chip; see BASELINE.md):
+    - off the TPU: 'xla' (Pallas TPU kernels don't run there);
+    - 'fused', the full-tile VMEM kernel (ops/fused_attention.py, no
+      [B, H, L, L] scores in HBM), when ``head_dim`` is known, the shape
+      fits the kernel's envelope AND ``seq_len <= 1024``;
+    - else 'flash' (the stock online-softmax kernel, O(L) memory) when
+      ``seq_len`` is a multiple of 512 and at least 2048 with remat off,
+      or at least 4096 under any remat policy (the kernel's O(L) memory
+      is itself the remat, so a policy's recompute on top of it is pure
+      overhead; ``remat`` is the model's policy: False | True | 'dots');
+    - else 'xla' (the einsum).
 
-    ============ ========== ============ ================
-    seq (chip bs)  xla+dots   flash+dots   flash+no-remat
-    ============ ========== ============ ================
-    1024 (8)      **62.3k**      42.8k         47.2k
-    2048 (4)       29.2k         27.8k        **32.8k**
-    4096 (2)       16.1k         16.6k        **20.6k**
-    ============ ========== ============ ================
-
-    Below 2k tokens the einsum path wins outright — the flash kernel's
-    block machinery costs more than it saves. At >=2k the flash kernel
-    wins **when remat is off**: its O(L) memory is itself the remat (no
-    [B, H, L, L] score materialization), so the bwd recompute a remat
-    policy adds is pure overhead that hands the race back to XLA's fused
-    attention. Hence ``remat`` (the model's policy: False | True |
-    'dots') feeds the decision: no-remat -> flash at >=2048; with remat
-    -> flash only at >=4096 (where it edges xla out even paying the
-    recompute). On CPU (tests, virtual meshes) 'auto' is always 'xla' —
-    Pallas TPU kernels don't run there.
+    UNMEASURED: the three thresholds (1024, 2048, 4096) are guesses. The
+    fused kernel's envelope takes L = 2048 and no cell has run it there;
+    the benchmark's cells sit at L = 1024 ('fused'; ``attn_kernel_ms``
+    18.08 of a 97.55 ms round at ``attn_kernel_roofline`` 12.2%: ledger,
+    PR 24) and at L = 2048 under ``remat=dots`` ('xla' for the global
+    layers), one side of each line only. ROADMAP S1 runs the other sides
+    (cell ``neo27b-l4-seq1024``, R4); D2 then makes the choice from shape
+    and deletes what loses.
 
     Sliding-WINDOW layers (GPT-Neo) have their own lane outside this
-    table: the banded kernel (ops/banded_attention.py) computes only
+    rule: the banded kernel (ops/banded_attention.py) computes only
     the key band and is dispatched per layer by the model itself —
     inside the 'fused' plan at L <= 1024, and as the local-layer branch
     of the einsum plan past it (GPTNeoModel._dense_attn_plan) — so this
-    resolver only ever decides the GLOBAL layers' impl. The L=2048
-    fused-vs-flash-noremat crossover has not been measured (ROADMAP S3).
+    resolver only ever decides the GLOBAL layers' impl.
     """
     impl = normalize_attention_impl(impl)
     remat = normalize_remat(remat)  # '0'/'false' must mean remat-OFF
@@ -115,16 +106,14 @@ def resolve_attention_impl(
     if head_dim is not None:
         from acco_tpu.ops.fused_attention import supports_fused_attention
 
-        # 'auto' only prefers the bespoke kernel up to L=1024 — the shape
-        # class it was built and measured for. At 2048 the flash kernel
-        # has a MEASURED no-remat win (32.8k vs 29.2k, table below) that
-        # the fused kernel has not yet beaten on-chip; prefer measured
-        # data over expectation there until it has.
+        # 'auto' prefers the bespoke kernel only up to L=1024, the shape
+        # class it was built for; past it the choice is unmeasured
+        # (docstring; ROADMAP S1).
         if supports_fused_attention(seq_len, head_dim) and seq_len <= 1024:
             return "fused"
     threshold = 2048 if remat in (False, None) else 4096
     if seq_len >= threshold and seq_len % 512:
-        # ADVICE round 1: a long-but-unaligned sequence (e.g. 3000) would
+        # a long-but-unaligned sequence (e.g. 3000) would otherwise
         # silently fall back to the O(L^2)-memory einsum path in exactly
         # the regime it stops fitting HBM.
         log.warning(
@@ -141,7 +130,7 @@ def normalize_remat(value) -> "bool | str":
     """THE remat-spelling normalizer: config/CLI/env surfaces write the
     policy as YAML booleans, 0/1 ints, or strings ('true', 'dots', the
     README's ``train.remat=1``); every consumer (wrap_remat, the
-    attention resolver, bench.py, hbm_check) normalizes through this
+    attention resolver, hbm_check) normalizes through this
     one function so a spelling can never mean remat-off to one of them
     and remat-on to another. Returns False | True | 'dots' |
     'dots+probs'; anything else raises."""
@@ -291,8 +280,8 @@ def dot_product_attention(
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     # Named for the 'dots+probs' remat policy (models/layers.wrap_remat):
     # saving the bf16 probabilities lets the backward skip recomputing
-    # the [B, H, L, L] float32 scores + softmax — the single biggest HBM
-    # stream of the einsum attention path (BASELINE.md roofline).
+    # the [B, H, L, L] float32 scores + softmax, the largest buffer the
+    # einsum attention path writes.
     from jax.ad_checkpoint import checkpoint_name
 
     probs = checkpoint_name(probs, "attn_probs")

@@ -5,9 +5,7 @@ GPT-Neo alternates global and local (window 256) attention layers
 models/gpt_neo.py preserves the pattern). The full-tile kernel
 (ops/fused_attention.py) serves both through one traced SMEM window
 scalar — but for a window layer at L=1024 it still computes the whole
-[L, L] score tile and masks ~3/4 of it away, which is exactly the
-GPT-Neo MFU deficit the round-4 verdict flagged (0.257 vs Llama 0.364;
-the window layers ARE the gap).
+[L, L] score tile and masks ~3/4 of it away.
 
 This kernel computes only the band. The window is a STATIC Python int —
 GPT-Neo's two per-layer window values (0 and config.window_size) are
@@ -54,8 +52,7 @@ def _nprev(window: int) -> int:
     """KV blocks BEFORE the diagonal block a q block can reach: the
     lowest in-window key for row qb·QB is qb·QB − W + 1, i.e. W−1 keys
     back — ceil((W−1)/QB) blocks, NOT ceil(W/QB): at W % QB == 1 the
-    latter loads one fully-masked extra KV view per grid cell (round-5
-    ADVICE #3)."""
+    latter loads one fully-masked extra KV view per grid cell."""
     return -(-(window - 1) // _QB)
 
 

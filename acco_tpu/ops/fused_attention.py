@@ -1,13 +1,13 @@
 """Bespoke fused attention kernel: full-tile, VMEM-resident scores.
 
 The einsum attention path materializes [B, H, L, L] float32 scores in
-HBM — ~4.4 GB/layer forward+backward at the flagship shape (Llama-125M,
-L=1024, D=64, per-chip bs 8), which BASELINE.md's roofline proves is the
-dataflow's binding constraint (~64 ms of the 130 ms round, ceiling
-~0.29 MFU). The stock Pallas flash kernel removes the HBM traffic but
-pays online-softmax block machinery that measures *slower* in-model at
-this shape (42.8–47.2k vs 62.3k tok/s — resolve_attention_impl's
-crossover table).
+HBM (at L=1024, D=64, 12 heads and a per-chip batch of 8, 403 MB a
+layer for each pass over them). The stock Pallas flash kernel removes
+that traffic with online-softmax blocks over L. How the three compare
+in a round at these lengths is unmeasured: the benchmark's 125M cells
+run this kernel (``attn_kernel_ms`` 18.08 of a 97.55 ms round, at
+``attn_kernel_roofline`` 12.2%: ledger, PR 24) and nothing runs the
+other two beside it (ROADMAP S1).
 
 This kernel is the third point in that design space, tuned for the
 L≤2048 regime where one head's entire [L, L] float32 score tile fits in
@@ -33,8 +33,7 @@ VMEM (4 MB at L=1024, 16 MB at L=2048 — v5e VMEM is 128 MB):
 
 Reference frame: the reference gets fused attention implicitly from HF
 transformers' SDPA/cuDNN path (`/root/reference/trainer_decoupled.py`);
-this kernel is the TPU-native equivalent, built because the measured
-stock kernels do not deliver at the pretrain shape.
+this kernel is the TPU-native equivalent for the pretrain shape.
 """
 
 from __future__ import annotations

@@ -1,11 +1,13 @@
 """Fused lm-head + cross-entropy Pallas kernel: no [N, V] HBM logits.
 
 The [B, L, V] float32 logits are the train step's largest transient
-(1.65 GB at the flagship shape, [B, L, 128k] for Llama-3 — BASELINE.md
-measures the materialized lm-head+CE at ~22 ms of the round against a
-~10 ms flops floor, the gap being logits HBM traffic). The existing
-``chunked_causal_lm_loss`` bounds *memory* but measured ~3% slower
-in-step (scan + recompute overhead). This kernel is the dataflow fix:
+([8, 1023, 50257] f32 = 1.65 GB in the 125M cells, [B, L, 128k] for
+Llama-3). The materialized lm-head + CE reads ``lm_head_ce_ms`` 15.96 of
+``neo125m-acco-1chip``'s round where its matmuls need 9.6 ms at the MXU's
+peak (ledger, PR 24; ROADMAP S5). ``chunked_causal_lm_loss`` bounds the
+*memory* with a scan and a recompute. This kernel removes the stream; it
+has NEVER RUN ON THE CHIP (compiled for it only: tests/test_tpu_compile.py,
+tests/test_fused_ce.py), so what it wins is unmeasured (ROADMAP S5):
 
 * forward — grid (row_blocks, vocab_tiles), vocab innermost: one
   [RB, VT] logits tile lives in VMEM per step; a running (max, sumexp,
@@ -28,9 +30,9 @@ in-step (scan + recompute overhead). This kernel is the dataflow fix:
   whose accumulators live in VMEM scratch (one extra logits recompute,
   5 contractions instead of 4, no [T, N, D] buffer at all).
   Total matmul work is 4 (or 5) lm-head-sized contractions vs the
-  materialized path's 3 — bought back several times over by the removed
-  HBM stream (and the backward contractions run in the activation dtype
-  on the MXU, where the materialized path's f32 dlogits matmuls do not).
+  materialized path's 3, the price of the removed HBM stream (the
+  backward contractions run in the activation dtype on the MXU, where
+  the materialized path's f32 dlogits matmuls do not).
 
 Semantics parity with ``ops.losses._per_token_ce`` (the contract every
 loss path shares): f32 log-sum-exp, IGNORE_INDEX masking, HF
@@ -406,7 +408,7 @@ def supports_fused_ce(n_rows: int, hidden: int, vocab: int) -> bool:
     IGNORE_INDEX, so they drop out of the loss), so no minimum row
     COUNT beyond non-emptiness — a degenerate B=1, L<=8 eval batch is
     in-envelope, and the build-time gate (losses.resolve_fused_loss)
-    can answer without knowing the runtime batch shape (ADVICE r4).
+    can answer without knowing the runtime batch shape.
     n_rows == 0 (L=1 with shift) stays out: a zero-row grid would never
     write the dW output buffer in the backward."""
     return n_rows >= 1 and hidden % 128 == 0 and vocab >= 128
@@ -433,7 +435,7 @@ def _tiles(D: int, V: int, n_rows: int, block_rows: int,
     # a non-power-of-2 n_rows (e.g. 400 at large D -> rb 200 after
     # halving) or a tiny batch (n_rows 9..15 -> rb = n_rows) would
     # otherwise hand Mosaic a row block it may refuse to lower on real
-    # TPU even though the interpreter accepts it (ADVICE r4). Rounding
+    # TPU even though the interpreter accepts it. Rounding
     # UP is safe — rows are padded to rb by the caller.
     rb = max(16, rb // 16 * 16)
     return rb, min(vt, max(V, 1))

@@ -28,7 +28,7 @@ def normalize_fused_loss(value) -> "bool | str":
     """Config-surface spellings of ``fused_loss`` to False | 'auto' |
     'chunk' | 'pallas'. Legacy booleans mean the scan-chunked form;
     'pallas' is the VMEM-tiled kernel (ops/fused_ce.py); 'auto' defers
-    to the measured/placement policy in :func:`resolve_fused_loss`."""
+    to the placement policy in :func:`resolve_fused_loss`."""
     if value in (False, None, 0, "0", "false", "False", ""):
         return False
     if value in (True, 1, "1", "true", "True", "chunk"):
@@ -43,26 +43,29 @@ def normalize_fused_loss(value) -> "bool | str":
 def _auto_fused_policy(model, n_vocab_shards, seq_sharded, platform):
     """The ``fused_loss: 'auto'`` decision, mirroring
     ``use_pallas_attention: auto`` (ops/attention.resolve_attention_impl):
-    'pallas' where the kernel is known or strongly expected to win,
-    False elsewhere, never 'chunk' (measured ~4 ms/round SLOWER at the
-    50k flagship vocab — BASELINE.md).
+    'pallas' or False from platform and placement, never 'chunk'.
 
-    Policy, in order:
+    UNMEASURED: the Pallas kernel has never run on the chip and no
+    benchmark cell sets ``fused_loss`` to anything but ``auto`` (which
+    resolves to False in all six), so every pick below is a guess from
+    what the materialized path must write, not a result. ROADMAP S5 runs
+    the kernel at the cells' widths; D2 then lets the code choose from
+    shape and deletes what loses ('chunk' included: no cell has timed it).
+
+    The rule, in order:
     - non-TPU platforms: False (the kernel is Mosaic-only; the
       interpreter is a test vehicle, not a performance path);
     - sharded vocab (tp / pp / pp·tp pipelined forms): 'pallas' — the
-      materialized path pays a [b, L, V/shards] f32 logits write+read
-      per microbatch tick, and the 8B {dp:2, pp:8, tp:2} placement is
-      compiler-proved to fit WITH the kernel (tools/hbm_check.py,
-      13.13 GB of 16); this is also where the kernel's envelope was
-      AOT-fitted (tests/test_fused_ce.py canaries at 8B dims);
+      materialized path writes and reads a [b, L, V/shards] f32 logits
+      buffer per microbatch tick, and the 8B {dp:2, pp:8, tp:2}
+      placement compiles to fit the chip with the kernel
+      (tools/hbm_check.py); this is also where the kernel's envelope
+      was AOT-fitted (tests/test_fused_ce.py canaries at 8B dims);
     - context parallelism: 'pallas' — the long-sequence regime is the
       no-materialized-logits loss's reason to exist;
-    - single-chip / plain dp: 'pallas' only for Llama-3-class vocabs
-      (V >= 100k, where the [N, V] f32 logits stream dwarfs the
-      lm-head matmul); the 50k-vocab flagship stays on the fused-free
-      path until the queued chip battery measures the crossover
-      (ACCO_BENCH_FUSED=pallas variant — fold the verdict in here).
+    - single-chip / plain dp: 'pallas' only at V >= 100k (Llama-3-class
+      vocabularies, where the [N, V] f32 logits are largest against
+      the lm-head matmul); below that, False. The threshold is a guess.
     """
     if platform != "tpu":
         return False
@@ -353,13 +356,12 @@ def chunked_causal_lm_loss(
     targets, IGNORE_INDEX mask, f32 log-sum-exp, HF LabelSmoother
     smoothing; equivalence-tested value and grad).
 
-    Speed is shape-dependent (v5e measurements): 5.8% faster than the
-    materialized path as a bare grad step at the flagship shape, but
-    ~3% slower embedded in the full sharded train step — so the 'auto'
-    policy (the shipped config default, resolve_fused_loss) never picks
-    'chunk'; it exists as the explicit-request fallback where Pallas
-    can't run, for the memory-bound regime (long sequences / 128k-vocab
-    models) where materializing the logits is not an option at all.
+    Its speed against the materialized path is unmeasured (no benchmark
+    cell runs it; ROADMAP S5 / D2). The 'auto' policy (the shipped config
+    default, resolve_fused_loss) never picks 'chunk'; it exists as the
+    explicit-request fallback where Pallas can't run, for the
+    memory-bound regime (long sequences / 128k-vocab models) where
+    materializing the logits is not an option at all.
 
     Not used under context parallelism or any sharded/padded vocab (no
     num_valid/shift/vocab_axis plumbing — model_ce raises; the 'pallas'
@@ -469,7 +471,7 @@ def _model_ce(model, forward, params, labels, label_smoothing, fused,
         # num_valid, or vocab_axis plumbing; resolve_fused_loss never
         # routes such a config here, so reaching this branch with any of
         # them set is caller misuse — fail at trace time rather than
-        # silently drop the argument (ADVICE r4).
+        # silently drop the argument.
         if not (
             shift is True
             and num_valid is None

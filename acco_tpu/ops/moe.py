@@ -29,7 +29,8 @@ kernel), compiled on the TPU and run in Pallas's interpret mode elsewhere (the
 CPU of the tests and the virtual meshes), so there is one implementation and
 the tests' float32 comparison with the plain reference runs its tiling and its
 group boundaries. ``jax.lax.ragged_dot`` was measured beside it on a v5e at
-the published widths, 32,768 rows over 64 groups (PERF.md, PR 25) and not
+the published widths, 32,768 rows over 64 groups (my chip runs, PR 25, in
+PERF.md §6) and not
 kept: forward and backward of the three matmuls take 32.1 ms through
 ``ragged_dot`` (its transposes are the slow part: 6.7 ms forward) and 14.3 ms
 through ``gmm`` at tiles of (256, 1024, 1024), against 146.6 at the kernel's
@@ -129,7 +130,7 @@ def _permute_bwd(res, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-GMM_TILES = (256, 1024, 1024)  # rows, contraction, columns: measured on the v5e (module docstring)
+GMM_TILES = (256, 1024, 1024)  # rows, contraction, columns: the best of six tried on the v5e (module docstring)
 
 
 def grouped_matmul(

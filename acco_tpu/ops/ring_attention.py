@@ -328,8 +328,7 @@ def zigzag_ring_attention(
     (plus two diagonal triangles on the self hop) instead of one fully
     masked-out Lc x Lc block — ~2x less attention compute than
     :func:`ring_attention` at identical semantics, and the work is uniform
-    across devices so no one gates the ring (striped/zig-zag balancing;
-    ADVICE round 1 'causal load imbalance').
+    across devices so no one gates the ring (striped/zig-zag balancing).
 
     Which (q-half, kv-half) pairs are live depends only on whether the
     hop wrapped around the ring, so the two computed blocks are selected

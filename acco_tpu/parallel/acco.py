@@ -18,8 +18,8 @@ single jitted ``shard_map`` program with two data-independent branches —
 Neither branch reads the other's outputs, so the communication can in
 principle run while the MXU computes — the overlap the reference gets
 from its com_thread/com_stream. Whether it actually happens is a
-compiler/scheduling property, and it was MEASURED here rather than
-assumed (round-1 VERDICT Weak #4): on the target libtpu the stock
+compiler/scheduling property, read off the compiled schedule rather
+than assumed: on the target libtpu the stock
 ``psum_scatter``/``all_gather`` lower to blocking all-reduces scheduled
 after the compute (no overlap). ``comm_impl='ring'`` re-expresses both
 collectives as bidirectional ppermute rings
@@ -28,7 +28,9 @@ collective-permute-start/done pairs, and with the layer scan unrolled
 (``scan_unroll=True``) the latency-hiding scheduler provably places the
 fwd/bwd compute inside the in-flight windows — see OVERLAP.md and
 tools/overlap_hlo.py (28/28 windows carry compute on a v5e-8 AOT
-compile). Host races are impossible by construction either way
+compile). What the chip then exposes is a benchmark metric,
+``exposed_collective_ms`` (``neo27b-l4-dp4``: 9.33 ms a round, beside
+DDP's 14.45: ledger, PR 26). Host races are impossible by construction either way
 (SURVEY.md §5 'race detection': no threads, one compiled program).
 
 Round semantics preserved exactly (SURVEY.md §3.2):
@@ -716,7 +718,8 @@ class AccoTrainStep:
         is traced from ``state.round_idx``. True (even/speculative) or
         False (odd/commit) compiles a parity-specialized program — the
         rollback/zeroing selects over the full flat vectors fold away
-        (measured win on v5e; the host loop alternates the two). The
+        (the host loop alternates the two; every benchmark cell runs this
+        pair, so what it wins over the generic program is unmeasured). The
         caller owns keeping the call parity consistent with
         ``state.round_idx``; in DPU mode all three are the same program.
         """

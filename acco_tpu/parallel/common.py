@@ -88,7 +88,7 @@ def health_specs() -> HealthState:
 def abstract_health(mesh) -> HealthState:
     """Aval-only health leaves (ShapeDtypeStruct + replicated sharding) —
     for tools that hand-build abstract train states (overlap_hlo,
-    hbm_check, step_estimate)."""
+    hbm_check)."""
     from jax.sharding import NamedSharding
 
     return jax.tree.map(
@@ -255,8 +255,8 @@ def accumulate_grads(
         if n_acc == 1:
             # The flagship pretrain config runs one microbatch per
             # half-round; a length-1 lax.scan still compiles to a while
-            # loop wrapping the whole fwd/bwd (time-neutral when measured,
-            # but the while op walls the body off from the round-level
+            # loop wrapping the whole fwd/bwd (its cost in time is
+            # unmeasured, but the while op walls the body off from the round-level
             # latency-hiding scheduler, which matters for the
             # ring-collective overlap). Inline it.
             (grad_sum, count), (loss, terms) = micro(
@@ -515,7 +515,8 @@ def synthetic_block(
     seed: int = 0, seq_axis: Optional[str] = None,
 ) -> dict:
     """Random-token microbatch block laid out over the mesh — the shared
-    input builder for bench.py and the driver dry run."""
+    input builder for the driver dry run (__graft_entry__.py) and the
+    tensor-parallel tests."""
     import numpy as np
 
     rng = np.random.default_rng(seed)

@@ -160,7 +160,7 @@ def _topology_grid(names, sizes, devices) -> np.ndarray:
         # Effectively 1-D (the plain-dp flagship case): the collective
         # that rides this axis is the bidirectional ppermute RING
         # (ring_collectives.py), and create_device_mesh optimizes
-        # generic all-reduce, not ring adjacency (measured on a v5e
+        # generic all-reduce, not ring adjacency (counted on a v5e
         # 2x4: its 1-D order leaves 4 non-neighbor hops where a
         # perimeter cycle has 0). Use a Hamiltonian cycle on the chip
         # grid when one exists.

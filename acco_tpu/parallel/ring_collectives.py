@@ -1,6 +1,6 @@
 """Ring reduce-scatter / all-gather built from ``lax.ppermute``.
 
-Why these exist (round-2 overlap work, VERDICT #2): on the target libtpu,
+Why these exist: on the target libtpu,
 ``lax.psum_scatter`` and ``lax.all_gather`` on the big flat ZeRO-1 vector
 lower to *blocking* all-reduce ops (pincer emitter) that the latency-hiding
 scheduler cannot move — the compiled ACCO round ran compute, then comm,
@@ -33,7 +33,8 @@ therefore take ``lax.dynamic_slice`` at ``c * S`` (fused into the hop's
 add) and write each gathered chunk once with
 ``lax.dynamic_update_slice`` into one ``[n*S]`` output, in place.
 Measured on a v5e 2x2 at dp=4, GPT-Neo-2.7B widths, S = 112,145,280
-(PERF.md, PR 24, against the ledger's PR 23): device self time under
+(my chip runs, PR 24, in PERF.md, against the ledger's PR 23; the
+ledger's PR 24 lines repeat the rates): device self time under
 ``acco/reduce_scatter`` 78.8 -> 7.3 ms a round, under
 ``acco/all_gather`` 63.9 -> 12.6 ms, the round 390.7 -> 274.4 ms, ACCO
 20,921 -> 29,748 and DDP 20,768 -> 28,965 tokens/s/chip; the compiled
@@ -42,10 +43,10 @@ four loops and 2.93 GiB (tests/test_ring_layout_aot.py holds it to
 that). Offsets are int32, so a device's vector stays under 2**31
 elements (a ``ValueError`` at trace time otherwise).
 
-**Hierarchical rings for large axes** (ESTIMATES.md dp=32 caveat): the
-XLA async-collective conversion gives up on long unrolled rings —
-measured 28/60/0 async start/done pairs at 8/16/32 devices for the SAME
-model — so past ``_FLAT_RING_MAX`` devices the collectives run as two
+**Hierarchical rings for large axes**: the XLA async-collective
+conversion gives up on long unrolled rings (28/60/0 async start/done
+pairs counted in the programs compiled for 8/16/32 devices, the SAME
+model; ``tests/test_ring_canary.py``), so past ``_FLAT_RING_MAX`` devices the collectives run as two
 nested rings over a ``g x m`` factorization (intra-group then
 inter-group, each phase <= _FLAT_RING_MAX hops, chunk ownership chosen
 strided so device ``d`` still ends with tiled chunk ``d``). Same
@@ -86,8 +87,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# Longest flat unrolled ring XLA still makes async (measured: 16 devices
-# = 60 async pairs OK, 32 devices = 0). Axes larger than this use the
+# Longest flat unrolled ring XLA still makes async (counted in the
+# compiled programs: 16 devices = 60 async pairs, 32 devices = 0). Axes larger than this use the
 # two-phase hierarchical ring.
 _FLAT_RING_MAX = 16
 

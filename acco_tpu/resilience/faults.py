@@ -1,4 +1,4 @@
-"""Fault injection: one registry for tests, configs, and bench chaos.
+"""Fault injection: one registry for tests, configs and chaos drills.
 
 Two layers, one implementation (ISSUE 7 satellite — the kill-mid-save /
 truncate / ShutdownAfterRounds helpers used to live only under
@@ -24,8 +24,7 @@ from operational drills:
 - :func:`send_self_sigterm` — real signal delivery.
 
 **Numerical faults** — the config-driven injector behind the
-``fault_injection:`` train-yaml key (and ``bench.py``'s
-``ACCO_BENCH_CHAOS``): :class:`FaultInjector` fires registered fault
+``fault_injection:`` train-yaml key: :class:`FaultInjector` fires registered fault
 kinds at chosen rounds of the train loop, poisoning the *inputs* or the
 *carried state* of the compiled round programs — never the programs
 themselves — so the in-program anomaly guard and the host watchdog are

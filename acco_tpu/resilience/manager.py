@@ -8,10 +8,10 @@ checkpoint stalled all three train loops for the full serialize + write
 pipeline overlap — OVERLAP.md). :class:`CheckpointManager` splits the
 save at its natural seam:
 
-* ``save()`` blocks only for Orbax's device->host snapshot (measured
-  ~15 ms on the CPU smoke vs ~90 ms for the full commit; the bench's
-  ``ckpt_async_stall_ms`` vs ``ckpt_sync_stall_ms``). The snapshot
-  happens *inside* the Orbax ``save()`` call, so the train loop may
+* ``save()`` blocks only for Orbax's device->host snapshot
+  (``ckpt_snapshot_ms``; the commit behind it is ``ckpt_commit_ms``. What
+  a save stalls the chip has no benchmark metric yet: ROADMAP S10). The
+  snapshot happens *inside* the Orbax ``save()`` call, so the train loop may
   immediately dispatch the next round even though the round programs
   donate their input state buffers — the checkpoint reads the copy,
   never the donated-away originals.

@@ -1,16 +1,16 @@
 """Closed-world metrics registry: every metric is declared or it raises.
 
-The repo's ledgers drifted the way ad-hoc dicts always do: ``bench.py``
-hand-enumerated its record keys in three places, the trainer passed
-health counters as loose ``extra=`` dicts, and TensorBoard tag strings
-lived at each call site. This registry applies the sharding rule
-engine's ethos to observability: the full set of counters / gauges /
-histograms the trainer, watchdog, compile cache, prefetcher, resilience
-manager, serve scheduler, and bench emit is *declared* below — name,
-kind, unit, help — and emitting an undeclared name raises
-:class:`UndeclaredMetricError`. ``analysis/metrics_gate.py`` proves the
-same property statically over every ``metrics.emit(...)`` call site, so
-a typo'd metric name cannot reach main.
+Ad-hoc dicts drift: the trainer passed health counters as loose
+``extra=`` dicts and TensorBoard tag strings lived at each call site.
+This registry applies the sharding rule engine's ethos to observability:
+the full set of counters / gauges / histograms the trainer, watchdog,
+compile cache, prefetcher, resilience manager and serve scheduler emit
+is *declared* below — name, kind, unit, help — and emitting an
+undeclared name raises :class:`UndeclaredMetricError`.
+``analysis/metrics_gate.py`` proves the same property statically over
+every ``metrics.emit(...)`` call site, so a typo'd metric name cannot
+reach main, and the converse: a name declared here that no call site
+emits fails the gate and is deleted.
 
 Readers: ``value()`` / ``scalar()`` / ``quantile()`` / ``snapshot()``
 in process, and one sink, ``to_prometheus_text()`` — the serve
@@ -254,16 +254,12 @@ DECLARED: Tuple[MetricSpec, ...] = (
     _spec("train_moe_max_load", GAUGE, "ratio",
           "most loaded expert's share of a sequence's assignments x num_experts "
           "(1.0 = balanced)"),
-    # -- checkpointing (resilience/manager.py; bench phase keys) --
+    # -- checkpointing (resilience/manager.py) --
     _spec("ckpt_saves_total", COUNTER, "saves", "checkpoints started"),
     _spec("ckpt_snapshot_ms", HISTOGRAM, "ms",
           "blocking device->host snapshot portion of save()"),
     _spec("ckpt_commit_ms", HISTOGRAM, "ms",
           "background finalize (write + meta commit + retention)"),
-    _spec("ckpt_async_stall_ms", GAUGE, "ms",
-          "bench: round stall added by one async checkpoint"),
-    _spec("ckpt_sync_stall_ms", GAUGE, "ms",
-          "bench: round stall added by one synchronous checkpoint"),
     # -- training-health watchdog (resilience/watchdog.py) --
     _spec("health_skipped_rounds", GAUGE, "rounds",
           "lifetime guard-skipped rounds (device counter)"),
@@ -275,8 +271,6 @@ DECLARED: Tuple[MetricSpec, ...] = (
           "grad-norm drift episodes"),
     _spec("health_rollbacks_total", COUNTER, "events",
           "auto-rollbacks performed"),
-    _spec("guard_overhead_pct", GAUGE, "pct",
-          "bench: step-time overhead of the in-program anomaly guard"),
     # -- compile cache (compile/cache.py) --
     _spec("compile_cache_requests_total", COUNTER, "compiles",
           "persistent-cache lookups"),
@@ -284,7 +278,7 @@ DECLARED: Tuple[MetricSpec, ...] = (
           "persistent-cache hits"),
     _spec("compile_cache_time_saved_s", COUNTER, "s",
           "compile seconds served from the persistent cache"),
-    # -- input pipeline (data/prefetch.py; bench phase key) --
+    # -- input pipeline (data/prefetch.py) --
     _spec("loader_blocks_total", COUNTER, "blocks",
           "microbatch blocks consumed from the prefetch source"),
     _spec("loader_block_wait_ms", HISTOGRAM, "ms",
@@ -328,7 +322,7 @@ DECLARED: Tuple[MetricSpec, ...] = (
           "serve chaos faults fired (resilience.faults serve kinds)"),
 )
 
-# The process-global registry: train, serve, bench, and the sinks all
+# The process-global registry: train, serve and the sinks all
 # share it, so one name means one metric everywhere.
 REGISTRY = MetricsRegistry(DECLARED)
 

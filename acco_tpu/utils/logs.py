@@ -82,10 +82,8 @@ def save_result(path_to_result_csv: str, dict_result: Dict[str, Any]) -> None:
     different config keys coexist (parity: logs_utils.py:83-138).
 
     Every row appended through this function is by definition a live
-    machine append, so it defaults ``provenance='measured'`` — the flag
-    that lets ledger consumers (step_estimate calibration) filter out
-    hand-restored rows, which carry
-    ``provenance='restored'`` (round-5 ADVICE #4)."""
+    machine append, so it defaults ``provenance='measured'``: a reader
+    can tell it from a row someone wrote by hand."""
     dict_result = dict(dict_result)
     dict_result.setdefault("provenance", "measured")
     rows: list[Dict[str, Any]] = []

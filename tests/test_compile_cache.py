@@ -349,7 +349,7 @@ def test_no_entry_point_makes_up_a_cache_path():
     checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     suspects = re.compile(r"mkdtemp|gettempdir|getpid|strftime|time\.time")
     for rel in (
-        "main.py", "bench.py", "serve.py", "chip_smoke.py",
+        "main.py", "serve.py", "chip_smoke.py",
         "tools/compile_report.py", "acco_tpu/compile/cache.py",
     ):
         with open(os.path.join(checkout, rel)) as f:

@@ -353,19 +353,73 @@ def test_metrics_gate_checks_named_scope_literals(call, findings):
     assert [f.rule for f in rep.findings] == ["undeclared-scope"] * findings
 
 
-def test_repo_metrics_gate_is_clean():
-    """The enforced baseline: every literal telemetry name in the
-    package, tools, and bench harness is declared — same walk
-    ``tools/lint.py --ci`` runs."""
-    from acco_tpu.analysis.metrics_gate import check_paths
+@pytest.fixture(scope="module")
+def repo_metrics_report():
+    """The walk ``tools/lint.py --ci`` runs, once for the cases below."""
+    from acco_tpu.analysis.metrics_gate import check_repo
 
-    rep = check_paths([
-        os.path.join(REPO, "acco_tpu"),
-        os.path.join(REPO, "tools"),
-        os.path.join(REPO, "bench.py"),
-    ])
+    return check_repo(REPO)
+
+
+def test_repo_metrics_gate_is_clean(repo_metrics_report):
+    """The enforced baseline: every literal telemetry name in the
+    package, the tools and the entry points is declared."""
+    rep = repo_metrics_report
     assert rep.ok, [str(f) for f in rep.findings]
     assert rep.checked > 40  # the subsystem's own call sites keep it honest
+
+
+def _declared_metric_names():
+    from acco_tpu.telemetry.metrics import DECLARED
+
+    return [spec.name for spec in DECLARED]
+
+
+@pytest.mark.parametrize("name", _declared_metric_names())
+def test_every_declared_metric_has_an_emit_site(repo_metrics_report, name):
+    """The gate's other direction, a case a name so a failure says which:
+    a declaration no call site emits is a measurement nothing takes.
+    Delete it (or emit it where it is measured); there is no exemption."""
+    assert name not in repo_metrics_report.orphaned([name])
+
+
+def test_metrics_gate_reports_a_declared_name_nothing_emits():
+    """Seeded: the fixture emits two declared names; held to the whole of
+    DECLARED, every other name is orphaned, and the gate says so."""
+    from acco_tpu.analysis.metrics_gate import check_file
+    from acco_tpu.telemetry.metrics import REGISTRY
+
+    rep = check_file(os.path.join(FIXTURES, "bad_metrics.py"))
+    orphans = rep.orphaned(REGISTRY.declared_names())
+    assert "train_rounds_total" not in orphans and "train_loss" not in orphans
+    assert "ckpt_saves_total" in orphans and "serve_ttft_ms" in orphans
+    assert len(orphans) == len(REGISTRY.declared_names()) - 2
+
+
+@pytest.mark.parametrize(
+    "source, declared, orphans",
+    [
+        # a conditional expression of literals spells both
+        ("metrics.emit('a_total' if hit else 'b_total', 1)",
+         ["a_total", "b_total", "c_total"], ["c_total"]),
+        # literal prefix + a dict literal's key: only names so spelled
+        ("terms = {'lb_loss': 1.0}\n"
+         "for k, v in terms.items():\n"
+         "    metrics.emit('train_' + k, v)",
+         ["train_lb_loss", "train_z_loss", "other_lb_loss"],
+         ["other_lb_loss", "train_z_loss"]),
+        # a variable name keeps nothing alive
+        ("metrics.emit(name, 1)", ["a_total"], ["a_total"]),
+        # emit_many's dict literal
+        ("metrics.emit_many({'a_total': 1, 'b_total': 2})",
+         ["a_total", "b_total"], []),
+    ],
+)
+def test_metrics_gate_orphans_by_call_shape(source, declared, orphans):
+    from acco_tpu.analysis.metrics_gate import check_file
+
+    rep = check_file("inline.py", source=source)
+    assert rep.orphaned(declared) == orphans
 
 
 def test_host_lint_suppression_markers():

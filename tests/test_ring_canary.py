@@ -2,8 +2,9 @@
 
 ``ring_collectives`` switches to hierarchical rings past 16 devices
 because THIS libtpu's async-collective conversion handles a 16-cycle
-ppermute chain but lowers the 32-participant case blocking (measured
-28/60/0 async pairs at 8/16/32 — ESTIMATES.md). That is a property of
+ppermute chain but lowers the 32-participant case blocking (28/60/0
+async pairs counted in the programs compiled for 8/16/32). That is a
+property of
 the compiler, not of this code: a libtpu upgrade can move the cliff in
 either direction and would otherwise only show up as a silent perf
 regression. These tests AOT-compile tiny probe programs (no chips
@@ -40,8 +41,7 @@ def _probe(case: str):
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     # tiny payload + few hops: the schedule structure, not the timing,
-    # is under test (the cliff is participant-count-driven, not payload —
-    # ESTIMATES.md probe)
+    # is under test (the cliff is participant-count-driven, not payload)
     return ast.literal_eval(proc.stdout.strip().splitlines()[-1])
 
 

@@ -158,8 +158,8 @@ def test_acco_round_ring_matches_xla(eight_devices):
 @pytest.mark.parametrize("n_dev", [27, 32])
 def test_hierarchical_ring_matches_stock_32_devices(n_dev):
     """Past _FLAT_RING_MAX the collectives run as two nested rings
-    (ESTIMATES.md dp=32 caveat: XLA stops making >16-hop unrolled rings
-    async); semantics must still match psum_scatter/all_gather tiled —
+    (XLA stops making >16-hop unrolled rings async:
+    tests/test_ring_canary.py); semantics must still match psum_scatter/all_gather tiled —
     including the strided chunk regrouping that preserves device d's
     ownership of tiled chunk d. 32 virtual devices in a subprocess (the
     suite's fixture pins 8)."""

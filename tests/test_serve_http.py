@@ -143,6 +143,26 @@ def _post_raw(port, payload, timeout=30):
         return e.code, json.loads(e.read() or b"{}"), dict(e.headers)
 
 
+def _wait_until(predicate, what, timeout_s=20.0):
+    """Bounded wait on the scheduler's own state. A sleep that stands for
+    "the request is active by now" is a guess the loaded tier-1 machine
+    breaks; the state says when."""
+    deadline = time.monotonic() + timeout_s
+    while not predicate():
+        assert time.monotonic() < deadline, f"never happened: {what}"
+        time.sleep(0.005)
+
+
+def _active(sched) -> int:
+    return sum(r is not None for r in sched.slots)
+
+
+def _long_context_engine(**kw):
+    """A stub whose context holds 512 tokens (the default holds 16), so a
+    request can be sized to miss a budget by a factor of ten, not two."""
+    return StubEngine(num_pages=160, max_pages_per_seq=128, **kw)
+
+
 @contextlib.contextmanager
 def _server(engine=None, request_timeout_s=30.0, **sched_kw):
     eng = engine or StubEngine(max_slots=2, num_pages=32)
@@ -188,24 +208,31 @@ def test_generate_input_validation_400s(stub_server):
 
 
 def test_shed_queue_full_gets_429_with_retry_after():
-    eng = StubEngine(max_slots=1, num_pages=32, decode_sleep_s=0.02)
+    eng = _long_context_engine(max_slots=1, decode_sleep_s=0.02)
     with _server(engine=eng, max_waiting=1, retry_after_s=3.0) as (
         port, sched, loop,
     ):
         results = []
 
-        def hit():
+        def hit(max_new_tokens):
             results.append(_post_raw(
-                port, {"tokens": [1], "max_new_tokens": 12}
+                port, {"tokens": [1], "max_new_tokens": max_new_tokens}
             ))
 
-        # 1 active + 1 waiting (queue full) + 1 shed
-        threads = [threading.Thread(target=hit) for _ in range(3)]
+        # 1 active + 1 waiting (queue full) + 1 shed, each arriving only
+        # once the scheduler holds the one before it. The first decodes
+        # for ~2 s (100 steps of 20 ms): two posts on the loopback fit in
+        # that many hundred times over.
+        threads = [
+            threading.Thread(target=hit, args=(n,)) for n in (100, 2, 2)
+        ]
+        threads[0].start()
+        _wait_until(lambda: _active(sched) == 1, "first request active")
+        threads[1].start()
+        _wait_until(lambda: len(sched.waiting) == 1, "second request queued")
+        threads[2].start()
         for t in threads:
-            t.start()
-            time.sleep(0.05)  # deterministic arrival order
-        for t in threads:
-            t.join(timeout=30)
+            t.join(timeout=60)
         statuses = sorted(s for s, _, _ in results)
         assert statuses == [200, 200, 429], statuses
         shed = next(r for r in results if r[0] == 429)
@@ -218,18 +245,17 @@ def test_zombie_timeout_cancels_and_frees_pages():
     """The 504 path must CANCEL the request in the scheduler — before
     ISSUE 20 the handler returned and the scheduler decoded a zombie to
     completion with its pages held."""
-    eng = StubEngine(max_slots=2, num_pages=32, decode_sleep_s=0.05)
-    with _server(engine=eng, request_timeout_s=0.2) as (port, sched, loop):
+    # 500 steps of 10 ms against a 0.5 s budget: missed tenfold. The
+    # fresh request at the end needs 2 steps: inside the budget 25 times.
+    eng = _long_context_engine(max_slots=2, decode_sleep_s=0.01)
+    with _server(engine=eng, request_timeout_s=0.5) as (port, sched, loop):
         status, body, _ = _post_raw(
-            port, {"tokens": [1], "max_new_tokens": 12}, timeout=30
+            port, {"tokens": [1], "max_new_tokens": 500}, timeout=30
         )
         assert status == 504 and "timed out" in body["error"]
         # regression lever: every page back in the free pool, no zombie
         # decode left running
-        deadline = time.time() + 5
-        while time.time() < deadline and sched.allocator.in_use:
-            time.sleep(0.02)
-        assert sched.allocator.in_use == 0
+        _wait_until(lambda: sched.allocator.in_use == 0, "pages freed")
         assert all(s is None for s in sched.slots)
         assert sched.cancelled == 1
         # and the loop still serves fresh work afterwards
@@ -275,7 +301,7 @@ def test_drain_endpoint_finishes_in_flight_then_stops():
 
         t = threading.Thread(target=hit)
         t.start()
-        time.sleep(0.03)  # request is in flight
+        _wait_until(lambda: _active(sched) == 1, "request in flight")
         req = urllib.request.Request(
             f"http://127.0.0.1:{port}/admin/drain",
             data=json.dumps({"budget_s": 10}).encode(),
@@ -306,19 +332,19 @@ def test_drain_endpoint_finishes_in_flight_then_stops():
 
 
 def test_drain_cancels_stragglers_over_budget():
-    eng = StubEngine(max_slots=2, num_pages=32, decode_sleep_s=0.05)
+    eng = _long_context_engine(max_slots=2, decode_sleep_s=0.01)
     with _server(engine=eng) as (port, sched, loop):
         results = []
 
         def hit():
             results.append(_post_raw(
-                port, {"tokens": [3], "max_new_tokens": 12}, timeout=30
+                port, {"tokens": [3], "max_new_tokens": 500}, timeout=30
             ))
 
         t = threading.Thread(target=hit)
         t.start()
-        time.sleep(0.06)
-        summary = loop.drain(budget_s=0.1)  # far less than ~0.6s of decode
+        _wait_until(lambda: _active(sched) == 1, "request in flight")
+        summary = loop.drain(budget_s=0.2)  # a 25th of the ~5 s of decode
         t.join(timeout=30)
         assert summary["drained"] and not summary["in_budget"]
         assert summary["cancelled"] == 1

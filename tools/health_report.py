@@ -1,17 +1,16 @@
 """Summarize training-health / robustness counters across runs.
 
-The watchdog writes its counters into two existing ledgers — the
-per-run ``results.csv`` row (``skipped_rounds`` / ``rollbacks`` /
-``grad_norm_spikes`` / ``grad_norm_drifts``) and ``bench.py``'s JSON
-record (``guard_overhead_pct`` / ``skipped_rounds`` / ``chaos``). This
-tool reads both back and prints one robustness table, so BENCH_* rounds
-can track guard overhead and skip/rollback behavior the same way they
-track tokens/sec — no JAX import, safe on any machine.
+The watchdog writes its counters into a run's ``results.csv`` row
+(``skipped_rounds`` / ``rollbacks`` / ``grad_norm_spikes`` /
+``grad_norm_drifts``; the trainer writes the file into its run
+directory), and ``tools/load_harness.py`` writes a ``serve_load`` record
+with the serving counters. This tool reads both back and prints one
+robustness table — no JAX import, safe on any machine.
 
 Usage::
 
-    python tools/health_report.py                    # ./results.csv + BENCH_*.json
-    python tools/health_report.py --results path.csv BENCH_r05.json ...
+    python tools/health_report.py --results <run_dir>/results.csv
+    python tools/health_report.py <serve_load record>.json ...
 """
 
 from __future__ import annotations
@@ -28,11 +27,6 @@ HEALTH_COLUMNS = (
     "rollbacks",
     "grad_norm_spikes",
     "grad_norm_drifts",
-)
-BENCH_FIELDS = (
-    "guard_overhead_pct",
-    "skipped_rounds",
-    "chaos",
 )
 # serve_load records (tools/load_harness.py) carry the serving
 # robustness counters instead of the training ones
@@ -75,7 +69,7 @@ def report_results_csv(path: str) -> list[str]:
     lines.append(
         "  {:<24} {:>7} {:>9} {:>6} {:>6}  {}".format(
             "id_run", "skipped", "rollback", "spike", "drift",
-            "method/bench"
+            "method"
         )
     )
     for r in health_rows:
@@ -86,14 +80,14 @@ def report_results_csv(path: str) -> list[str]:
                 _fmt(r.get("rollbacks")),
                 _fmt(r.get("grad_norm_spikes")),
                 _fmt(r.get("grad_norm_drifts")),
-                _fmt(r.get("method_name") or r.get("bench")),
+                _fmt(r.get("method_name")),
             )
         )
     return lines
 
 
 def _record_from_text(text: str):
-    """First line that parses as a dict carrying a bench metric."""
+    """First line that parses as a dict carrying a ``metric`` key."""
     for line in text.splitlines():
         line = line.strip()
         if not line or line.startswith("#"):
@@ -115,39 +109,28 @@ def report_bench_json(path: str) -> list[str]:
         return [f"{path}: unreadable ({exc})"]
     rec = None
     try:
-        # BENCH_r*.json: a driver wrapper object whose "tail" string
-        # holds the harness stdout (the JSON record line among it).
-        whole = json.loads(text)
-        if isinstance(whole, dict):
-            if "metric" in whole:
-                rec = whole
-            elif isinstance(whole.get("tail"), str):
-                rec = _record_from_text(whole["tail"])
+        whole = json.loads(text)  # the record file load_harness writes
+        if isinstance(whole, dict) and "metric" in whole:
+            rec = whole
     except json.JSONDecodeError:
         pass
     if rec is None:
         # raw harness output: the record is its own line
         rec = _record_from_text(text)
-    if rec is None:
-        return [f"{path}: no bench record found"]
-    if rec.get("metric") == "serve_load":
-        fields = ", ".join(
-            f"{k}={_fmt(rec.get(k))}" for k in SERVE_BENCH_FIELDS
-        )
-        return [f"{os.path.basename(path)}: serve_load — {fields}"]
-    fields = ", ".join(f"{k}={_fmt(rec.get(k))}" for k in BENCH_FIELDS)
-    step = rec.get("acco_step_ms")
-    return [
-        f"{os.path.basename(path)}: {rec.get('metric')} "
-        f"(step={_fmt(step)} ms) — {fields}"
-    ]
+    if rec is None or rec.get("metric") != "serve_load":
+        return [f"{path}: no serve_load record found"]
+    fields = ", ".join(
+        f"{k}={_fmt(rec.get(k))}" for k in SERVE_BENCH_FIELDS
+    )
+    return [f"{os.path.basename(path)}: serve_load — {fields}"]
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
         "bench_json", nargs="*",
-        help="bench JSON files (default: ./BENCH_*.json)",
+        help="serve_load record files (default: ./BENCH_*.json, where "
+             "tools/load_harness.py writes by default)",
     )
     ap.add_argument("--results", default="results.csv")
     args = ap.parse_args(argv)
@@ -163,7 +146,7 @@ def main(argv=None) -> int:
     lines = ["== training-health report =="]
     lines += report_results_csv(results)
     lines.append("")
-    lines.append(f"bench records ({len(bench_paths)}):")
+    lines.append(f"serve_load records ({len(bench_paths)}):")
     for path in bench_paths:
         lines += ["  " + l for l in report_bench_json(path)]
     print("\n".join(lines))

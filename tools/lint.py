@@ -36,7 +36,7 @@ Exit status is nonzero iff any gate fails.
 production ACCO round on the TPU toolchain (libtpu, no chips; minutes
 per dp size) and runs the async-overlap verdict at dp=8/16/32. The
 dp=32 failure is the RECORDED baseline (this libtpu's device-count async
-gate refuses to form pairs there — ROADMAP item 1, ESTIMATES.json): the
+gate refuses to form pairs there — ROADMAP "dp≥32 overlap wall"): the
 lane exits 0 when dp=8/16 pass and dp=32 fails *as expected*, and
 prints loudly if dp=32 ever starts passing so the baseline can be
 retired. The overlap analyzer itself is regression-tested in tier-1
@@ -153,14 +153,11 @@ def gate_slow_markers() -> Gate:
 def gate_metrics() -> Gate:
     """Every literal-named telemetry call site across the production
     sources must name a declared metric (telemetry/metrics.py DECLARED)
-    or span (telemetry/trace.py SPAN_NAMES)."""
-    from acco_tpu.analysis.metrics_gate import check_paths
+    or span (telemetry/trace.py SPAN_NAMES), and every declared metric
+    must have a call site that emits it."""
+    from acco_tpu.analysis.metrics_gate import check_repo
 
-    rep = check_paths([
-        os.path.join(REPO, "acco_tpu"),
-        os.path.join(REPO, "tools"),
-        os.path.join(REPO, "bench.py"),
-    ])
+    rep = check_repo(REPO)
     return Gate(
         name="metrics-gate", ok=rep.ok,
         detail=[str(f) for f in rep.findings],

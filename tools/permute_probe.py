@@ -1,9 +1,9 @@
 """Pin down WHAT makes XLA lower ``collective-permute`` blocking at 32
-devices (ESTIMATES.md dp=32 caveat).
+devices.
 
-Round-3 measurements established the cliff (28/60/0 async start/done
-pairs at 8/16/32 chips, model-size-independent, flag-immune) but not the
-trigger. This probe AOT-compiles minimal shard_map programs — one
+Counting the compiled programs established the cliff (28/60/0 async
+start/done pairs at 8/16/32 chips, model-size-independent, flag-immune)
+but not the trigger. This probe AOT-compiles minimal shard_map programs — one
 ppermute chain + independent matmul compute to overlap — with controlled
 permutation-table structure, and counts async pairs in the scheduled
 HLO:
