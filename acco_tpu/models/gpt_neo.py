@@ -315,9 +315,15 @@ class GPTNeoModel:
         # the scope holds the scan itself, not only its body: stacking the
         # layers' saved activations and slicing them back out in the
         # backward pass is the block stack's time too
+        # The qkv thirds become ONE [D, 3 Dh] matrix a layer before the scan
+        # (the block's own flatten is then the identity): a [D, 3, Dh] slice
+        # crossing the loop is tiled T(4,128) over its 3 rows, a pass over the
+        # leaf each way between the flat vector's slab and the matmul.
+        layers = dict(params["layers"])
+        layers["w_qkv"] = layers["w_qkv"].reshape(*layers["w_qkv"].shape[:2], -1)
         with jax.named_scope("model/block"):
             x, _ = jax.lax.scan(
-                body, x, (params["layers"], windows), unroll=self.scan_unroll
+                body, x, (layers, windows), unroll=self.scan_unroll
             )
         return layer_norm(x, params["lnf_scale"], params["lnf_bias"], eps)
 

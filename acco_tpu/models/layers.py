@@ -3,8 +3,8 @@
 Models here are *pure pytrees + functions*, not framework modules: ACCO's
 machinery lives on the flat 1-D parameter vector (ZeRO-1 slice geometry,
 reduce-scatter/all-gather staging — `/root/reference/trainer_base.py:
-284-332`), and `jax.flatten_util.ravel_pytree` over a plain dict pytree is
-the cheapest bridge between the two views.
+284-332`), and a plain dict pytree is what `parallel/flat_layout.py` needs
+to be the bridge between the two views (it reads the leaves' shapes alone).
 
 TPU-first layout choices:
 - **stacked layers**: every per-layer leaf carries a leading ``n_layers``

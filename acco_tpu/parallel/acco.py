@@ -64,7 +64,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from acco_tpu.ops.adamw import AdamWState
@@ -80,6 +79,7 @@ from acco_tpu.parallel.common import (
     world_mean_loss,
     world_mean_terms,
 )
+from acco_tpu.parallel.flat_layout import FlatLayout
 from acco_tpu.parallel.mesh import DATA_AXIS
 from acco_tpu.parallel.zero1 import (
     ShardGeometry,
@@ -243,6 +243,9 @@ class AccoTrainStep:
             self.tp = mesh.shape[self.model_axis] if self.model_axis else 1
         self.tp_layout = None  # built in init_state when a model axis is set
         self.geom: ShardGeometry | None = None
+        # the order of the flat vector's elements (parallel/flat_layout.py);
+        # None under a model axis, where tp_layout owns a row-major order
+        self.layout: FlatLayout | None = None
         self.unravel = None
         self._round: dict = {}
         self._seed = None
@@ -288,8 +291,10 @@ class AccoTrainStep:
                 specs.zero1.opt.params,
             )
         else:
-            flat, self.unravel = ravel_pytree(cast)
-            self.geom = ShardGeometry(flat.size, self.num_shards)
+            self.layout = FlatLayout(cast)
+            self.unravel = self.layout.unravel
+            flat = self.layout.ravel(cast)
+            self.geom = ShardGeometry(self.layout.n_flat, self.num_shards)
             Pp, ns = self.geom.padded_size, self.num_shards
             specs = self.state_specs()
             flat_all = self.geom.pad_flat(flat)

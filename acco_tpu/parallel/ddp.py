@@ -20,7 +20,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from acco_tpu.ops.adamw import AdamWState
@@ -36,6 +35,7 @@ from acco_tpu.parallel.common import (
     world_mean_loss,
     world_mean_terms,
 )
+from acco_tpu.parallel.flat_layout import FlatLayout
 from acco_tpu.parallel.mesh import DATA_AXIS
 from acco_tpu.parallel.zero1 import ShardGeometry, Zero1State, init_zero1_state, zero1_update_shard
 
@@ -127,6 +127,9 @@ class DDPTrainStep:
             self.tp = mesh.shape[self.model_axis] if self.model_axis else 1
         self.tp_layout = None
         self.geom: ShardGeometry | None = None
+        # the order of the flat vector's elements (parallel/flat_layout.py);
+        # None under a model axis, where tp_layout owns a row-major order
+        self.layout: FlatLayout | None = None
         self.unravel = None
         self._step = None
         # name -> jax.stages.Compiled, installed by the AOT warmup
@@ -165,8 +168,10 @@ class DDPTrainStep:
                 specs.zero1.opt.params,
             )
         else:
-            flat, self.unravel = ravel_pytree(cast)
-            self.geom = ShardGeometry(flat.size, self.num_shards)
+            self.layout = FlatLayout(cast)
+            self.unravel = self.layout.unravel
+            flat = self.layout.ravel(cast)
+            self.geom = ShardGeometry(self.layout.n_flat, self.num_shards)
             flat_all = self.geom.pad_flat(flat)
             zero1 = init_zero1_state(flat.astype(jnp.float32), self.geom)
         state = DDPState(

@@ -245,6 +245,9 @@ DECLARED: Tuple[MetricSpec, ...] = (
           "last boundary's global gradient norm"),
     _spec("train_grads_committed", GAUGE, "grads",
           "device-side committed-gradient counter at the last boundary"),
+    _spec("train_flat_bitcast_share", GAUGE, "ratio",
+          "share of the flat vector's elements in tile-ordered slabs "
+          "(parallel/flat_layout.py): static, set once at state init"),
     # the objective's auxiliary terms of a model with experts (models/llama.py
     # _aux_terms): the trainer emits "train_" + each term's name
     _spec("train_moe_lb_loss", GAUGE, "loss",

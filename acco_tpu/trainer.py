@@ -46,6 +46,11 @@ from acco_tpu.ops.schedules import get_schedule
 from acco_tpu.parallel.acco import AccoTrainStep
 from acco_tpu.parallel.common import BATCH_KEYS, batch_specs
 from acco_tpu.parallel.ddp import DDPTrainStep
+from acco_tpu.parallel.flat_layout import (
+    LAYOUT_META_KEY,
+    flat_layout_tag,
+    restore_flat_state,
+)
 from acco_tpu.parallel.mesh import (
     DATA_AXIS,
     SEQ_AXIS,
@@ -60,7 +65,7 @@ from acco_tpu.resilience import (
 )
 from acco_tpu.telemetry import ALL_DEVICE_SCOPES, Tracer, metrics, scope_table
 from acco_tpu.utils import logs as logs_utils
-from acco_tpu.utils.checkpoint import latest_checkpoint, restore_checkpoint
+from acco_tpu.utils.checkpoint import latest_checkpoint
 
 _module_log = logging.getLogger(__name__)
 
@@ -984,6 +989,15 @@ class DecoupledTrainer:
         # the state holds its own copies: the leaves would stay on the
         # device for the whole run (2 B a parameter: 1.2 GiB of a 626M model)
         del params
+        # a static fact of the layout, so one number a run: the share of the
+        # flat vector that unpack takes out as a bitcast (0 under tp / pp,
+        # whose layouts are row-major)
+        flat_bitcast_share = step.layout.bitcast_share if step.layout else 0.0
+        metrics.emit("train_flat_bitcast_share", flat_bitcast_share)
+        self.log.info(
+            "flat vector: layout %s, %d elements, flat_bitcast_share=%.6f",
+            flat_layout_tag(step), step.geom.n_params, flat_bitcast_share,
+        )
 
         # Join the background AOT warmup (started at construction and
         # overlapped with tokenize / loader setup / state init above):
@@ -1018,7 +1032,7 @@ class DecoupledTrainer:
                         "checkpoint ROOT to fall back to the newest "
                         "complete step instead"
                     )
-            state, meta = restore_checkpoint(path, state)
+            state, meta = restore_flat_state(path, state, step, log=self.log)
             self.log.info(
                 "Resumed from %s at %d grads", path, meta["count_grad_tot"]
             )
@@ -1078,6 +1092,7 @@ class DecoupledTrainer:
                 # including tp_layout, whose n_repl drives the replicated-
                 # prefix gradient psum under tensor parallelism
                 warm.geom, warm.unravel = step.geom, step.unravel
+                warm.layout = step.layout
                 warm.tp_layout = step.tp_layout
                 state, _ = warm.seed_fn()(state, source.next_block())
                 warm_round = warm.round_fn()
@@ -1615,6 +1630,7 @@ class DecoupledTrainer:
                 else 0
             ),
             "rollbacks": self._rollbacks,
+            "flat_bitcast_share": flat_bitcast_share,
         }
 
     # -- eval ---------------------------------------------------------------
@@ -2015,7 +2031,7 @@ class DecoupledTrainer:
         # may still be writing the very step dir we are about to
         # restore, and Orbax save/restore of one tree must not overlap.
         self.ckpt_manager.wait()
-        state, meta = restore_checkpoint(path, state)
+        state, meta = restore_flat_state(path, state, self.step_obj, log=self.log)
         self.train_loader.set_state(fence)
         new_source = PrefetchingBlockSource(
             self.train_loader,
@@ -2060,6 +2076,8 @@ class DecoupledTrainer:
             "elapsed_s": time.time() - t_beg,
             "method": self.method,
             "id_run": self.id_run,
+            # the order of the state's flat vectors (parallel/flat_layout.py)
+            LAYOUT_META_KEY: flat_layout_tag(self.step_obj),
             # exact data-iterator position (identical on every rank:
             # shards differ, the seed ladder and consumption don't).
             # Through the block source: the position of the last
@@ -2136,10 +2154,13 @@ class DecoupledTrainer:
         export is impossible (multi-host tensor parallelism)."""
         layout = getattr(self.step_obj, "tp_layout", None)
         if layout is None:
-            # flat_params is replicated; rank 0 holds the full vector.
+            # flat_params is replicated; rank 0 holds the full vector. The
+            # artifact stays in ravel_pytree's order, whatever the state's.
+            flat = np.asarray(
+                jax.device_get(state.flat_params)[: self.step_obj.geom.n_params]
+            )
             return np.asarray(
-                jax.device_get(state.flat_params)[: self.step_obj.geom.n_params],
-                dtype=np.float32,
+                self.step_obj.layout.to_row_major(flat), dtype=np.float32
             )
         if jax.process_count() == 1:
             # tp: flat_params is the tp-major stack of per-shard local

@@ -159,6 +159,12 @@ def validate_checkpoint(path: str) -> Optional[str]:
     return None
 
 
+def read_meta(path: str) -> dict:
+    """The ``meta.json`` of a ``step_*`` dir (validated ones have it)."""
+    with open(os.path.join(os.path.abspath(path), "meta.json")) as f:
+        return json.load(f)
+
+
 def latest_checkpoint(ckpt_dir: str, log=None) -> Optional[str]:
     """Newest *valid* ``step_*`` dir under ``ckpt_dir`` (fallback chain:
     incomplete and corrupt/truncated dirs are skipped and reported, and
@@ -248,9 +254,7 @@ def restore_checkpoint(path: str, abstract_state: Any) -> tuple[Any, dict]:
                 state = _restore_legacy_acco(ckptr, state_path, target)
             except Exception as legacy_exc:
                 raise legacy_exc from pre_watchdog_exc
-    with open(os.path.join(path, "meta.json")) as f:
-        meta = json.load(f)
-    return state, meta
+    return state, read_meta(path)
 
 
 def _fresh_health(template: Any) -> Any:
@@ -393,8 +397,39 @@ def _find_leaf(tree: Any, name: str):
     return None
 
 
-def load_flat_params(step_dir: str, n_params: int, log=None):
-    """Portable fp32 flat parameter vector from a ``step_*`` dir.
+def _to_row_major(step_dir: str, flat, template):
+    """A ``step_*`` dir's ``flat_params`` leaf in ``ravel_pytree`` order."""
+    from acco_tpu.parallel.flat_layout import (
+        LAYOUT_META_KEY,
+        ROW_MAJOR_TAG,
+        FlatLayout,
+    )
+
+    tag = read_meta(step_dir).get(LAYOUT_META_KEY, ROW_MAJOR_TAG)
+    if tag == ROW_MAJOR_TAG:
+        return flat
+    if template is None:
+        raise ValueError(
+            f"checkpoint {step_dir} holds its flat vector in layout {tag!r}: "
+            "reading it back needs the model's parameter tree (template=)"
+        )
+    layout = FlatLayout(template)
+    if layout.tag != tag:
+        raise ValueError(
+            f"checkpoint {step_dir} holds its flat vector in layout {tag!r}; "
+            f"this build reads {layout.tag!r} and {ROW_MAJOR_TAG!r}"
+        )
+    if flat.size < layout.n_flat:
+        raise ValueError(
+            f"checkpoint {step_dir} holds {flat.size} elements but the model's "
+            f"layout needs {layout.n_flat} — wrong model config for this checkpoint?"
+        )
+    return layout.to_row_major(flat[: layout.n_flat])
+
+
+def load_flat_params(step_dir: str, n_params: int, log=None, template=None):
+    """Portable fp32 flat parameter vector from a ``step_*`` dir, in
+    ``ravel_pytree`` order.
 
     Final saves export ``params.npz`` (rank 0, ``flat_params`` key) — the
     cheap path: a plain numpy load, no Orbax, no train-state template.
@@ -403,6 +438,10 @@ def load_flat_params(step_dir: str, n_params: int, log=None):
     buffers to describe) and digs out the ``flat_params`` leaf. Either
     way the vector may carry ZeRO alignment padding past ``n_params``;
     the caller's model-init template defines the real size, so trim.
+    ``params.npz`` is always in ``ravel_pytree`` order; the Orbax state is
+    in the order its ``meta.json`` names (parallel/flat_layout.py), and is
+    brought to ``ravel_pytree`` order through ``template``, the model's
+    parameter tree (arrays or avals).
     """
     import numpy as np
 
@@ -421,6 +460,7 @@ def load_flat_params(step_dir: str, n_params: int, log=None):
                 "checkpoint this build can serve from"
             )
         source = "orbax state (no params.npz — periodic save)"
+        flat = _to_row_major(step_dir, np.asarray(flat).reshape(-1), template)
     flat = np.asarray(flat, dtype=np.float32).reshape(-1)
     if flat.size < n_params:
         raise ValueError(

@@ -141,7 +141,9 @@ def main(argv=None):
 
     template = model.init(jax.random.PRNGKey(0))
     flat_template, unravel = ravel_pytree(template)
-    flat = load_flat_params(step_dir, int(flat_template.size), log=log)
+    flat = load_flat_params(
+        step_dir, int(flat_template.size), log=log, template=template
+    )
     params = unravel(jnp.asarray(np.asarray(flat), dtype=flat_template.dtype))
     del template, flat
     engine.set_params(params)

@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.flatten_util import ravel_pytree
 
 from acco_tpu.models import LlamaConfig, LlamaModel
 from acco_tpu.ops.schedules import get_schedule
@@ -131,8 +130,8 @@ def _micros_for(batch):
 @pytest.mark.parametrize("mode", ["acco", "dpu"])
 def test_trajectory_matches_simulator(eight_devices, mode):
     t, state, params = _make(mode)
-    flat, unravel = ravel_pytree(params)
-    loss_fn = make_flat_loss_fn(t.model, unravel, t.geom.n_params, 0.0)
+    flat = t.layout.ravel(params)  # the step's own order, not ravel_pytree's
+    loss_fn = make_flat_loss_fn(t.model, t.unravel, t.geom.n_params, 0.0)
     grad_fn = lambda fp, mb: np.asarray(
         jax.grad(loss_fn)(jnp.asarray(fp, jnp.float32), mb), np.float64
     )

@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.flatten_util import ravel_pytree
 
 from acco_tpu.models import LlamaConfig, LlamaModel
 from acco_tpu.ops.schedules import get_schedule
@@ -104,8 +103,8 @@ def test_one_step_matches_unsharded_math(trainer):
     new_state, metrics = step(state, batch)
 
     # Hand-compute: average grad over all ws*n_acc microbatches at params.
-    flat, unravel = ravel_pytree(params)
-    loss_fn = make_flat_loss_fn(model, unravel, flat.size, 0.0)
+    flat = t.layout.ravel(params)  # the step's own order, not ravel_pytree's
+    loss_fn = make_flat_loss_fn(model, t.unravel, flat.size, 0.0)
     flat_padded = t.geom.pad_flat(flat)
     total_g = np.zeros(t.geom.padded_size, np.float32)
     for a in range(N_ACC):
@@ -150,8 +149,8 @@ def test_heterogeneous_microbatch_mask(trainer):
     assert float(metrics.grads_this_step) == 8 * N_ACC - 1
 
     # equivalent dense computation: drop that microbatch, weight by count
-    flat, unravel = ravel_pytree(params)
-    loss_fn = make_flat_loss_fn(model, unravel, flat.size, 0.0)
+    flat = t.layout.ravel(params)  # the step's own order, not ravel_pytree's
+    loss_fn = make_flat_loss_fn(model, t.unravel, flat.size, 0.0)
     flat_padded = t.geom.pad_flat(flat)
     total_g = np.zeros(t.geom.padded_size, np.float32)
     for a in range(N_ACC):

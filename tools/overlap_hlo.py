@@ -149,20 +149,13 @@ def build_round(
     # Abstract state: init on the CPU backend only to learn shapes/geometry
     # (AOT topologies expose no addressable devices to put arrays on).
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    flat_size = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
+    from acco_tpu.parallel.flat_layout import FlatLayout
     from acco_tpu.parallel.zero1 import ShardGeometry
 
-    step.geom = ShardGeometry(flat_size, step.num_shards)
-    # unravel is only needed inside the loss; build it from a concrete
-    # CPU init of the same tiny-but-real pytree structure.
-    cpu = jax.devices("cpu")[0]
-    with jax.default_device(cpu):
-        concrete = model.init(jax.random.PRNGKey(0))
-    from jax.flatten_util import ravel_pytree
-
-    _, step.unravel = ravel_pytree(
-        jax.tree.map(lambda x: x.astype(jnp.bfloat16), concrete)
-    )
+    # the layout needs shapes only, and unravel is only needed inside the loss
+    step.layout = FlatLayout(params)
+    step.unravel = step.layout.unravel
+    step.geom = ShardGeometry(step.layout.n_flat, step.num_shards)
 
     Pp, ns, ws = step.geom.padded_size, step.num_shards, step.world_size
     specs = step.state_specs()
