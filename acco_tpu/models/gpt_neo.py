@@ -373,13 +373,14 @@ class GPTNeoModel:
 
         Returns ``(fused, banded_local, global_bias, local_bias)``.
         ``banded_local`` extends the banded window kernel to the EINSUM
-        plan: at L=2048 — GPT-Neo's max context — 'auto' resolves the
-        *global* layers to the einsum (the full-tile kernel is unmeasured
-        there: ROADMAP S1), but the local layers' einsum still
-        computes the whole [L, L] it masks ~(L-W)/L away; the banded
-        kernel (no L wall, parity-tested) replaces just those. Requires
-        mask-free batches (const-len) and a TPU (or the interpreter
-        env) — pallas can't run on CPU test meshes."""
+        plan: where 'auto' resolves the *global* layers to the einsum
+        (past the full-tile kernel's envelope, L > 2048: at 2048 the
+        fused plan measured 18-19% faster, ops/attention.py), the local
+        layers' einsum would still compute the whole [L, L] it masks
+        ~(L-W)/L away; the banded kernel (no L wall, parity-tested)
+        replaces just those. No benchmark cell reaches this plan
+        (ROADMAP D2). Requires mask-free batches (const-len) and a TPU
+        (or the interpreter env) — pallas can't run on CPU test meshes."""
         fused = (
             resolve_attention_impl(
                 self.attention, L, platform=self.platform,
@@ -488,9 +489,8 @@ class GPTNeoModel:
                 elif banded_local:
                     # einsum plan, banded local layers: global layers keep
                     # the einsum, local layers skip the
-                    # out-of-window score work entirely (L=2048 — GPT-Neo's
-                    # max context, where 'auto' doesn't pick the full-tile
-                    # kernel — computes a 5.3x-narrower band instead)
+                    # out-of-window score work entirely (past the full-tile
+                    # kernel's envelope, L > 2048)
                     from acco_tpu.ops.banded_attention import (
                         banded_dot_product_attention,
                     )

@@ -42,8 +42,10 @@ def wrap_remat(block, remat):
     wins a round is unmeasured: no benchmark cell sets it (ROADMAP D2).
     Anything else is a config error.
 
-    The 'dots' policy additionally saves the fused attention kernel's
-    named outputs (attn_out + attn_lse, ops/fused_attention.py): a
+    The 'dots' policy additionally saves the attention kernels' named
+    outputs (attn_out + attn_lse: ops/fused_attention.py,
+    ops/banded_attention.py and, for the stock flash kernel, whose own
+    ``custom_vjp`` names nothing, ops/attention._named_flash): a
     pallas_call is not a dot, so
     without the names the backward re-traces and reruns the forward
     kernel once per layer purely to regenerate its residuals. On the

@@ -15,12 +15,13 @@ dtype (bfloat16 on TPU) so they hit the MXU.
 :func:`flash_dot_product_attention` is the fused O(L)-memory alternative
 (JAX's bundled Pallas TPU flash kernel) behind the same call contract;
 :func:`resolve_attention_impl` picks between them by a rule on
-platform, length and remat policy whose thresholds are unmeasured
-(ROADMAP S1).
+platform, length and remat policy; its docstring says which of the
+rule's lines a chip run stands behind (ROADMAP S1).
 """
 
 from __future__ import annotations
 
+import functools
 import logging
 from typing import Optional
 
@@ -68,30 +69,43 @@ def resolve_attention_impl(
 
     - off the TPU: 'xla' (Pallas TPU kernels don't run there);
     - 'fused', the full-tile VMEM kernel (ops/fused_attention.py, no
-      [B, H, L, L] scores in HBM), when ``head_dim`` is known, the shape
-      fits the kernel's envelope AND ``seq_len <= 1024``;
-    - else 'flash' (the stock online-softmax kernel, O(L) memory) when
-      ``seq_len`` is a multiple of 512 and at least 2048 with remat off,
-      or at least 4096 under any remat policy (the kernel's O(L) memory
-      is itself the remat, so a policy's recompute on top of it is pure
-      overhead; ``remat`` is the model's policy: False | True | 'dots');
+      [B, H, L, L] scores in HBM), when ``head_dim`` is known and the
+      shape fits the kernel's envelope (L <= 2048);
+    - else 'flash' (the stock online-softmax kernel, O(L) memory, at the
+      tiles :func:`flash_block_sizes` chooses) when ``seq_len`` is a
+      multiple of 512 and at least 2048 with remat off, or at least 4096
+      under any remat policy (``remat`` is the model's policy: False |
+      True | 'dots');
     - else 'xla' (the einsum).
 
-    UNMEASURED: the three thresholds (1024, 2048, 4096) are guesses. The
-    fused kernel's envelope takes L = 2048 and no cell has run it there;
-    the benchmark's cells sit at L = 1024 ('fused'; ``attn_kernel_ms``
-    18.08 of a 97.55 ms round at ``attn_kernel_roofline`` 12.2%: ledger,
-    PR 24) and at L = 2048 under ``remat=dots`` ('xla' for the global
-    layers), one side of each line only. ROADMAP S1 runs the other sides
-    (cell ``neo27b-l4-seq1024``, R4); D2 then makes the choice from shape
-    and deletes what loses.
+    MEASURED (my chip runs, PR 29; ``PERF.md`` §6): at L = 2048 under
+    ``remat=dots`` (GPT-Neo-2.7B at depth 4, 20 heads x 128) the fused
+    kernel on the global layers against the einsum, which this rule chose
+    there until then (``and seq_len <= 1024``), parent and change in one
+    call at one seed: one chip, batch 2, 29,620 -> 34,889 and 29,651 ->
+    34,914 tokens/s/chip (+17.8%, +17.7%); dp=4, batch 4, ACCO 31,228 ->
+    37,286 (+19.4%) and DDP 30,057 -> 35,666 (+18.7%), one pair;
+    ``loss_at_ref_round`` equal to 7e-4 relative or closer; the compiled dp=4
+    round's peak 13.20 -> 8.75 GB. The 1024 line went on that evidence.
+    Still UNMEASURED: the other two thresholds. The "4096 under any remat
+    policy" branch was reasoned from "the kernel's O(L) memory is itself
+    the remat, so a policy's recompute on top of it is pure overhead";
+    since PR 29 the 'dots' policies save the flash kernel's residuals
+    (:func:`_named_flash`), its forward runs once a layer, and that reason
+    is gone. With the fused kernel taking every aligned L <= 2048 the two
+    thresholds now decide only lengths past 2048 and head sizes the fused
+    kernel refuses; no shape's resolution was changed on the reasoning
+    alone, and the flash kernel at its new tiles (0.96 ms forward +
+    backward at ``[1, 16, 2048, 128]``) has not been timed against the
+    fused kernel at 2048 (ROADMAP S1).
 
     Sliding-WINDOW layers (GPT-Neo) have their own lane outside this
     rule: the banded kernel (ops/banded_attention.py) computes only
     the key band and is dispatched per layer by the model itself —
-    inside the 'fused' plan at L <= 1024, and as the local-layer branch
-    of the einsum plan past it (GPTNeoModel._dense_attn_plan) — so this
-    resolver only ever decides the GLOBAL layers' impl.
+    inside the 'fused' plan, and as the local-layer branch of the
+    einsum plan where the fused kernel's envelope ends
+    (GPTNeoModel._dense_attn_plan) — so this resolver only ever decides
+    the GLOBAL layers' impl.
     """
     impl = normalize_attention_impl(impl)
     remat = normalize_remat(remat)  # '0'/'false' must mean remat-OFF
@@ -106,10 +120,7 @@ def resolve_attention_impl(
     if head_dim is not None:
         from acco_tpu.ops.fused_attention import supports_fused_attention
 
-        # 'auto' prefers the bespoke kernel only up to L=1024, the shape
-        # class it was built for; past it the choice is unmeasured
-        # (docstring; ROADMAP S1).
-        if supports_fused_attention(seq_len, head_dim) and seq_len <= 1024:
+        if supports_fused_attention(seq_len, head_dim):
             return "fused"
     threshold = 2048 if remat in (False, None) else 4096
     if seq_len >= threshold and seq_len % 512:
@@ -178,6 +189,121 @@ def repeat_kv(
     return k, v
 
 
+def flash_block_sizes(seq_len: int, head_dim: int):
+    """The stock flash kernel's tile sizes, chosen from the shape.
+
+    jax 0.9.0's ``BlockSizes.get_default`` gives 128 for all eleven ("TODO:
+    select better parameters"): at L = 4096 a 32 x 32 grid of 128 x 128
+    score tiles a head, each a matmul too small to fill the MXU. The rule
+    here: ``b`` is the largest of 1024, 512, 256, 128 that divides
+    ``seq_len``, and ``h = min(b, 512)``;
+
+    - forward: ``block_q = block_k_major = block_k = b``;
+    - dK/dV: ``block_q_major = block_k_major = block_k = b``, ``block_q = h``;
+    - dQ: ``block_q = b``, ``block_k_major = block_k = h``.
+
+    The sweep behind it (my chip run, PR 29: one v5e chip, ``[1, 16, 4096,
+    128]`` bf16, causal, no segment ids; each kernel jitted alone, ms a call
+    by the host's clock over 20 calls, best of 3; 99 tile sets, every one
+    accepted by Mosaic). Forward as (block_q, block_k_major, block_k), dK/dV
+    as (block_q_major, block_q, block_k_major, block_k), dQ as (block_q,
+    block_k_major, block_k):
+
+    ===================  ====  ========================  ====  ==================  ====
+    forward              ms    dK/dV                     ms    dQ                  ms
+    ===================  ====  ========================  ====  ==================  ====
+    128, 128, 128        5.17  128, 128, 128, 128        5.87  128, 128, 128       4.67
+    256, 512, 256        1.46  256, 256, 512, 256        1.95  256, 512, 512       1.47
+    256, 1024, 1024      0.90  512, 512, 512, 512        1.36  512, 512, 512       1.25
+    512, 512, 512        0.80  1024, 512, 512, 512       1.37  1024, 512, 256      1.23
+    1024, 512, 512       0.80  512, 512, 1024, 1024      1.24  **1024, 512, 512**  1.20
+    512, 1024, 1024      0.78  1024, 1024, 1024, 1024    1.24  1024, 1024, 512     1.36
+    1024, 1024, 512      0.76  1024, 256, 1024, 1024     1.23  1024, 1024, 1024    1.37
+    **1024, 1024, 1024** 0.74  **1024, 512, 1024, 1024** 1.22  512, 2048, 512      1.78
+    1024, 2048, 1024     0.82  1024, 512, 2048, 1024     1.34  1024, 2048, 1024    1.76
+    ===================  ====  ========================  ====  ==================  ====
+
+    Past 128 the surface is flat (the best eight of each kernel lie within
+    10%); a ``block_k_major`` of 2048 loses everywhere, because a causal
+    tile on the diagonal is computed whole. Forward + backward through
+    ``jax.grad``, ms, the rule against one size for all eleven:
+
+    ====  =====  =====  =====  =====  ========
+    L     128    256    512    1024   the rule
+    ====  =====  =====  =====  =====  ========
+    2048  3.61   1.63   0.96   1.18   0.96
+    4096  15.04  6.22   3.25   3.23   3.03
+    8192  68.27  25.35  12.14  10.88  10.51
+    ====  =====  =====  =====  =====  ========
+
+    At 4096 the three kernels do 206 GFLOP (1.05 ms of the chip's peak) in
+    3.03 ms where the default took 15.04. ``head_dim`` was 128 throughout;
+    what a tile costs in VMEM is its ``[block_q, block_k]`` float32 scores,
+    which ``head_dim`` does not enter, and 64 compiles for the chip at the
+    same tiles (tests/test_tpu_compile.py), unmeasured.
+    """
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
+
+    del head_dim  # enters no tile's size: the docstring's last paragraph
+    if seq_len % 128:
+        raise ValueError(
+            f"the flash kernel tiles the sequence by 128; got seq_len={seq_len}"
+        )
+    b = next(size for size in (1024, 512, 256, 128) if seq_len % size == 0)
+    h = min(b, 512)
+    return BlockSizes(
+        block_q=b, block_k_major=b, block_k=b, block_b=1,
+        block_q_major_dkv=b, block_q_dkv=h, block_k_major_dkv=b, block_k_dkv=b,
+        block_q_dq=b, block_k_major_dq=h, block_k_dq=h,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _named_flash(q, k, v, segment_ids, scale, block_sizes):
+    """The stock kernel's forward-with-residuals and backward under a
+    ``custom_vjp`` of this repo's, for one reason: the residuals get the
+    names the 'dots' policies save (models/layers.wrap_remat), as
+    ops/fused_attention.py gives its own. The stock ``custom_vjp`` names
+    nothing, so under ``remat=dots`` every layer's forward kernel ran
+    twice (4.2 of 18.6 ms in ``olmoe-l1-acco-1chip``: ledger, PR 28)."""
+    return _named_flash_fwd(q, k, v, segment_ids, scale, block_sizes)[0]
+
+
+def _named_flash_fwd(q, k, v, segment_ids, scale, block_sizes):
+    from jax.ad_checkpoint import checkpoint_name
+    from jax.experimental.pallas.ops.tpu import flash_attention as stock
+
+    # jitted under the stock entry point's name: the chip's trace names a
+    # kernel by its innermost jit or scope (flash_attention.N), and that is
+    # what the benchmark's flash_attn_kernel_* metrics read
+    @jax.jit
+    def flash_attention(q, k, v, segment_ids):
+        return stock._flash_attention_impl(
+            q, k, v, None, segment_ids, True, True, scale,
+            block_sizes.block_b, block_sizes.block_q,
+            block_sizes.block_k_major, block_sizes.block_k, False,
+        )
+
+    out, l, m = flash_attention(q, k, v, segment_ids)
+    out = checkpoint_name(out, "attn_out")
+    # the softmax's running sum and maximum, [B, H, L] float32 each: the
+    # two halves of the log-sum-exp the repo's own kernels save
+    l, m = checkpoint_name(l, "attn_lse"), checkpoint_name(m, "attn_lse")
+    return out, (q, k, v, None, segment_ids, out, l, m)
+
+
+def _named_flash_bwd(scale, block_sizes, residuals, d_out):
+    from jax.experimental.pallas.ops.tpu import flash_attention as stock
+
+    dq, dk, dv, _, _ = stock._flash_attention_bwd(
+        False, True, scale, block_sizes, False, residuals, d_out
+    )
+    return dq, dk, dv, None
+
+
+_named_flash.defvjp(_named_flash_fwd, _named_flash_bwd)
+
+
 def flash_dot_product_attention(
     q: jax.Array,  # [B, H, L, D]
     k: jax.Array,  # [B, Hkv, L, D]
@@ -192,12 +318,10 @@ def flash_dot_product_attention(
     online-softmax tiles stay in VMEM (pallas_guide.md; this is what makes
     long sequences fit HBM at all). Padding is expressed as segment ids
     (pad tokens get segment 0, real tokens 1, cross-segment pairs are
-    masked), gradients flow through the kernel's custom VJP.
+    masked), gradients flow through the kernel's own backward kernels
+    (:func:`_named_flash`), at the tiles :func:`flash_block_sizes` gives.
     """
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        SegmentIds,
-        flash_attention as _pallas_flash,
-    )
+    from jax.experimental.pallas.ops.tpu.flash_attention import SegmentIds
 
     k, v = repeat_kv(q, k, v)  # the kernel wants equal head counts
     if scale is None:
@@ -206,7 +330,9 @@ def flash_dot_product_attention(
     if pad_mask is not None:
         ids = pad_mask.astype(jnp.int32)
         seg = SegmentIds(q=ids, kv=ids)
-    return _pallas_flash(q, k, v, segment_ids=seg, causal=True, sm_scale=scale)
+    return _named_flash(
+        q, k, v, seg, float(scale), flash_block_sizes(q.shape[2], q.shape[3])
+    )
 
 
 def cached_attention(

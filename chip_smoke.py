@@ -21,7 +21,9 @@ warm-relaunch check is only a check if the second run is a fresh process
 that finds the compile cache by its path alone.
 
 Jobs, one chip: the kernels against their einsum reference at the model's
-attention shape; ``train=acco``, ``dpu``, ``ddp``; the same ACCO job with
+attention shape, and the stock flash kernel at OLMoE's, at the tiles
+``ops.attention.flash_block_sizes`` gives it; ``train=acco``, ``dpu``,
+``ddp``; the same ACCO job with
 ``train.use_pallas_attention=false`` (plain XLA einsum attention, the test
 oracle) to compare losses with; the ACCO job again, which must compile
 nothing. Jobs, ``--chips 4``: DDP at dp=4 against DDP on one of the four
@@ -103,6 +105,9 @@ def real_size(chips: int) -> dict:
         "kernel_attention": "auto",
         "attention_shape": (8, 12, 1024, 64),
         "window": 256,
+        # the stock flash kernel at the tiles ops.attention.flash_block_sizes
+        # chooses, at the shape olmoe-l1-acco-1chip gives it
+        "flash_shape": (1, 16, 4096, 128),
     }
 
 
@@ -137,6 +142,7 @@ def rehearsal_size(chips: int) -> dict:
         "kernel_attention": "fused",
         "attention_shape": (2, 2, 128, 64),
         "window": 64,
+        "flash_shape": None,  # the stock kernel has no interpreter switch
     }
 
 
@@ -223,32 +229,41 @@ def kernels_job(size: dict, rehearse: bool) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from acco_tpu.ops.attention import attention_mask_bias, dot_product_attention
+    from acco_tpu.ops.attention import (
+        attention_mask_bias,
+        dot_product_attention,
+        flash_dot_product_attention,
+    )
     from acco_tpu.ops.banded_attention import banded_dot_product_attention
     from acco_tpu.ops.fused_attention import fused_dot_product_attention
 
-    B, H, L, D = size["attention_shape"]
     W = size["window"]
-    kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(0), 4)
-    draw = lambda key: (0.5 * jax.random.normal(key, (B, H, L, D))).astype(jnp.bfloat16)
-    q, k, v, g = draw(kq), draw(kk), draw(kv), draw(kg)
 
-    def oracle(window):
+    def oracle(L, window):
         bias = attention_mask_bias(L, window)
         return lambda q, k, v: dot_product_attention(q, k, v, bias, scale=1.0)
 
+    # name -> (shape, kernel, the window of its einsum oracle)
     pairs = {
         "fused (global)": (
+            size["attention_shape"],
             lambda q, k, v: fused_dot_product_attention(q, k, v, window=0, scale=1.0),
-            oracle(0),
+            0,
         ),
         f"banded (window {W})": (
+            size["attention_shape"],
             lambda q, k, v: banded_dot_product_attention(q, k, v, window=W, scale=1.0),
-            oracle(W),
+            W,
         ),
     }
+    if size["flash_shape"] is not None:
+        pairs["flash (stock kernel, tiles from the shape)"] = (
+            size["flash_shape"],
+            lambda q, k, v: flash_dot_product_attention(q, k, v, scale=1.0),
+            0,
+        )
 
-    def fwd_bwd(fn):
+    def fwd_bwd(fn, q, k, v, g):
         def run(q, k, v, g):
             out, vjp = jax.vjp(fn, q, k, v)
             return (out,) + vjp(g)
@@ -256,8 +271,12 @@ def kernels_job(size: dict, rehearse: bool) -> dict:
         return jax.jit(run)(q, k, v, g)
 
     report = {}
-    for name, (kernel, reference) in pairs.items():
-        got, want = fwd_bwd(kernel), fwd_bwd(reference)
+    for name, (shape, kernel, window) in pairs.items():
+        qkvg = [
+            (0.5 * jax.random.normal(key, shape)).astype(jnp.bfloat16)
+            for key in jax.random.split(jax.random.PRNGKey(0), 4)
+        ]
+        got, want = fwd_bwd(kernel, *qkvg), fwd_bwd(oracle(shape[2], window), *qkvg)
         errs = {}
         for label, a, b in zip(("out", "dq", "dk", "dv"), got, want):
             a, b = a.astype(jnp.float32), b.astype(jnp.float32)
@@ -266,7 +285,7 @@ def kernels_job(size: dict, rehearse: bool) -> dict:
             errs[label] = float(jnp.abs(a - b).max()) / scale
         report[name] = errs
         say(
-            f"kernel {name} vs einsum at (B,H,L,D)={(B, H, L, D)} bf16: max error "
+            f"kernel {name} vs einsum at (B,H,L,D)={shape} bf16: max error "
             + ", ".join(f"{k} {e:.2e}" for k, e in errs.items())
             + f" of the tensor's scale (tolerance 8 x 2^-8 = {KERNEL_TOL:.2e})"
         )

@@ -34,11 +34,54 @@ def test_resolve_auto():
 
 
 def test_resolve_auto_is_remat_aware():
-    # Measured v5e crossover (attention.py table): with a remat policy the
-    # flash kernel's bwd recompute loses to xla+dots until ~4k tokens.
+    # Where the fused kernel does not apply (no head_dim given here): under
+    # a remat policy the flash kernel waits for 4096, a line no chip run
+    # stands behind (the resolver's docstring).
     assert resolve_attention_impl("auto", 2048, "tpu", remat="dots") == "xla"
     assert resolve_attention_impl("auto", 4096, "tpu", remat="dots") == "flash"
     assert resolve_attention_impl("auto", 2048, "tpu", remat=False) == "flash"
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("seq_len", [512, 1536, 2048, 4096, 8192])
+def test_flash_block_sizes_fit_the_shape(seq_len, head_dim):
+    """The tiles are a function of the shape: every size divides the
+    sequence, every minor divides its major (``BlockSizes`` checks that
+    itself, at construction), the backward kernels have theirs, and no
+    kernel is left at the stock 128 x 128."""
+    import dataclasses
+
+    from acco_tpu.ops.attention import flash_block_sizes
+
+    sizes = flash_block_sizes(seq_len, head_dim)
+    assert sizes.has_backward_blocks and sizes.block_b == 1
+    tiles = {k: v for k, v in dataclasses.asdict(sizes).items() if k != "block_b"}
+    assert len(tiles) == 10
+    for name, size in tiles.items():
+        assert seq_len % size == 0 and size % 128 == 0, (name, size)
+        assert size >= min(seq_len, 512), (name, size)
+    for major, minor in [
+        ("block_k_major", "block_k"),
+        ("block_q_major_dkv", "block_q_dkv"),
+        ("block_k_major_dkv", "block_k_dkv"),
+        ("block_k_major_dq", "block_k_dq"),
+    ]:
+        assert tiles[major] % tiles[minor] == 0, (major, minor)
+    # the measured cell: [1, 16, 4096, 128], and the rule's other lengths
+    if seq_len % 1024 == 0:
+        assert (sizes.block_q, sizes.block_k_major, sizes.block_k) == (1024,) * 3
+        assert (
+            sizes.block_q_major_dkv, sizes.block_q_dkv,
+            sizes.block_k_major_dkv, sizes.block_k_dkv,
+        ) == (1024, 512, 1024, 1024)
+        assert (sizes.block_q_dq, sizes.block_k_major_dq, sizes.block_k_dq) == (1024, 512, 512)
+
+
+def test_flash_block_sizes_refuse_an_untiled_length():
+    from acco_tpu.ops.attention import flash_block_sizes
+
+    with pytest.raises(ValueError, match="by 128"):
+        flash_block_sizes(3000, 128)
 
 
 def test_resolve_rejects_unknown():

@@ -143,8 +143,8 @@ def test_gptneo_model_banded_matches_xla(monkeypatch):
 
 def test_gptneo_einsum_plan_banded_local_matches_xla(monkeypatch):
     """The einsum plan's banded-local dispatch (attention='auto' where
-    'auto' does NOT pick the full-tile kernel — e.g. CPU here, L=2048 on
-    chip): global layers keep the pure einsum path, local layers take
+    'auto' does NOT pick the full-tile kernel — the CPU here, L > 2048 on
+    the chip): global layers keep the pure einsum path, local layers take
     the banded kernel; logits match the explicit-'xla' model (which must
     stay the untouched einsum oracle)."""
     from acco_tpu.models.gpt_neo import GPTNeoConfig, GPTNeoModel

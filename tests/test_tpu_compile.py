@@ -18,6 +18,7 @@ this pytest process must stay off libtpu too. About 2-4 s a case here.
 
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -200,6 +201,20 @@ def _moe_experts(shape):
     )
 
 
+def _flash_attention(shape):
+    """The stock flash kernel at the tiles ``flash_block_sizes`` chooses."""
+    import jax.numpy as jnp
+
+    from acco_tpu.ops.attention import flash_dot_product_attention
+
+    B, H, L, D = shape
+
+    def loss(q, k, v):
+        return jnp.sum(flash_dot_product_attention(q, k, v).astype(jnp.float32) ** 2)
+
+    return _program(loss, (0, 1, 2), [((B, H, L, D), jnp.bfloat16)] * 3)
+
+
 def _olmoe_layer(remat):
     """One OLMoE layer at the published widths and 4096 positions (a small
     vocabulary: the head is not the point), as the benchmark's cell runs it:
@@ -276,11 +291,20 @@ CASES = {
     # sparse experts (ops/moe.py), [tokens, experts a token, experts, hidden, width]:
     # three grouped matmuls forward, two gradient matmuls each backward
     "moe_experts_olmoe": (_moe_experts, ((4096, 8, 64, 2048, 1024),), {}, dict(mosaic=9)),
+    # the stock flash kernel (ops/attention.py) at the tiles chosen from the
+    # shape, [B, H, L, D]: one forward and two backward kernels. The cell's
+    # shape; a length only 512 divides, at D=64; the longest length swept
+    "flash_attn_olmoe": (_flash_attention, ((1, 16, 4096, 128),), {}, dict(mosaic=3)),
+    "flash_attn_l1536_d64": (_flash_attention, ((2, 12, 1536, 64),), {}, dict(mosaic=3)),
+    "flash_attn_l8192": (_flash_attention, ((1, 8, 8192, 128),), {}, dict(mosaic=3)),
     # the 'dots' policy saves the grouped matmuls' named outputs (moe_gate,
-    # moe_up, moe_down): 9 grouped-matmul kernels and, for the stock flash
-    # kernel whose outputs carry no name, forward twice and its two backward
-    # kernels. Full remat runs the three forward grouped matmuls again.
-    "olmoe_layer_remat_dots": (_olmoe_layer, ("dots",), {}, dict(mosaic=13)),
+    # moe_up, moe_down) and the flash kernel's (attn_out, attn_lse, named by
+    # ops.attention._named_flash): 9 grouped-matmul kernels, ONE flash forward
+    # and its two backward kernels. 13 means the flash kernel's residuals lost
+    # their names and every layer's forward runs twice, as it did until PR 29.
+    # Full remat runs the flash forward and the three forward grouped matmuls
+    # again.
+    "olmoe_layer_remat_dots": (_olmoe_layer, ("dots",), {}, dict(mosaic=12)),
     "olmoe_layer_remat_full": (_olmoe_layer, (True,), {}, dict(mosaic=16)),
 }
 
@@ -330,7 +354,11 @@ def compile_all(out_path: str) -> None:
                 jax.jit(jax.grad(loss, argnums=prog["argnums"]))
                 .lower(*map(place, prog["avals"], specs)).compile().as_text()
             )
-            results[name] = {"hlo_mosaic": hlo.count(MOSAIC), "seconds": time.time() - t0}
+            results[name] = {
+                "hlo_mosaic": hlo.count(MOSAIC),
+                "kernels": re.findall(rf"%([\w.\-]+) = [^\n]*{MOSAIC}", hlo),
+                "seconds": time.time() - t0,
+            }
             absent = CASES[name][3].get("absent")
             if absent:
                 results[name]["absent_found"] = absent in hlo
@@ -370,6 +398,31 @@ def test_compiles_for_the_chip_with_a_mosaic_call(compiled, case):
     else:
         assert result["hlo_mosaic"] == expect["mosaic"], result
     assert not result.get("absent_found"), f"{expect.get('absent')} is in HBM"
+
+
+@pytest.mark.tpu_aot
+def test_the_olmoe_layers_flash_kernels_carry_the_chosen_tiles(compiled):
+    """The chip's trace names a kernel by its innermost scope, and the
+    stock backward kernels' scopes spell their tile sizes: the compiled
+    layer runs the tiles ``flash_block_sizes`` chose, none at the stock
+    128 x 128, under the names ``flash_attn_kernel_ms`` reads, and one
+    forward kernel (under ``remat=dots`` there were two until PR 29)."""
+    if "__skip__" in compiled:
+        pytest.skip(f"{TOPOLOGY} cannot be described here: {compiled['__skip__']}")
+    from acco_tpu.ops.attention import flash_block_sizes
+
+    t = flash_block_sizes(4096, 128)
+    result = compiled["olmoe_layer_remat_dots"]
+    assert "error" not in result, result["error"]
+    flash = sorted(k.rsplit(".", 1)[0] for k in result["kernels"] if k.startswith("flash_"))
+    assert flash == [
+        "flash_attention",
+        f"flash_mha_bwd_dkv_block_q_major_{t.block_q_major_dkv}_block_q_{t.block_q_dkv}"
+        f"_block_k_major_{t.block_k_major_dkv}_block_k_{t.block_k_dkv}",
+        f"flash_mha_bwd_dq_block_q_major_{t.block_q_dq}"
+        f"_block_k_major_{t.block_k_major_dq}_block_k_{t.block_k_dq}",
+    ]
+    assert not any("_128_block_q_128" in k for k in flash)  # the stock default's name
 
 
 if __name__ == "__main__":
