@@ -157,10 +157,11 @@ class GPTNeoModel:
         # body still serves both layer kinds), and removes the [B,H,L,L]
         # score HBM traffic entirely. 'auto' resolves to it per shape.
         # Local layers additionally dispatch (lax.cond in _block_body) to
-        # the BANDED kernel (ops/banded_attention): QB=128 q-row blocks
-        # against only their nprev+1 in-window key blocks: its band unit
-        # is far below the stock kernel's 512, so a 256-token window skips
-        # ~(L-W-QB)/L of the score work instead of masking it.
+        # the BANDED kernel (ops/banded_attention): a grid step takes whole
+        # heads (the rows, heads and tile banded_block_sizes chooses from
+        # the shape) and works through them a tile of query rows at a time,
+        # each against only its tile + W in-window keys: a 256-token window
+        # skips ~(L-W-tile)/L of the score work instead of masking it.
         self.attention = impl
         self.config = config
         self.param_dtype = param_dtype
@@ -467,7 +468,7 @@ class GPTNeoModel:
                         # serves all layers) but takes only two values: 0
                         # (global) and the STATIC config window. Branch at
                         # runtime; the local branch's banded kernel computes
-                        # only the [L, W+QB] key band instead of the full
+                        # only the [L, W+tile] key band instead of the full
                         # [L, L] tile it would mask ~3/4 away.
                         attn = jax.lax.cond(
                             window == 0,

@@ -21,7 +21,9 @@ warm-relaunch check is only a check if the second run is a fresh process
 that finds the compile cache by its path alone.
 
 Jobs, one chip: the kernels against their einsum reference at the model's
-attention shape, and the stock flash kernel at OLMoE's, at the tiles
+attention shape (the banded kernel at the 2.7B cells' shape too, at the steps
+``ops.banded_attention.banded_block_sizes`` gives it), and the stock flash
+kernel at OLMoE's, at the tiles
 ``ops.attention.flash_block_sizes`` gives it; ``train=acco``, ``dpu``,
 ``ddp``; the same ACCO job with
 ``train.use_pallas_attention=false`` (plain XLA einsum attention, the test
@@ -105,6 +107,9 @@ def real_size(chips: int) -> dict:
         "kernel_attention": "auto",
         "attention_shape": (8, 12, 1024, 64),
         "window": 256,
+        # the banded kernel also at the 2.7B cells' widths: the steps
+        # ops.banded_attention.banded_block_sizes chooses differ there
+        "banded_shapes": [(8, 12, 1024, 64), (2, 20, 2048, 128)],
         # the stock flash kernel at the tiles ops.attention.flash_block_sizes
         # chooses, at the shape olmoe-l1-acco-1chip gives it
         "flash_shape": (1, 16, 4096, 128),
@@ -142,6 +147,7 @@ def rehearsal_size(chips: int) -> dict:
         "kernel_attention": "fused",
         "attention_shape": (2, 2, 128, 64),
         "window": 64,
+        "banded_shapes": [(2, 2, 128, 64)],
         "flash_shape": None,  # the stock kernel has no interpreter switch
     }
 
@@ -250,12 +256,13 @@ def kernels_job(size: dict, rehearse: bool) -> dict:
             lambda q, k, v: fused_dot_product_attention(q, k, v, window=0, scale=1.0),
             0,
         ),
-        f"banded (window {W})": (
-            size["attention_shape"],
+    }
+    for shape in size["banded_shapes"]:
+        pairs[f"banded (window {W}, steps from the shape) at L={shape[2]} D={shape[3]}"] = (
+            shape,
             lambda q, k, v: banded_dot_product_attention(q, k, v, window=W, scale=1.0),
             W,
-        ),
-    }
+        )
     if size["flash_shape"] is not None:
         pairs["flash (stock kernel, tiles from the shape)"] = (
             size["flash_shape"],

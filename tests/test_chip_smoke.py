@@ -97,7 +97,9 @@ def test_kernels_job_at_a_tiny_size(monkeypatch, tmp_path):
     monkeypatch.setattr(chip_smoke, "WORK", str(tmp_path))
     monkeypatch.setenv("ACCO_FUSED_ATTN_INTERPRET", "1")
     report = chip_smoke.kernels_job(chip_smoke.rehearsal_size(1), rehearse=True)
-    assert set(report["kernel_errors"]) == {"fused (global)", "banded (window 64)"}
+    assert set(report["kernel_errors"]) == {
+        "fused (global)", "banded (window 64, steps from the shape) at L=128 D=64",
+    }
 
 
 def test_train_job_at_a_tiny_size(eight_devices, monkeypatch, tmp_path, capsys):

@@ -254,9 +254,19 @@ CASES = {
     # the envelope's ceiling: one 16 MB score tile
     "fused_attn_l2048": (_fused_attention, ((2, 12, 12, 2048, 64),), {}, {}),
     # banded attention (ops/banded_attention.py), [B, H, L, D, W]
-    "banded_attn_neo125m": (_banded_attention, ((8, 12, 1024, 64, 256),), {}, {}),
-    "banded_attn_neo27b": (_banded_attention, ((8, 20, 1024, 128, 256),), {}, {}),
-    "banded_attn_l4096": (_banded_attention, ((2, 2, 4096, 64, 256),), {}, {}),
+    # one forward and two backward kernels. The three shapes the cells run
+    # (neo125m-*, neo27b-l4-acco-1chip, neo27b-l4-dp4); 2.7B widths at a
+    # length no cell runs; a length past 2048, where a step takes a row block
+    # and the kernel computes its offsets from the grid index
+    "banded_attn_neo125m": (_banded_attention, ((8, 12, 1024, 64, 256),), {}, dict(mosaic=3)),
+    "banded_attn_neo27b_l2048_b2": (
+        _banded_attention, ((2, 20, 2048, 128, 256),), {}, dict(mosaic=3),
+    ),
+    "banded_attn_neo27b_l2048_b4": (
+        _banded_attention, ((4, 20, 2048, 128, 256),), {}, dict(mosaic=3),
+    ),
+    "banded_attn_neo27b": (_banded_attention, ((8, 20, 1024, 128, 256),), {}, dict(mosaic=3)),
+    "banded_attn_l4096": (_banded_attention, ((2, 2, 4096, 64, 256),), {}, dict(mosaic=3)),
     # fused lm-head + cross-entropy (ops/fused_ce.py), [B, L, D, V]
     "fused_ce_768x50257": (_fused_ce, ((8, 1024, 768, 50257),), {}, {}),
     "fused_ce_4096x128256": (_fused_ce, ((1, 512, 4096, 128256),), {}, {}),
@@ -423,6 +433,27 @@ def test_the_olmoe_layers_flash_kernels_carry_the_chosen_tiles(compiled):
         f"_block_k_major_{t.block_k_major_dq}_block_k_{t.block_k_dq}",
     ]
     assert not any("_128_block_q_128" in k for k in flash)  # the stock default's name
+
+
+@pytest.mark.tpu_aot
+@pytest.mark.parametrize(
+    "case", ["banded_attn_neo125m", "banded_attn_neo27b_l2048_b2", "banded_attn_neo27b_l2048_b4"]
+)
+def test_the_banded_kernels_carry_the_chosen_steps(compiled, case):
+    """Each banded kernel's name spells its grid step after the prefix the
+    benchmark's ``attn_kernel_ms`` finds it by: at the shapes the cells run,
+    the compiled kernels are the ones ``banded_block_sizes`` chose."""
+    if "__skip__" in compiled:
+        pytest.skip(f"{TOPOLOGY} cannot be described here: {compiled['__skip__']}")
+    from acco_tpu.ops.banded_attention import banded_block_sizes
+
+    _, H, L, D, W = CASES[case][1][0]
+    step = banded_block_sizes(L, W, D, H)
+    result = compiled[case]
+    assert "error" not in result, result["error"]
+    # under jax.grad the calls' names gain jvp_ / transpose_jvp_ in front
+    found = sorted(re.search(r"acco_banded_attn_\w+?(?=_*\.|_*$)", k)[0] for k in result["kernels"])
+    assert found == [f"acco_banded_attn_{kind}_{step.tag()}" for kind in ("dkv", "dq", "fwd")]
 
 
 if __name__ == "__main__":
