@@ -17,15 +17,19 @@ Entry points: ``setup_compilation_cache`` (main.py, tests/conftest.py),
 tools/compile_report.py), ``cache_stats``/``CacheStatsWindow``
 (observability and the cache-key stability tests),
 ``attribute_cache_events`` (exact per-program hit/miss attribution for
-the warmup records).
+the warmup records), ``trace_compiles`` (every backend compile of the
+process as a ``compile/backend`` span on the run's tracer) and
+``cache_dir_usage`` (how full the cache dir stands against its cap).
 """
 
 from acco_tpu.compile.cache import (
     CacheStatsWindow,
     active_cache_dir,
     attribute_cache_events,
+    cache_dir_usage,
     cache_stats,
     setup_compilation_cache,
+    trace_compiles,
 )
 from acco_tpu.compile.warmup import (
     CompileWarmup,
@@ -44,8 +48,10 @@ __all__ = [
     "active_cache_dir",
     "aot_call_with_fallback",
     "attribute_cache_events",
+    "cache_dir_usage",
     "cache_stats",
     "drain_abandoned_compiles",
     "setup_compilation_cache",
+    "trace_compiles",
     "warmup_programs",
 ]

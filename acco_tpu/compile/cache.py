@@ -61,6 +61,73 @@ _LISTENERS_INSTALLED = False
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 _SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
+# fired around compile_or_get_cached, on the thread that compiles, hit or
+# miss: once per program XLA was asked for
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# The run's tracer (trace_compiles): every backend-compile event lands on
+# it as a compile/backend span on the compiling thread's track. The
+# listeners are process-global and cannot be unregistered, so the tracer
+# is what changes, not the listener.
+_TRACER = None
+
+
+def trace_compiles(tracer) -> None:
+    """Write every backend-compile event of the process to ``tracer`` from
+    now on (None stops it): a warmup thread's inside its
+    ``compile/compile``, a lazy compile on the main thread bare."""
+    global _TRACER
+    _install_listeners()
+    _TRACER = tracer
+
+
+def _on_event(event: str, **kwargs) -> None:
+    if event == _HIT_EVENT:
+        key = "hits"
+    elif event == _REQUEST_EVENT:
+        key = "requests"
+    else:
+        return
+    # the event fires on the compiling thread: attribute it to that
+    # thread's registered window NOW, not via a later snapshot diff
+    target = getattr(_ATTRIBUTION, "target", None)
+    with _LOCK:
+        _COUNTS[key] += 1
+        if target is not None:
+            target[key] += 1
+    # registry mirror (declared names; its own lock — never taken under
+    # _LOCK, the registry emit locks internally)
+    metrics.emit(
+        "compile_cache_hits_total"
+        if key == "hits"
+        else "compile_cache_requests_total",
+        1,
+    )
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    if event == _SAVED_EVENT:
+        target = getattr(_ATTRIBUTION, "target", None)
+        with _LOCK:
+            _COUNTS["time_saved_s"] += float(duration)
+            if target is not None:
+                target["time_saved_s"] += float(duration)
+        # jax reports sub-ms NEGATIVE savings on trivial programs (cache
+        # overhead > compile time); the counter is monotone, so clamp —
+        # _COUNTS above keeps the signed truth.
+        metrics.emit("compile_cache_time_saved_s", max(0.0, float(duration)))
+    elif event == _BACKEND_EVENT:
+        tracer = _TRACER
+        if tracer is not None:
+            # duration from the event, end = now, thread = this one; of a
+            # compile that began before this tracer's clock did (an
+            # earlier run's abandoned warmup), the part on the clock
+            now_us = tracer.now_us()
+            start_us = max(0.0, now_us - float(duration) * 1e6)
+            tracer.complete_event(
+                "compile/backend", (now_us - start_us) / 1e3, cat="compile",
+                ts_us=start_us,
+            )
 
 
 def _install_listeners() -> None:
@@ -73,46 +140,8 @@ def _install_listeners() -> None:
             return
         from jax import monitoring
 
-        def on_event(event: str, **kwargs) -> None:
-            if event == _HIT_EVENT:
-                key = "hits"
-            elif event == _REQUEST_EVENT:
-                key = "requests"
-            else:
-                return
-            # the event fires on the compiling thread: attribute it to
-            # that thread's registered window NOW, not via a later
-            # snapshot diff
-            target = getattr(_ATTRIBUTION, "target", None)
-            with _LOCK:
-                _COUNTS[key] += 1
-                if target is not None:
-                    target[key] += 1
-            # registry mirror (declared names; its own lock — never
-            # taken under _LOCK, the registry emit locks internally)
-            metrics.emit(
-                "compile_cache_hits_total"
-                if key == "hits"
-                else "compile_cache_requests_total",
-                1,
-            )
-
-        def on_duration(event: str, duration: float, **kwargs) -> None:
-            if event == _SAVED_EVENT:
-                target = getattr(_ATTRIBUTION, "target", None)
-                with _LOCK:
-                    _COUNTS["time_saved_s"] += float(duration)
-                    if target is not None:
-                        target["time_saved_s"] += float(duration)
-                # jax reports sub-ms NEGATIVE savings on trivial programs
-                # (cache overhead > compile time); the counter is monotone,
-                # so clamp — _COUNTS above keeps the signed truth.
-                metrics.emit(
-                    "compile_cache_time_saved_s", max(0.0, float(duration))
-                )
-
-        monitoring.register_event_listener(on_event)
-        monitoring.register_event_duration_secs_listener(on_duration)
+        monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
         _LISTENERS_INSTALLED = True
 
 
@@ -202,6 +231,23 @@ def active_cache_dir() -> Optional[str]:
     import jax
 
     return jax.config.jax_compilation_cache_dir
+
+
+def cache_dir_usage() -> tuple:
+    """``(bytes under the active cache dir, the cap on them)``, either None
+    where there is none: no dir, or ``jax_compilation_cache_max_size``
+    unset (-1: jax never evicts). jax keeps the dir flat, so one
+    ``scandir``. At the cap jax's LRU evicts on every write, and a launch's
+    miss may be another launch's eviction (ROADMAP S7)."""
+    import jax
+
+    cache_dir = jax.config.jax_compilation_cache_dir
+    used = None
+    if cache_dir and os.path.isdir(cache_dir):
+        with os.scandir(cache_dir) as entries:
+            used = sum(e.stat().st_size for e in entries if e.is_file())
+    cap = int(jax.config.jax_compilation_cache_max_size)
+    return used, (cap if cap > 0 else None)
 
 
 #: The checkout this package lives in. A relative cache dir is resolved

@@ -34,6 +34,8 @@ from concurrent.futures import TimeoutError as FutureTimeoutError
 from dataclasses import dataclass, field
 from typing import Optional
 
+from acco_tpu.telemetry.trace import Tracer
+
 _log = logging.getLogger(__name__)
 
 # Executors released by close(wait=False) with compiles still in flight.
@@ -88,24 +90,40 @@ class ProgramCompileRecord:
         return (self.lower_ms or 0.0) + (self.compile_ms or 0.0)
 
 
-def _lower_and_compile(name: str, fn, args, kwargs) -> ProgramCompileRecord:
-    """One warmup job: trace/lower then XLA-compile; wall times per phase.
+def _lower_and_compile(
+    name: str, fn, args, kwargs, tracer: Tracer, submitted_us: float
+) -> ProgramCompileRecord:
+    """One warmup job: trace/lower then XLA-compile, each a span on this
+    worker's track of the run's trace (``compile/lower``,
+    ``compile/compile``); the record's wall times are the spans' own.
 
     The lowering (python tracing) holds the GIL, so concurrent jobs
-    serialize there; the compile releases it, which is where the
-    parallelism pays."""
+    serialize there (and slow the main thread's set-up meanwhile); the
+    compile releases it, which is where the parallelism pays.
+    ``submitted_us`` is the trace clock at ``submit``: a span's start
+    less that is how long the program waited for a worker."""
     from acco_tpu.compile.cache import attribute_cache_events
 
     rec = ProgramCompileRecord(name)
+    args_of = {"program": name, "submitted_us": round(submitted_us, 1)}
     with attribute_cache_events() as window:
         try:
-            t0 = time.perf_counter()
+            t0 = tracer.now_us()
             lowered = fn.lower(*args, **kwargs)
-            t1 = time.perf_counter()
+            t1 = tracer.now_us()
+            rec.lower_ms = (t1 - t0) / 1e3
+            tracer.complete_event(
+                "compile/lower", rec.lower_ms, cat="compile", ts_us=t0,
+                args=dict(args_of),
+            )
             rec.compiled = lowered.compile()
-            t2 = time.perf_counter()
-            rec.lower_ms = (t1 - t0) * 1e3
-            rec.compile_ms = (t2 - t1) * 1e3
+            rec.compile_ms = (tracer.now_us() - t1) / 1e3
+            stats = window.stats()
+            # deserialised (a hit) or compiled (a miss)
+            tracer.complete_event(
+                "compile/compile", rec.compile_ms, cat="compile", ts_us=t1,
+                args={**args_of, "hits": stats["hits"], "misses": stats["misses"]},
+            )
         except Exception as exc:  # never propagate: first real call will raise
             rec.error = f"{type(exc).__name__}: {exc}"
     rec.cache = window.stats()
@@ -198,8 +216,14 @@ class CompileWarmup:
     raises on program errors — inspect the records.
     """
 
-    def __init__(self, max_workers: int = 4, log=None) -> None:
+    def __init__(
+        self, max_workers: int = 4, log=None, tracer: Optional[Tracer] = None
+    ) -> None:
         self._log = log or _log
+        # the run's tracer (the trainer hands its own, as it does to the
+        # CheckpointManager); without one the jobs time themselves on a
+        # tracer that records nothing
+        self._tracer = tracer if tracer is not None else Tracer(enabled=False)
         self._executor: Optional[ThreadPoolExecutor] = ThreadPoolExecutor(
             max_workers=max_workers, thread_name_prefix="acco-compile"
         )
@@ -217,8 +241,14 @@ class CompileWarmup:
         if name in self._futures:
             raise ValueError(f"duplicate warmup program name {name!r}")
         self._futures[name] = self._executor.submit(
-            _lower_and_compile, name, fn, args, kwargs
+            _lower_and_compile, name, fn, args, kwargs,
+            self._tracer, self._tracer.now_us(),
         )
+
+    @property
+    def submitted(self) -> int:
+        """How many programs have been queued."""
+        return len(self._futures)
 
     @property
     def pending(self) -> bool:

@@ -35,9 +35,12 @@ from acco_tpu.telemetry.trace import (
     DECLARED_DEVICE_SCOPES,
     DEVICE_SCOPES,
     EXPERT_DEVICE_SCOPES,
+    INSIDE_TRAINER_INIT,
+    SETUP_SPANS,
     SPAN_NAMES,
     Tracer,
     UndeclaredSpanError,
+    setup_phases,
     test_duration_records,
     validate_trace,
 )
@@ -52,11 +55,14 @@ __all__ = [
     "DECLARED_DEVICE_SCOPES",
     "DEVICE_SCOPES",
     "EXPERT_DEVICE_SCOPES",
+    "INSIDE_TRAINER_INIT",
+    "SETUP_SPANS",
     "SPAN_NAMES",
     "Tracer",
     "UndeclaredSpanError",
     "innermost_scope",
     "scope_table",
+    "setup_phases",
     "test_duration_records",
     "validate_trace",
 ]

@@ -238,8 +238,6 @@ DECLARED: Tuple[MetricSpec, ...] = (
     _spec("train_log_sync_ms", HISTOGRAM, "ms",
           "the logging-boundary device_get (the one per-cadence sync)"),
     _spec("train_eval_ms", HISTOGRAM, "ms", "evaluate() wall per call"),
-    _spec("train_warmup_join_ms", GAUGE, "ms",
-          "residual wait joining the background AOT compile warmup"),
     _spec("train_loss", GAUGE, "loss", "last boundary's training loss"),
     _spec("train_grad_norm", GAUGE, "norm",
           "last boundary's global gradient norm"),
@@ -285,6 +283,12 @@ DECLARED: Tuple[MetricSpec, ...] = (
           "persistent-cache hits"),
     _spec("compile_cache_time_saved_s", COUNTER, "s",
           "compile seconds served from the persistent cache"),
+    # the trainer, at the warmup's join (also on compile/warmup_join's args)
+    _spec("compile_cache_dir_bytes", GAUGE, "bytes",
+          "size of the files under the persistent cache dir at the join"),
+    _spec("compile_cache_max_bytes", GAUGE, "bytes",
+          "jax_compilation_cache_max_size, where one is set: at the cap "
+          "jax evicts least-recently-used entries on every write"),
     # -- input pipeline (data/prefetch.py) --
     _spec("loader_blocks_total", COUNTER, "blocks",
           "microbatch blocks consumed from the prefetch source"),

@@ -37,6 +37,12 @@ Design constraints, all load-bearing:
 * **bounded memory** — at most ``max_events`` events are kept; overflow
   increments a drop counter reported in ``otherData`` instead of
   growing without bound on long runs.
+* **one clock from the process's start** — ``main.run`` makes the run's
+  tracer at its entry and hands it to the trainer, so the set-up path
+  (``setup/*``, ``compile/*``) and the round loop share a zero. What the
+  trainer learns later (rank, ``telemetry.enabled``, the annotation
+  factory) arrives through :meth:`Tracer.configure`; a tracer told it is
+  disabled drops what it had recorded.
 """
 
 from __future__ import annotations
@@ -61,6 +67,28 @@ SPAN_NAMES = frozenset(
         "ckpt/snapshot",         # blocking device->host part of save()
         "ckpt/commit",           # background finalize (its own thread)
         "compile/warmup_join",   # join of the background AOT warmup
+        # -- set-up, main thread: main.run's entry -> the first
+        # loader/next_block (what setup_s is made of; PERF.md section 3) --
+        "setup/config",          # compose_config, run dir, compile-cache wiring (the
+                                 # first to import jax in a `python main.py`)
+        "setup/imports",         # jax.numpy / data / registry / trainer imports
+        "setup/build_model",     # build_model or from_pretrained
+        "setup/load_data",       # load_tokenizer + load_text_dataset
+        "setup/trainer_init",    # the trainer's constructor (parent of the next three)
+        "setup/start_warmup",    # step object, abstract state, jit objects, submits
+        "setup/tokenize",        # tokenize/pack both datasets, const-len check, loaders
+        "setup/summary_writer",  # the TensorBoard writer and its import (in trainer_init)
+        "setup/state_init",      # model.init + step.init_state (compile lazily here)
+        "setup/restore",         # the resume_from restore, only when it runs
+        "setup/seed",            # block source, seed program / DPU warm-up rounds, the
+                                 # device_gets that wait for them
+        "setup/scope_table",     # device_scopes.json, only with profile_steps
+        "train/profile_start",   # block_until_ready + jax.profiler.start_trace
+        "train/profile_stop",    # block_until_ready + jax.profiler.stop_trace
+        # -- whichever thread compiles (the warmup's are acco-compile_N) --
+        "compile/lower",         # fn.lower of one warmed program (holds the GIL)
+        "compile/compile",       # lowered.compile() of one warmed program
+        "compile/backend",       # one jax backend_compile_duration event
         "serve/prefill",         # one admitted request's prefill dispatch
         "serve/decode_step",     # one batched decode+sample step
         "serve/request",         # submit -> finish of one GenRequest
@@ -143,8 +171,35 @@ class Tracer:
         self._events: List[Dict[str, Any]] = []
         self._lock = threading.Lock()
         self._pid = os.getpid()
-        self._tids: Dict[int, int] = {}  # ident -> small stable tid
+        self._tids: Dict[int, tuple] = {}  # ident -> (small stable tid, thread)
+        self._n_tids = 0
         self._t0_ns = time.perf_counter_ns()
+
+    def configure(
+        self,
+        *,
+        enabled: Optional[bool] = None,
+        process_name: Optional[str] = None,
+        max_events: Optional[int] = None,
+        annotate: Optional[Callable[[str], ContextManager]] = None,
+    ) -> None:
+        """Facts the owner learns after the clock started (the trainer's
+        rank and ``telemetry`` block, the profiler's annotation factory).
+        ``enabled=False`` also drops what was recorded so far: a rank that
+        writes no trace holds none."""
+        with self._lock:
+            if process_name is not None:
+                self.process_name = process_name
+            if max_events is not None:
+                self.max_events = int(max_events)
+            if annotate is not None:
+                self._annotate = annotate
+            if enabled is not None:
+                self.enabled = bool(enabled)
+                if not self.enabled:
+                    self._events.clear()
+                    self._tids.clear()
+                    self._n_tids = 0
 
     # -- time ----------------------------------------------------------------
 
@@ -158,18 +213,23 @@ class Tracer:
         """Small stable id of the calling thread; a thread's first event
         brings a thread-name metadata event with it. ``_append`` calls
         this under the lock with room for one event, and counts again
-        afterwards."""
-        ident = threading.get_ident()
-        tid = self._tids.get(ident)
-        if tid is None:
-            tid = self._tids[ident] = len(self._tids)
-            self._events.append(
-                {
-                    "ph": "M", "name": "thread_name", "pid": self._pid,
-                    "tid": tid,
-                    "args": {"name": threading.current_thread().name},
-                }
-            )
+        afterwards. Keyed by the thread OBJECT as well as its ident: the
+        OS hands a dead thread's ident to the next one (a warmup pool's
+        worker, then the prefetcher), which must not inherit its track."""
+        thread = threading.current_thread()
+        known = self._tids.get(thread.ident)
+        if known is not None and known[1] is thread:
+            return known[0]
+        tid = self._n_tids
+        self._n_tids += 1
+        self._tids[thread.ident] = (tid, thread)
+        self._events.append(
+            {
+                "ph": "M", "name": "thread_name", "pid": self._pid,
+                "tid": tid,
+                "args": {"name": thread.name},
+            }
+        )
         return tid
 
     def _check_name(self, name: str, cat: str) -> None:
@@ -182,6 +242,8 @@ class Tracer:
 
     def _append(self, event: Dict[str, Any]) -> None:
         with self._lock:
+            if not self.enabled:  # disabled while the span was open
+                return
             if len(self._events) >= self.max_events:
                 self.dropped += 1
                 return
@@ -336,6 +398,33 @@ def validate_trace(trace: Dict[str, Any]) -> List[str]:
                 )
             stack.append((beg, end, name))
     return problems
+
+
+#: The main thread's set-up spans in the order a run passes them.
+SETUP_SPANS = (
+    "setup/config", "setup/imports", "setup/build_model", "setup/load_data",
+    "setup/trainer_init", "setup/start_warmup", "setup/tokenize",
+    "setup/summary_writer", "setup/state_init", "compile/warmup_join",
+    "setup/restore", "setup/seed", "setup/scope_table",
+)
+#: The phases (:func:`setup_phases`) that lie inside ``trainer_init``; the
+#: others follow one another.
+INSIDE_TRAINER_INIT = ("start_warmup", "tokenize", "summary_writer")
+
+
+def setup_phases(events: List[Dict[str, Any]]) -> Dict[str, float]:
+    """``{phase: seconds}`` over :data:`SETUP_SPANS`, a phase being the
+    span's name without its category (``trainer_init``, ``warmup_join``):
+    what the run's summary, its set-up log line and
+    ``tools/trace_report.py`` show. A span that ran twice (the warmup's
+    restart) counts twice; one that did not run is left out."""
+    sums: Dict[str, float] = {}
+    for ev in events:
+        if ev.get("ph") == "X" and ev.get("name") in SETUP_SPANS:
+            sums[ev["name"]] = sums.get(ev["name"], 0.0) + ev.get("dur", 0.0) / 1e6
+    return {
+        name.split("/", 1)[1]: sums[name] for name in SETUP_SPANS if name in sums
+    }
 
 
 def test_duration_records(events: List[Dict[str, Any]]) -> Dict[str, dict]:

@@ -63,7 +63,14 @@ from acco_tpu.resilience import (
     ShutdownHandler,
     TrainingHealthMonitor,
 )
-from acco_tpu.telemetry import DECLARED_DEVICE_SCOPES, Tracer, metrics, scope_table
+from acco_tpu.telemetry import (
+    DECLARED_DEVICE_SCOPES,
+    INSIDE_TRAINER_INIT,
+    Tracer,
+    metrics,
+    scope_table,
+    setup_phases,
+)
 from acco_tpu.utils import logs as logs_utils
 from acco_tpu.utils.checkpoint import latest_checkpoint
 
@@ -90,6 +97,56 @@ def _arg(args: Any, name: str, default: Any = None) -> Any:
     else:
         value = getattr(args, name, default)
     return default if value is None else value
+
+
+_SETUP_LABELS = {
+    "build_model": "model", "load_data": "data", "trainer_init": "trainer",
+    "summary_writer": "writer", "state_init": "state",
+    "warmup_join": "warmup join", "scope_table": "scope table",
+}
+
+
+def _setup_line(total_s: float, events: list) -> str:
+    """The run's one set-up line: seconds from the tracer's zero to the
+    first dispatch, by phase (``telemetry.setup_phases``), with what the
+    warmup's join learned (``compile/warmup_join``'s args) and what the
+    cache dir held at the launch (``setup/config``'s, from ``main.run``)::
+
+        set-up 84.1 s: config 0.3, imports 0.1, model 0.4, data 1.2, trainer
+        9.8 (tokenize 6.1, writer 2.2), state 7.2, warmup join 58.3 [3 hits
+        0 misses, cache 201->188/192 MiB], seed 2.9
+    """
+    phases = setup_phases(events)
+    args = {
+        e["name"]: e.get("args") or {} for e in events
+        if e.get("name") in ("setup/config", "compile/warmup_join")
+    }
+    joined = args.get("compile/warmup_join", {})
+    at_launch = args.get("setup/config", {}).get("cache_dir_bytes")
+    parts = []
+    for phase, seconds in phases.items():
+        if phase in INSIDE_TRAINER_INIT:
+            continue
+        part = f"{_SETUP_LABELS.get(phase, phase)} {seconds:.1f}"
+        if phase == "trainer_init":
+            inside = [
+                f"{_SETUP_LABELS.get(p, p)} {phases[p]:.1f}"
+                for p in ("tokenize", "summary_writer") if p in phases
+            ]
+            part += f" ({', '.join(inside)})" if inside else ""
+        if phase == "warmup_join" and "hits" in joined:
+            part += f" [{joined['hits']} hits {joined['misses']} misses"
+            if "cache_dir_bytes" in joined:
+                part += ", cache "
+                if at_launch is not None:
+                    part += f"{at_launch / 2**20:.0f}->"
+                part += f"{joined['cache_dir_bytes'] / 2**20:.0f}"
+                if "cache_max_bytes" in joined:
+                    part += f"/{joined['cache_max_bytes'] / 2**20:.0f}"
+                part += " MiB"
+            part += "]"
+        parts.append(part)
+    return f"set-up {total_s:.1f} s: " + ", ".join(parts)
 
 
 class DecoupledTrainer:
@@ -121,8 +178,49 @@ class DecoupledTrainer:
         dist_info: Optional[dict] = None,
         initial_params: Optional[dict] = None,
         shutdown_handler: Optional[ShutdownHandler] = None,
+        tracer: Optional[Tracer] = None,
     ) -> None:
         self._t_construct = time.time()
+        # Telemetry (acco_tpu/telemetry): the span tracer + the global
+        # closed-world metrics registry. Host clocks only — enabled or
+        # disabled, telemetry adds ZERO host-device syncs (the module
+        # never imports jax; the host-lint sync gate holds it to that).
+        # main.run hands over the tracer it made at its entry, so set-up
+        # and the round loop share one clock from the process's start; a
+        # trainer built in code starts the clock here. Rank and the
+        # config's telemetry block are known further down (_construct),
+        # where the tracer is told.
+        self.tracer = tracer if tracer is not None else Tracer()
+        from acco_tpu.compile import trace_compiles
+
+        # every backend compile of the process, warmup thread or lazy on
+        # the main thread, becomes a compile/backend span
+        trace_compiles(self.tracer)
+        with self.tracer.span("setup/trainer_init", cat="setup") as init:
+            self._construct(
+                model, tokenizer, train_dataset, eval_dataset, args, log,
+                seed=seed, run_dir=run_dir, mesh=mesh, dist_info=dist_info,
+                initial_params=initial_params,
+                shutdown_handler=shutdown_handler,
+            )
+            init.update(method=self.method, world_size=self.world_size)
+
+    def _construct(
+        self,
+        model,
+        tokenizer,
+        train_dataset,
+        eval_dataset,
+        args,
+        log,
+        *,
+        seed: int,
+        run_dir: str,
+        mesh,
+        dist_info: Optional[dict],
+        initial_params: Optional[dict],
+        shutdown_handler: Optional[ShutdownHandler],
+    ) -> None:
         self.model = model
         # Pretrained start (the reference's finetune mode, main.py:33-35):
         # when given, these weights replace the random init in train().
@@ -178,6 +276,22 @@ class DecoupledTrainer:
         self.id_run = logs_utils.create_id_run()
 
         self.method = str(_arg(args, "method_name", "acco"))
+        # Rank 0 writes the trace, like the reference's rank gating; another
+        # rank, or telemetry.enabled=false, drops what the tracer has held
+        # since the launch. The annotation it is handed puts every span on
+        # the profiler's host plane too while train.profile_steps captures
+        # a trace: host spans and device ops on one clock.
+        tel = _arg(args, "telemetry", None) or {}
+        _tel = tel.get if hasattr(tel, "get") else (
+            lambda k, d=None: getattr(tel, k, d)
+        )
+        self.telemetry_enabled = bool(_tel("enabled", True))
+        self.tracer.configure(
+            enabled=self.telemetry_enabled and self.rank == 0,
+            process_name=f"acco-{self.method}",
+            max_events=int(_tel("max_trace_events", 200_000)),
+            annotate=jax.profiler.TraceAnnotation,
+        )
         if self.method not in ("acco", "ddp", "dpu"):
             raise ValueError(
                 f"method_name must be one of acco/ddp/dpu, got {self.method!r}"
@@ -386,79 +500,66 @@ class DecoupledTrainer:
                 self._warmup = self._start_warmup()
 
             # Data: process-rank shard -> tokenize -> static-shape loaders.
-            n_proc, proc = jax.process_count(), jax.process_index()
-            self.local_devices = self.world_size // n_proc
-            self.train_dataset = self._tokenized(
-                shard_dataset(train_dataset, n_proc, proc) if n_proc > 1 else train_dataset
-            )
-            self.eval_dataset = (
-                self._tokenized(
-                    shard_dataset(eval_dataset, n_proc, proc) if n_proc > 1 else eval_dataset
+            with self.tracer.span("setup/tokenize", cat="setup") as tokenized:
+                n_proc, proc = jax.process_count(), jax.process_index()
+                self.local_devices = self.world_size // n_proc
+                self.train_dataset = self._tokenized(
+                    shard_dataset(train_dataset, n_proc, proc) if n_proc > 1 else train_dataset
                 )
-                if eval_dataset is not None
-                else None
-            )
-            if self.const_len_batch or self.seq_axis:
-                # Catch data that bypasses the const_len_batch flag (e.g.
-                # pre-tokenized variable-length rows the loader would pad):
-                # collectively agreed so one process's bad shard fails every
-                # process together instead of deadlocking the others at the
-                # next collective. Not just CP: const_len_batch=True makes
-                # every train/eval program statically DROP its all-ones
-                # masks, so a padded row would become silently-attendable
-                # padding on any mesh.
-                self._check_const_len()
-            self.train_loader = ShardedBatchIterator(
-                self.train_dataset,
-                batch_size=self.batch_size * self.local_devices,
-                max_length=self.max_length,
-                pad_token_id=int(getattr(tokenizer, "pad_token_id", 0) or 0),
-                shuffle=True,
-                seed=self.seed,
-            )
-            self.eval_loader = (
-                ShardedBatchIterator(
-                    self.eval_dataset,
+                self.eval_dataset = (
+                    self._tokenized(
+                        shard_dataset(eval_dataset, n_proc, proc) if n_proc > 1 else eval_dataset
+                    )
+                    if eval_dataset is not None
+                    else None
+                )
+                if self.const_len_batch or self.seq_axis:
+                    # Catch data that bypasses the const_len_batch flag (e.g.
+                    # pre-tokenized variable-length rows the loader would pad):
+                    # collectively agreed so one process's bad shard fails every
+                    # process together instead of deadlocking the others at the
+                    # next collective. Not just CP: const_len_batch=True makes
+                    # every train/eval program statically DROP its all-ones
+                    # masks, so a padded row would become silently-attendable
+                    # padding on any mesh.
+                    self._check_const_len()
+                self.train_loader = ShardedBatchIterator(
+                    self.train_dataset,
                     batch_size=self.batch_size * self.local_devices,
                     max_length=self.max_length,
                     pad_token_id=int(getattr(tokenizer, "pad_token_id", 0) or 0),
-                    shuffle=False,
-                    drop_last=False,
+                    shuffle=True,
+                    seed=self.seed,
                 )
-                if self.eval_dataset is not None and len(self.eval_dataset) > 0
-                else None
-            )
+                self.eval_loader = (
+                    ShardedBatchIterator(
+                        self.eval_dataset,
+                        batch_size=self.batch_size * self.local_devices,
+                        max_length=self.max_length,
+                        pad_token_id=int(getattr(tokenizer, "pad_token_id", 0) or 0),
+                        shuffle=False,
+                        drop_last=False,
+                    )
+                    if self.eval_dataset is not None and len(self.eval_dataset) > 0
+                    else None
+                )
+                tokenized["rows"] = len(self.train_dataset)
 
             # Observability (rank 0 writes, like the reference's rank gating).
-            # Telemetry (acco_tpu/telemetry): span tracer + the global
-            # closed-world metrics registry. Host clocks only — enabled
-            # or disabled, telemetry adds ZERO host-device syncs (the
-            # module never imports jax; the host-lint sync gate holds it
-            # to that). The annotation it is handed puts every span on
-            # the profiler's host plane too while train.profile_steps
-            # captures a trace: host spans and device ops on one clock.
-            tel = _arg(args, "telemetry", None) or {}
-            _tel = tel.get if hasattr(tel, "get") else (
-                lambda k, d=None: getattr(tel, k, d)
-            )
-            self.telemetry_enabled = bool(_tel("enabled", True))
-            self.tracer = Tracer(
-                enabled=self.telemetry_enabled and self.rank == 0,
-                process_name=f"acco-{self.method}",
-                max_events=int(_tel("max_trace_events", 200_000)),
-                annotate=jax.profiler.TraceAnnotation,
-            )
             self.trace_path = os.path.join(
                 self.run_dir, f"trace_{self.id_run}.json"
             )
             run_name = str(_arg(args, "run_name", self.method))
-            self.writer = (
-                logs_utils.make_summary_writer(
-                    os.path.join(self.run_dir, "tensorboard", run_name, self.id_run)
+            # a span of its own inside setup/trainer_init: the writer's
+            # import (torch.utils.tensorboard) costs seconds of every launch
+            with self.tracer.span("setup/summary_writer", cat="setup"):
+                self.writer = (
+                    logs_utils.make_summary_writer(
+                        os.path.join(self.run_dir, "tensorboard", run_name, self.id_run)
+                    )
+                    if self.rank == 0
+                    else logs_utils.NoOpWriter()
                 )
-                if self.rank == 0
-                else logs_utils.NoOpWriter()
-            )
             self.ckpt_dir = os.path.join(self.run_dir, "checkpoints", run_name)
             self.checkpoint_every_s = float(_arg(args, "checkpoint_every_s", 1800))
             # Resilience (acco_tpu/resilience): overlapped async checkpointing
@@ -754,6 +855,13 @@ class DecoupledTrainer:
         — ``AccoTrainStep.abstract_state`` traces ``init_state`` through
         ``jax.eval_shape``). A failure here never fails training: the
         programs just compile lazily at first call, as before."""
+        with self.tracer.span("setup/start_warmup", cat="setup") as started:
+            handle = self._submit_warmup()
+            if handle is not None:
+                started["programs"] = handle.runner.submitted
+        return handle
+
+    def _submit_warmup(self) -> Optional[_WarmupHandle]:
         from acco_tpu.compile import CompileWarmup
 
         try:
@@ -766,7 +874,7 @@ class DecoupledTrainer:
                 if self.initial_params is not None
                 else None
             )
-            runner = CompileWarmup(log=self.log)
+            runner = CompileWarmup(log=self.log, tracer=self.tracer)
             step.warmup(
                 self.n_acc,
                 self.batch_size * self.world_size,
@@ -812,30 +920,31 @@ class DecoupledTrainer:
         )
         if not do_eval or self._warmup is None:
             return
-        try:
-            eval_fn = self._build_eval_fn()
-            step = self._warmup.step
-            # the flat-param placement comes from the step's sharding
-            # rule table (acco_tpu/sharding) — same source as state_specs
-            flat_aval = jax.ShapeDtypeStruct(
-                (step.tp * step.geom.padded_size,),
-                self.param_dtype,
-                sharding=NamedSharding(
-                    self.mesh, step.rule_table().match("flat_params")
-                ),
-            )
-            row = NamedSharding(self.mesh, P(DATA_AXIS, self.seq_axis))
-            batch_aval = jax.ShapeDtypeStruct(
-                (self.batch_size * self.world_size, self.max_length),
-                jnp.int32,
-                sharding=row,
-            )
-            self._warmup.runner.submit(
-                "eval", eval_fn, flat_aval, batch_aval, batch_aval, batch_aval
-            )
-            self._eval_fn = eval_fn
-        except Exception as exc:
-            self.log.warning("eval compile warmup skipped (%s)", exc)
+        with self.tracer.span("setup/start_warmup", cat="setup", programs=1):
+            try:
+                eval_fn = self._build_eval_fn()
+                step = self._warmup.step
+                # the flat-param placement comes from the step's sharding
+                # rule table (acco_tpu/sharding) — same source as state_specs
+                flat_aval = jax.ShapeDtypeStruct(
+                    (step.tp * step.geom.padded_size,),
+                    self.param_dtype,
+                    sharding=NamedSharding(
+                        self.mesh, step.rule_table().match("flat_params")
+                    ),
+                )
+                row = NamedSharding(self.mesh, P(DATA_AXIS, self.seq_axis))
+                batch_aval = jax.ShapeDtypeStruct(
+                    (self.batch_size * self.world_size, self.max_length),
+                    jnp.int32,
+                    sharding=row,
+                )
+                self._warmup.runner.submit(
+                    "eval", eval_fn, flat_aval, batch_aval, batch_aval, batch_aval
+                )
+                self._eval_fn = eval_fn
+            except Exception as exc:
+                self.log.warning("eval compile warmup skipped (%s)", exc)
 
     def join_warmup(self, timeout: Optional[float] = None):
         """Block until the background compile warmup finishes (no-op when
@@ -920,6 +1029,9 @@ class DecoupledTrainer:
         the results.csv ledger row.
         """
         self._block_source = None
+        from acco_tpu.compile import trace_compiles
+
+        trace_compiles(self.tracer)  # again: a second train() of one trainer
         own_handler = False
         if self._shutdown is None and self._handle_signals:
             # auto-created per train() call and discarded after: a latch
@@ -953,6 +1065,9 @@ class DecoupledTrainer:
             # compiles finish in the background and only warm the cache).
             if self._warmup is not None:
                 self._warmup.runner.close(wait=False)
+            # the trace is written: later compiles of the process (an
+            # evaluation, another trainer's) are not this run's
+            trace_compiles(None)
             if installed:
                 self._shutdown.uninstall()
             if own_handler:
@@ -971,71 +1086,86 @@ class DecoupledTrainer:
             else self._make_step(self.method)
         )
         self.step_obj = step
-        if self.initial_params is not None:
-            params = self.initial_params
-        elif self.tensor_axis is not None or self.pipeline_axis is not None:
-            # tp/pp exist for models whose full parameters exceed one
-            # chip's HBM — initialize on the host CPU backend, where
-            # init_state's per-shard staging (TpLayout.init_sharded_state)
-            # picks them up without any full-size device transient.
-            # local_devices: in a multi-process world jax.devices()[0]
-            # belongs to process 0 — every process must init on its OWN
-            # host device or the implicit transfer deadlocks.
-            with jax.default_device(jax.local_devices(backend="cpu")[0]):
+        with tracer.span("setup/state_init", cat="setup"):
+            if self.initial_params is not None:
+                params = self.initial_params
+            elif self.tensor_axis is not None or self.pipeline_axis is not None:
+                # tp/pp exist for models whose full parameters exceed one
+                # chip's HBM — initialize on the host CPU backend, where
+                # init_state's per-shard staging (TpLayout.init_sharded_state)
+                # picks them up without any full-size device transient.
+                # local_devices: in a multi-process world jax.devices()[0]
+                # belongs to process 0 — every process must init on its OWN
+                # host device or the implicit transfer deadlocks.
+                with jax.default_device(jax.local_devices(backend="cpu")[0]):
+                    params = self.model.init(jax.random.PRNGKey(self.seed))
+            else:
                 params = self.model.init(jax.random.PRNGKey(self.seed))
-        else:
-            params = self.model.init(jax.random.PRNGKey(self.seed))
-        state = step.init_state(params)
-        # the state holds its own copies: the leaves would stay on the
-        # device for the whole run (2 B a parameter: 1.2 GiB of a 626M model)
-        del params
-        # a static fact of the layout, so one number a run: the share of the
-        # flat vector that unpack takes out as a bitcast (0 under tp / pp,
-        # whose layouts are row-major)
-        flat_bitcast_share = step.layout.bitcast_share if step.layout else 0.0
-        metrics.emit("train_flat_bitcast_share", flat_bitcast_share)
-        self.log.info(
-            "flat vector: layout %s, %d elements, flat_bitcast_share=%.6f",
-            flat_layout_tag(step), step.geom.n_params, flat_bitcast_share,
-        )
+            state = step.init_state(params)
+            # the state holds its own copies: the leaves would stay on the
+            # device for the whole run (2 B a parameter: 1.2 GiB of a 626M model)
+            del params
+            # a static fact of the layout, so one number a run: the share of the
+            # flat vector that unpack takes out as a bitcast (0 under tp / pp,
+            # whose layouts are row-major)
+            flat_bitcast_share = step.layout.bitcast_share if step.layout else 0.0
+            metrics.emit("train_flat_bitcast_share", flat_bitcast_share)
+            self.log.info(
+                "flat vector: layout %s, %d elements, flat_bitcast_share=%.6f",
+                flat_layout_tag(step), step.geom.n_params, flat_bitcast_share,
+            )
 
         # Join the background AOT warmup (started at construction and
         # overlapped with tokenize / loader setup / state init above):
         # past this line every program this run dispatches holds its
         # compiled executable, installed for direct AOT dispatch.
-        t_wj = time.perf_counter()
-        with tracer.span("compile/warmup_join", cat="compile"):
-            self.join_warmup()
-        metrics.emit(
-            "train_warmup_join_ms", (time.perf_counter() - t_wj) * 1e3
-        )
+        with tracer.span("compile/warmup_join", cat="compile") as joined:
+            report = self.join_warmup()
+            if report is not None:
+                joined.update(
+                    hits=report.cache.get("hits", 0),
+                    misses=report.cache.get("misses", 0),
+                )
+            # where the evictions happen: a miss in a cache that stands
+            # at its cap may be another launch's eviction (ROADMAP S7)
+            from acco_tpu.compile import cache_dir_usage
+
+            cache_bytes, cache_cap = cache_dir_usage()
+            if cache_bytes is not None:
+                metrics.emit("compile_cache_dir_bytes", cache_bytes)
+                joined["cache_dir_bytes"] = cache_bytes
+            if cache_cap is not None:
+                metrics.emit("compile_cache_max_bytes", cache_cap)
+                joined["cache_max_bytes"] = cache_cap
 
         # Resume (framework improvement over the reference's save-only).
         meta = {"count_grad_tot": 0, "rounds_done": 0, "elapsed_s": 0.0}
         resume_from = _arg(self.args, "resume_from")
         if resume_from:
-            path = (
-                resume_from
-                if os.path.basename(resume_from).startswith("step_")
-                else latest_checkpoint(resume_from, log=self.log)
-            )
-            if path is None:
-                raise FileNotFoundError(f"No checkpoint under {resume_from!r}")
-            if os.path.basename(resume_from).startswith("step_"):
-                from acco_tpu.utils.checkpoint import validate_checkpoint
+            with tracer.span("setup/restore", cat="setup") as restored:
+                path = (
+                    resume_from
+                    if os.path.basename(resume_from).startswith("step_")
+                    else latest_checkpoint(resume_from, log=self.log)
+                )
+                if path is None:
+                    raise FileNotFoundError(f"No checkpoint under {resume_from!r}")
+                if os.path.basename(resume_from).startswith("step_"):
+                    from acco_tpu.utils.checkpoint import validate_checkpoint
 
-                reason = validate_checkpoint(path)
-                if reason is not None:
-                    raise ValueError(
-                        f"explicitly requested checkpoint {path!r} is not "
-                        f"restorable ({reason}); point resume_from at the "
-                        "checkpoint ROOT to fall back to the newest "
-                        "complete step instead"
-                    )
-            state, meta = restore_flat_state(path, state, step, log=self.log)
-            self.log.info(
-                "Resumed from %s at %d grads", path, meta["count_grad_tot"]
-            )
+                    reason = validate_checkpoint(path)
+                    if reason is not None:
+                        raise ValueError(
+                            f"explicitly requested checkpoint {path!r} is not "
+                            f"restorable ({reason}); point resume_from at the "
+                            "checkpoint ROOT to fall back to the newest "
+                            "complete step instead"
+                        )
+                state, meta = restore_flat_state(path, state, step, log=self.log)
+                self.log.info(
+                    "Resumed from %s at %d grads", path, meta["count_grad_tot"]
+                )
+                restored["path"] = path
         count_grad_tot = float(meta["count_grad_tot"])
         rounds_done = int(meta["rounds_done"])
         if "loader" in meta:
@@ -1054,109 +1184,115 @@ class DecoupledTrainer:
                 len(self.train_loader), 1
             )
 
-        # Input pipeline: a PrefetchingBlockSource collates + transfers
-        # round N+1's block on a worker thread while round N's compiled
-        # program executes (prefetch=False runs the same interface
-        # synchronously). Created AFTER the resume restore above so the
-        # worker starts from the restored position.
-        source = PrefetchingBlockSource(
-            self.train_loader,
-            self.n_acc,
-            self._put_block,
-            depth=self.prefetch_depth,
-            prefetch=self.prefetch,
-        )
-        self._block_source = source
-        # Valid micro-grads contributed per half-round: the microbatch_mask
-        # sum under heterogeneous workers, ws*n_acc otherwise. This host
-        # mirror of the device-side count drives the termination check
-        # without a per-round device sync; the authoritative count is the
-        # state's grads_committed counter, reconciled at every logging /
-        # eval boundary (bookkeeping that hardcodes ws*n_acc inflates
-        # progress under a mask).
-        mask = _arg(self.args, "microbatch_mask")
-        grads_per_round = (
-            float(np.asarray(mask, np.float32).sum())
-            if mask is not None
-            else float(self.world_size * self.n_acc)
-        )
-
-        if self.method in ("acco", "dpu") and rounds_done == 0:
-            # ACCO warmup parity (`trainer_decoupled.py:436-438,318-383`):
-            # n_warmup_steps sequential real-update rounds — i.e. DPU rounds
-            # — before the decoupled regime takes over.
-            n_warmup = int(_arg(self.args, "n_warmup_steps", 0))
-            if self.method == "acco" and n_warmup > 0:
-                warm = self._make_step("dpu")
-                # the warm step reuses the main step's resolved layout —
-                # including tp_layout, whose n_repl drives the replicated-
-                # prefix gradient psum under tensor parallelism
-                warm.geom, warm.unravel = step.geom, step.unravel
-                warm.layout = step.layout
-                warm.tp_layout = step.tp_layout
-                state, _ = warm.seed_fn()(state, source.next_block())
-                warm_round = warm.round_fn()
-                for _ in range(n_warmup):
-                    state, _ = warm_round(state, source.next_block())
-                    count_grad_tot += grads_per_round
-                # Hand over mid-stream: round 0 (even) consumes the staged
-                # pending grads speculatively AND — because even ACCO
-                # rounds read ``pending_grads`` as their accumulator
-                # carry-in — folds them into round 1's *real* update too:
-                # the reference's count_after_init=-2 post-warmup carry
-                # (`trainer_decoupled.py:359-383,441`), without which the
-                # last warmup round's gradients would be dropped.
-                state = state._replace(round_idx=jnp.zeros((), jnp.int32))
-            else:
-                state, _ = step.program_callable("seed", log=self.log)(
-                    state, source.next_block()
-                )
-        elif self.method in ("acco", "dpu"):
-            pass  # resumed: buffers restored, no seed
-        # Dispatch through program_callable: the AOT executables the
-        # warmup installed run directly (no jit-path cache interaction
-        # per dispatch); without a warmup these are the plain jit fns.
-        if self.method == "acco":
-            # Parity-specialized round programs: the host knows the round
-            # parity, so the speculative-rollback/zeroing selects over the
-            # full flat vectors constant-fold out of each program.
-            round_programs = ("round_even", "round_odd")
-        elif self.method == "dpu":
-            round_programs = ("round",)
-        else:
-            round_programs = ("step",)
-        round_fns = {
-            name: step.program_callable(name, log=self.log)
-            for name in round_programs
-        }
-
-        # Count bookkeeping: DDP/DPU commit one round's valid grads per
-        # round; ACCO commits two half-rounds every odd round
-        # (`trainer_decoupled.py:501-502,763`). ACCO round parity is
-        # tracked host-side from the state's round_idx (one device sync
-        # here, none per round; warmup resets it, resume restores it).
-        round_idx_host = (
-            int(jax.device_get(state.round_idx))
-            if self.method in ("acco", "dpu")
-            else 0
-        )
-        first_metrics = last_metrics = None
-        # Host half of the watchdog, fresh per train(): fed at the
-        # logging boundary (piggybacking the existing device fetch), it
-        # classifies spikes vs drift and escalates K consecutive guard-
-        # skipped rounds into the auto-rollback below.
-        self._health_monitor = TrainingHealthMonitor(
-            escalate_after=self.rollback_after_skipped, log=self.log
-        )
-        if self.nan_guard:
-            # A resumed state carries its lifetime skip counter; without
-            # this anchor the monitor's first boundary would read the
-            # whole history as "new skips this run" and misclassify a
-            # healthy resume as anomalous (same re-anchor _rollback does
-            # after its restore).
-            self._health_monitor.last_skipped_rounds = int(
-                jax.device_get(state.health.skipped_rounds)
+        # setup/seed: the block source (its worker starts collating), the
+        # seed program or the DPU warm-up rounds, and the two device_gets
+        # that WAIT for them: the run's first device work ends at those,
+        # not at the dispatch.
+        with tracer.span("setup/seed", cat="setup", rounds=0) as seeded:
+            # Input pipeline: a PrefetchingBlockSource collates + transfers
+            # round N+1's block on a worker thread while round N's compiled
+            # program executes (prefetch=False runs the same interface
+            # synchronously). Created AFTER the resume restore above so the
+            # worker starts from the restored position.
+            source = PrefetchingBlockSource(
+                self.train_loader,
+                self.n_acc,
+                self._put_block,
+                depth=self.prefetch_depth,
+                prefetch=self.prefetch,
             )
+            self._block_source = source
+            # Valid micro-grads contributed per half-round: the microbatch_mask
+            # sum under heterogeneous workers, ws*n_acc otherwise. This host
+            # mirror of the device-side count drives the termination check
+            # without a per-round device sync; the authoritative count is the
+            # state's grads_committed counter, reconciled at every logging /
+            # eval boundary (bookkeeping that hardcodes ws*n_acc inflates
+            # progress under a mask).
+            mask = _arg(self.args, "microbatch_mask")
+            grads_per_round = (
+                float(np.asarray(mask, np.float32).sum())
+                if mask is not None
+                else float(self.world_size * self.n_acc)
+            )
+
+            if self.method in ("acco", "dpu") and rounds_done == 0:
+                # ACCO warmup parity (`trainer_decoupled.py:436-438,318-383`):
+                # n_warmup_steps sequential real-update rounds — i.e. DPU rounds
+                # — before the decoupled regime takes over.
+                n_warmup = int(_arg(self.args, "n_warmup_steps", 0))
+                if self.method == "acco" and n_warmup > 0:
+                    warm = self._make_step("dpu")
+                    # the warm step reuses the main step's resolved layout —
+                    # including tp_layout, whose n_repl drives the replicated-
+                    # prefix gradient psum under tensor parallelism
+                    warm.geom, warm.unravel = step.geom, step.unravel
+                    warm.layout = step.layout
+                    warm.tp_layout = step.tp_layout
+                    state, _ = warm.seed_fn()(state, source.next_block())
+                    warm_round = warm.round_fn()
+                    for _ in range(n_warmup):
+                        state, _ = warm_round(state, source.next_block())
+                        count_grad_tot += grads_per_round
+                    seeded["rounds"] = n_warmup
+                    # Hand over mid-stream: round 0 (even) consumes the staged
+                    # pending grads speculatively AND — because even ACCO
+                    # rounds read ``pending_grads`` as their accumulator
+                    # carry-in — folds them into round 1's *real* update too:
+                    # the reference's count_after_init=-2 post-warmup carry
+                    # (`trainer_decoupled.py:359-383,441`), without which the
+                    # last warmup round's gradients would be dropped.
+                    state = state._replace(round_idx=jnp.zeros((), jnp.int32))
+                else:
+                    state, _ = step.program_callable("seed", log=self.log)(
+                        state, source.next_block()
+                    )
+            elif self.method in ("acco", "dpu"):
+                pass  # resumed: buffers restored, no seed
+            # Dispatch through program_callable: the AOT executables the
+            # warmup installed run directly (no jit-path cache interaction
+            # per dispatch); without a warmup these are the plain jit fns.
+            if self.method == "acco":
+                # Parity-specialized round programs: the host knows the round
+                # parity, so the speculative-rollback/zeroing selects over the
+                # full flat vectors constant-fold out of each program.
+                round_programs = ("round_even", "round_odd")
+            elif self.method == "dpu":
+                round_programs = ("round",)
+            else:
+                round_programs = ("step",)
+            round_fns = {
+                name: step.program_callable(name, log=self.log)
+                for name in round_programs
+            }
+
+            # Count bookkeeping: DDP/DPU commit one round's valid grads per
+            # round; ACCO commits two half-rounds every odd round
+            # (`trainer_decoupled.py:501-502,763`). ACCO round parity is
+            # tracked host-side from the state's round_idx (one device sync
+            # here, none per round; warmup resets it, resume restores it).
+            round_idx_host = (
+                int(jax.device_get(state.round_idx))
+                if self.method in ("acco", "dpu")
+                else 0
+            )
+            first_metrics = last_metrics = None
+            # Host half of the watchdog, fresh per train(): fed at the
+            # logging boundary (piggybacking the existing device fetch), it
+            # classifies spikes vs drift and escalates K consecutive guard-
+            # skipped rounds into the auto-rollback below.
+            self._health_monitor = TrainingHealthMonitor(
+                escalate_after=self.rollback_after_skipped, log=self.log
+            )
+            if self.nan_guard:
+                # A resumed state carries its lifetime skip counter; without
+                # this anchor the monitor's first boundary would read the
+                # whole history as "new skips this run" and misclassify a
+                # healthy resume as anomalous (same re-anchor _rollback does
+                # after its restore).
+                self._health_monitor.last_skipped_rounds = int(
+                    jax.device_get(state.health.skipped_rounds)
+                )
         self._rollbacks = 0
         self._last_consec_skipped = 0
         injector = self.fault_injector
@@ -1184,11 +1320,10 @@ class DecoupledTrainer:
         profiling = False
         profiled_rounds = None  # [first, last] round inside the capture
         profiled_programs: list[str] = []  # the program of each such round
-        scope_table_path = (
-            self._write_scope_table(step, round_programs)
-            if profile_steps
-            else None
-        )
+        scope_table_path = None
+        if profile_steps:
+            with tracer.span("setup/scope_table", cat="setup"):
+                scope_table_path = self._write_scope_table(step, round_programs)
         t_last_round = time.time()
         round_wall_ms: list[float] = []
         rounds_this_run = 0  # run-local: resume restores rounds_done > 0
@@ -1197,6 +1332,16 @@ class DecoupledTrainer:
         # Construction to first dispatch: tokenisation, state init, the
         # compile warmup's join, the resume restore.
         setup_s = time.time() - self._t_construct
+        # The same by phase, from the tracer's set-up spans (empty where
+        # telemetry is off): the summary's, and ONE line in every run's
+        # log, since the untraced runs are where a median setup_s comes
+        # from and their output is all there is of them.
+        setup_events = tracer.events()
+        setup_phases_s = setup_phases(setup_events)
+        if setup_phases_s and self.rank == 0:
+            self.log.info(
+                "%s", _setup_line(tracer.now_us() / 1e6, setup_events)
+            )
 
         while True:
             if count_grad_tot >= self.nb_grad_tot:
@@ -1228,8 +1373,15 @@ class DecoupledTrainer:
                 and self.rank == 0
                 and not profiling
             ):
+                ts_profile = tracer.now_us()
                 jax.block_until_ready(state)  # compile round fully done
                 jax.profiler.start_trace(profile_dir)
+                # after the fact and not a span: an annotation would put
+                # a new name on the capture's host plane
+                tracer.complete_event(
+                    "train/profile_start", (tracer.now_us() - ts_profile) / 1e3,
+                    cat="train", ts_us=ts_profile,
+                )
                 profiling = True
                 profiled_rounds = [rounds_done + 1, rounds_done + 1]
             program_name = round_programs[round_idx_host % len(round_programs)]
@@ -1285,8 +1437,7 @@ class DecoupledTrainer:
                 profiled_rounds[1] = rounds_done
                 profiled_programs.append(program_name)
             if profiling and rounds_this_run >= profile_after + profile_steps:
-                jax.block_until_ready(state)
-                jax.profiler.stop_trace()
+                self._stop_profile(state)
                 profiling = False
                 self.log.info("profiler trace written to %s", profile_dir)
             if self.method in ("ddp", "dpu"):
@@ -1499,8 +1650,7 @@ class DecoupledTrainer:
                 break
 
         if profiling:  # nb_grad_tot reached before profile_steps rounds
-            jax.block_until_ready(state)
-            jax.profiler.stop_trace()
+            self._stop_profile(state)
         health_final = (
             jax.device_get(state.health) if self.nan_guard else None
         )
@@ -1616,6 +1766,11 @@ class DecoupledTrainer:
             "count_grad_tot": int(count_grad_tot),
             "rounds": rounds_done,
             "setup_s": setup_s,
+            # {phase: seconds} over the tracer's set-up spans, main.run's
+            # included when it made the tracer (telemetry.SETUP_SPANS;
+            # start_warmup, tokenize and summary_writer lie inside
+            # trainer_init)
+            "setup": setup_phases_s,
             "total_time_s": total_time,
             "method": self.method,
             # True = stopped by a shutdown request (preemption/SIGTERM)
@@ -1632,6 +1787,19 @@ class DecoupledTrainer:
             "rollbacks": self._rollbacks,
             "flat_bitcast_share": flat_bitcast_share,
         }
+
+    def _stop_profile(self, state) -> None:
+        """End the capture once the traced rounds have finished; what that
+        cost the loop is a ``train/profile_stop`` event written after the
+        fact (an annotation would put a new name on the capture's host
+        plane, under which the device's idle time would then be filed)."""
+        ts = self.tracer.now_us()
+        jax.block_until_ready(state)
+        jax.profiler.stop_trace()
+        self.tracer.complete_event(
+            "train/profile_stop", (self.tracer.now_us() - ts) / 1e3,
+            cat="train", ts_us=ts,
+        )
 
     # -- eval ---------------------------------------------------------------
 

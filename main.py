@@ -32,101 +32,119 @@ def run(argv: list[str] | None = None):
     """``main`` for a caller that wants to look at the trainer afterwards
     (chip_smoke.py reads its compiled programs and final state):
     returns ``(trainer, summary)``."""
-    argv = sys.argv[1:] if argv is None else argv
-    repo_root = os.path.dirname(os.path.abspath(__file__))
+    # The run's one clock starts here: every set-up span below, the
+    # trainer's, the warmup threads' and the round loop's share this
+    # tracer's zero (telemetry/trace.py). The trainer tells it later
+    # what only it knows: rank, telemetry.enabled, the profiler's
+    # annotation factory.
+    from acco_tpu.telemetry import Tracer
 
-    from acco_tpu.configuration import compose_config
+    tracer = Tracer()
+    with tracer.span("setup/config", cat="setup") as configured:
+        argv = sys.argv[1:] if argv is None else argv
+        repo_root = os.path.dirname(os.path.abspath(__file__))
 
-    cfg = compose_config(os.path.join(repo_root, "config"), argv)
+        from acco_tpu.configuration import compose_config
 
-    run_dir_pattern = cfg.select("hydra.run.dir", "./outputs/%Y-%m-%d/%H-%M-%S")
-    run_dir = datetime.datetime.now().strftime(run_dir_pattern)
-    os.makedirs(run_dir, exist_ok=True)
-    with open(os.path.join(run_dir, "config.yaml"), "w") as f:
-        yaml.safe_dump(cfg.to_container(), f, sort_keys=False)
+        cfg = compose_config(os.path.join(repo_root, "config"), argv)
 
-    logging.basicConfig(
-        level=logging.INFO,
-        format="[%(asctime)s][%(name)s][%(levelname)s] - %(message)s",
-    )
-    log = logging.getLogger("acco_tpu")
-    log.info("run dir: %s", run_dir)
+        run_dir_pattern = cfg.select("hydra.run.dir", "./outputs/%Y-%m-%d/%H-%M-%S")
+        run_dir = datetime.datetime.now().strftime(run_dir_pattern)
+        os.makedirs(run_dir, exist_ok=True)
+        with open(os.path.join(run_dir, "config.yaml"), "w") as f:
+            yaml.safe_dump(cfg.to_container(), f, sort_keys=False)
 
-    # Compile-once subsystem (acco_tpu/compile): turn the persistent
-    # compilation cache on BEFORE anything compiles. It lives at
-    # $JAX_COMPILATION_CACHE_DIR if that is set, else at the config's
-    # dir (outputs/compile_cache in config/train/*.yaml, resolved
-    # against the checkout) — shared across launches and
-    # preemption-resumes of the same config, so a repeat run compiles
-    # nothing. Set train.compile_cache_dir='' to disable.
-    cache_dir = cfg.train.get("compile_cache_dir")
-    if cache_dir:
-        from acco_tpu.compile import setup_compilation_cache
-
-        active = setup_compilation_cache(cache_dir, log=log)
-        log.info("compile cache: %s", active)
-
-    import jax.numpy as jnp
-
-    from acco_tpu.data.datasets import load_text_dataset
-    from acco_tpu.data.tokenizer import load_tokenizer
-    from acco_tpu.models.registry import build_model
-    from acco_tpu.trainer import DecoupledTrainer
-
-    seed = int(cfg.select("seed", 12345))
-    use_mp = bool(cfg.train.get("use_mixed_precision", True))
-    # An 'sp' mesh axis > 1 means context parallelism: the model must be
-    # built on the ring-attention path with the matching sequence axis.
-    mesh_shape = cfg.train.get("mesh_shape") or {}
-    use_cp = int(mesh_shape.get("sp", 1) or 1) > 1
-    # A 'tp' axis > 1 means tensor parallelism: Llama layer matrices shard
-    # over it (parallel/tp.py); the model is built with the matching axis.
-    use_tp = int(mesh_shape.get("tp", 1) or 1) > 1
-    # A 'pp' axis > 1 means pipeline parallelism (parallel/pp.py): the
-    # layer stack splits into stages; vocab pads to a pp multiple (the
-    # embedding/head are vocab-parallel over pp, like tp's).
-    pp_size = int(mesh_shape.get("pp", 1) or 1)
-    # padding multiple for the vocab-parallel embedding/head: the vocab
-    # dim splits over tp, pp, or — composed — their product
-    tp_size = int(mesh_shape.get("tp", 1) or 1)
-    vocab_mult = max(tp_size, 1) * max(pp_size, 1)
-    attention = "ring" if use_cp else cfg.train.get("use_pallas_attention", "auto")
-    # remat / attention values are validated downstream (wrap_remat /
-    # normalize_attention_impl) — YAML bools, None, and 'dots' all pass
-    # through unmangled so typos fail loudly instead of silently coercing.
-    initial_params = None
-    if bool(cfg.train.get("finetune", False)):
-        # finetune: True -> the model group's config_path names a local
-        # pretrained HF checkpoint (reference `main.py:33-35`; hub names
-        # resolve through ACCO_MODELS_ROOT, the root_path_model analogue).
-        from acco_tpu.models.hf_loader import from_pretrained
-
-        model, initial_params = from_pretrained(
-            cfg.model.config_path,
-            param_dtype=jnp.bfloat16 if use_mp else jnp.float32,
-            remat=cfg.train.get("remat", False),
-            attention=attention,
-            sequence_axis="sp" if use_cp else None,
-            scan_unroll=cfg.train.get("scan_unroll", 1),
-            zigzag=use_cp and bool(cfg.train.get("zigzag_cp", True)),
-            tensor_axis="tp" if use_tp else None,
-            vocab_pad_multiple=vocab_mult,
+        logging.basicConfig(
+            level=logging.INFO,
+            format="[%(asctime)s][%(name)s][%(levelname)s] - %(message)s",
         )
-    else:
-        model = build_model(
-            cfg.model,
-            repo_root=repo_root,
-            param_dtype=jnp.bfloat16 if use_mp else jnp.float32,
-            remat=cfg.train.get("remat", False),
-            attention=attention,
-            sequence_axis="sp" if use_cp else None,
-            scan_unroll=cfg.train.get("scan_unroll", 1),
-            zigzag=use_cp and bool(cfg.train.get("zigzag_cp", True)),
-            tensor_axis="tp" if use_tp else None,
-            vocab_pad_multiple=vocab_mult,
-        )
-    tokenizer = load_tokenizer(cfg.model.get("tokenizer"), log)
-    train_ds, eval_ds = load_text_dataset(cfg.data, log)
+        log = logging.getLogger("acco_tpu")
+        log.info("run dir: %s", run_dir)
+
+        # Compile-once subsystem (acco_tpu/compile): turn the persistent
+        # compilation cache on BEFORE anything compiles. It lives at
+        # $JAX_COMPILATION_CACHE_DIR if that is set, else at the config's
+        # dir (outputs/compile_cache in config/train/*.yaml, resolved
+        # against the checkout) — shared across launches and
+        # preemption-resumes of the same config, so a repeat run compiles
+        # nothing. Set train.compile_cache_dir='' to disable.
+        cache_dir = cfg.train.get("compile_cache_dir")
+        if cache_dir:
+            from acco_tpu.compile import cache_dir_usage, setup_compilation_cache
+
+            active = setup_compilation_cache(cache_dir, log=log)
+            log.info("compile cache: %s", active)
+            # what the dir held at the launch: less at the warmup's join
+            # than this plus what the run wrote means jax evicted
+            cache_bytes = cache_dir_usage()[0]
+            if cache_bytes is not None:
+                configured["cache_dir_bytes"] = cache_bytes
+
+    with tracer.span("setup/imports", cat="setup"):
+        import jax.numpy as jnp
+
+        from acco_tpu.data.datasets import load_text_dataset
+        from acco_tpu.data.tokenizer import load_tokenizer
+        from acco_tpu.models.registry import build_model
+        from acco_tpu.trainer import DecoupledTrainer
+
+    with tracer.span("setup/build_model", cat="setup"):
+        seed = int(cfg.select("seed", 12345))
+        use_mp = bool(cfg.train.get("use_mixed_precision", True))
+        # An 'sp' mesh axis > 1 means context parallelism: the model must be
+        # built on the ring-attention path with the matching sequence axis.
+        mesh_shape = cfg.train.get("mesh_shape") or {}
+        use_cp = int(mesh_shape.get("sp", 1) or 1) > 1
+        # A 'tp' axis > 1 means tensor parallelism: Llama layer matrices shard
+        # over it (parallel/tp.py); the model is built with the matching axis.
+        use_tp = int(mesh_shape.get("tp", 1) or 1) > 1
+        # A 'pp' axis > 1 means pipeline parallelism (parallel/pp.py): the
+        # layer stack splits into stages; vocab pads to a pp multiple (the
+        # embedding/head are vocab-parallel over pp, like tp's).
+        pp_size = int(mesh_shape.get("pp", 1) or 1)
+        # padding multiple for the vocab-parallel embedding/head: the vocab
+        # dim splits over tp, pp, or — composed — their product
+        tp_size = int(mesh_shape.get("tp", 1) or 1)
+        vocab_mult = max(tp_size, 1) * max(pp_size, 1)
+        attention = "ring" if use_cp else cfg.train.get("use_pallas_attention", "auto")
+        # remat / attention values are validated downstream (wrap_remat /
+        # normalize_attention_impl) — YAML bools, None, and 'dots' all pass
+        # through unmangled so typos fail loudly instead of silently coercing.
+        initial_params = None
+        if bool(cfg.train.get("finetune", False)):
+            # finetune: True -> the model group's config_path names a local
+            # pretrained HF checkpoint (reference `main.py:33-35`; hub names
+            # resolve through ACCO_MODELS_ROOT, the root_path_model analogue).
+            from acco_tpu.models.hf_loader import from_pretrained
+
+            model, initial_params = from_pretrained(
+                cfg.model.config_path,
+                param_dtype=jnp.bfloat16 if use_mp else jnp.float32,
+                remat=cfg.train.get("remat", False),
+                attention=attention,
+                sequence_axis="sp" if use_cp else None,
+                scan_unroll=cfg.train.get("scan_unroll", 1),
+                zigzag=use_cp and bool(cfg.train.get("zigzag_cp", True)),
+                tensor_axis="tp" if use_tp else None,
+                vocab_pad_multiple=vocab_mult,
+            )
+        else:
+            model = build_model(
+                cfg.model,
+                repo_root=repo_root,
+                param_dtype=jnp.bfloat16 if use_mp else jnp.float32,
+                remat=cfg.train.get("remat", False),
+                attention=attention,
+                sequence_axis="sp" if use_cp else None,
+                scan_unroll=cfg.train.get("scan_unroll", 1),
+                zigzag=use_cp and bool(cfg.train.get("zigzag_cp", True)),
+                tensor_axis="tp" if use_tp else None,
+                vocab_pad_multiple=vocab_mult,
+            )
+    with tracer.span("setup/load_data", cat="setup") as loaded:
+        tokenizer = load_tokenizer(cfg.model.get("tokenizer"), log)
+        train_ds, eval_ds = load_text_dataset(cfg.data, log)
+        loaded["train_docs"] = len(train_ds)
     log.info(
         "model=%s train_docs=%d eval_docs=%d method=%s",
         cfg.model.config_path,
@@ -157,6 +175,7 @@ def run(argv: list[str] | None = None):
         seed=seed,
         run_dir=run_dir,
         initial_params=initial_params,
+        tracer=tracer,
     )
     summary = trainer.train()
     if summary.get("interrupted"):

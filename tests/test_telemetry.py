@@ -31,6 +31,7 @@ from acco_tpu.telemetry import (
     Tracer,
     UndeclaredMetricError,
     UndeclaredSpanError,
+    setup_phases,
     validate_trace,
 )
 from acco_tpu.telemetry import test_duration_records as duration_records  # noqa: E501  (aliased so pytest does not collect it)
@@ -262,6 +263,115 @@ def test_thread_name_event_counts_against_the_bound():
     tr = Tracer(max_events=1)
     tr.complete_event("train/dispatch", 0.001)
     assert [e["ph"] for e in tr.events()] == ["M"] and tr.dropped == 1
+
+
+# -- one clock from the launch (ISSUE 37) --------------------------------------
+
+
+def test_events_recorded_before_the_late_facts_survive_them():
+    """main.run makes the tracer before rank, telemetry.enabled and the
+    annotation factory are known; telling it later loses nothing and the
+    early spans stand at ts >= 0 on the clock the loop's spans use."""
+    ann = _Annotations()
+    tr = Tracer()
+    with tr.span("setup/config", cat="setup"):
+        pass
+    with tr.span("setup/trainer_init", cat="setup") as init:
+        tr.configure(enabled=True, process_name="acco-ddp", max_events=64,
+                     annotate=ann)
+        with tr.span("setup/tokenize", cat="setup", rows=3):
+            pass
+        init["method"] = "ddp"
+    with tr.span("train/dispatch"):
+        pass
+    trace = tr.to_dict()
+    assert validate_trace(trace) == []
+    assert trace["otherData"]["process"] == "acco-ddp" and tr.max_events == 64
+    spans = [e for e in trace["traceEvents"] if e["ph"] == "X"]
+    assert [e["name"] for e in spans] == [
+        "setup/config", "setup/tokenize", "setup/trainer_init", "train/dispatch",
+    ]
+    assert all(e["ts"] >= 0 for e in spans)
+    assert spans[0]["ts"] + spans[0]["dur"] <= spans[2]["ts"] + 0.2
+    assert spans[2]["args"] == {"method": "ddp"}
+    # only the spans that began after the factory arrived entered it
+    assert ann.entered == ["setup/tokenize", "train/dispatch"]
+    assert setup_phases(trace["traceEvents"]).keys() == {
+        "config", "trainer_init", "tokenize",
+    }
+
+
+@pytest.mark.parametrize("while_open", [True, False])
+def test_a_tracer_told_it_is_disabled_holds_nothing(while_open):
+    """telemetry.enabled=false, or a rank that writes no trace: what was
+    recorded since the launch goes, and so does a span that was open when
+    the word came (setup/trainer_init is)."""
+    tr = Tracer()
+    with tr.span("setup/config", cat="setup"):
+        pass
+    if while_open:
+        with tr.span("setup/trainer_init", cat="setup"):
+            tr.configure(enabled=False)
+    else:
+        tr.configure(enabled=False)
+    tr.complete_event("train/dispatch", 0.01)
+    with tr.span("setup/state_init", cat="setup"):
+        pass
+    assert tr.events() == [] and tr.dropped == 0
+    assert setup_phases(tr.events()) == {}
+
+
+def test_a_backend_compile_event_lands_on_the_compiling_threads_track():
+    """compile/cache.py's duration listener writes one compile/backend span
+    per jax backend-compile event: duration from the event, end = now,
+    on the track of whichever thread compiled."""
+    from acco_tpu.compile import cache
+
+    tr = Tracer()
+    cache.trace_compiles(tr)
+    try:
+        def warmup_thread():
+            with tr.span("compile/compile", cat="compile", program="round"):
+                cache._on_duration(cache._BACKEND_EVENT, 0.0)
+
+        t = threading.Thread(target=warmup_thread, name="acco-compile_0")
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+        with tr.span("setup/state_init", cat="setup"):
+            cache._on_duration(cache._BACKEND_EVENT, 0.0)  # a lazy compile
+        cache._on_duration("/jax/some/other_duration", 1.0)
+    finally:
+        cache.trace_compiles(None)
+    cache._on_duration(cache._BACKEND_EVENT, 0.0)  # nobody listens any more
+    trace = tr.to_dict()
+    assert validate_trace(trace) == []
+    track = {e["tid"]: e["args"]["name"] for e in trace["traceEvents"] if e["ph"] == "M"}
+    backend = [e for e in trace["traceEvents"] if e.get("name") == "compile/backend"]
+    assert sorted(track[e["tid"]] for e in backend) == ["MainThread", "acco-compile_0"]
+    assert all(e["cat"] == "compile" and e["ts"] >= 0 for e in backend)
+
+
+def test_a_compile_left_behind_by_an_earlier_run_stays_on_its_own_track():
+    """An earlier run's abandoned warmup fires its backend event into the
+    new run's tracer: the event is cut at the clock's zero, not pushed
+    past now, and the dead thread's ident, handed to a new thread, does
+    not put the two on one track."""
+    from acco_tpu.compile import cache
+
+    tr = Tracer()
+    cache.trace_compiles(tr)
+    try:
+        cache._on_duration(cache._BACKEND_EVENT, 5.0)  # began 5 s ago: before zero
+    finally:
+        cache.trace_compiles(None)
+    (ev,) = [e for e in tr.events() if e["ph"] == "X"]
+    assert ev["ts"] == 0.0 and ev["dur"] <= tr.now_us()
+    # a thread object the tracer has not seen, under an ident it has
+    tr._tids[threading.get_ident()] = (0, threading.Thread())
+    tr.complete_event("train/dispatch", 0.0)
+    tids = [e["tid"] for e in tr.events() if e["ph"] == "X"]
+    assert tids == [0, 1] and validate_trace(tr.to_dict()) == []
 
 
 def test_device_scopes_are_declared_once_and_distinct():

@@ -152,6 +152,105 @@ def test_acco_count_bookkeeping(eight_devices, tmp_path):
     assert other["profile_dir"] is None and other["profiled_rounds"] is None
     assert "attribution" not in other and "attribution" not in summary
 
+    # -- ISSUE 37 (same run again): set-up is tiled by spans on the loop's
+    # clock. On the main thread, from the constructor's start to the first
+    # block, the top-level spans follow one another with nothing between
+    # them, by ORDER as above (no bound in ms under six workers); a lazy
+    # compile's compile/backend lies inside one of them.
+    track = {e["tid"]: e["args"]["name"] for e in trace["traceEvents"]
+             if e.get("ph") == "M"}
+    first_block = next(e for e in loop if e["name"] == "loader/next_block")
+    head = [e for e in loop if e["ts"] + e["dur"] <= first_block["ts"] + 0.2]
+    top, end = [], -1.0
+    for e in sorted(head, key=lambda e: (e["ts"], -e["dur"])):
+        if e["ts"] >= end - 0.2:  # not inside the last top-level span
+            top.append(e)
+            end = e["ts"] + e["dur"]
+    assert [e["name"] for e in top] == [
+        "setup/trainer_init", "setup/state_init", "compile/warmup_join",
+        "setup/seed",
+    ]
+    assert end <= first_block["ts"] + 0.2
+    init = top[0]
+    assert init["args"] == {"method": "acco", "world_size": 8}
+    inside = [e for e in head if e is not init and init["ts"] <= e["ts"]
+              and e["ts"] + e["dur"] <= init["ts"] + init["dur"] + 0.2]
+    # these rows are shorter than max_length, so the const-len verdict flips
+    # during tokenising and the warmup starts a second time
+    assert [(e["name"], e.get("args")) for e in inside if e["name"] != "compile/backend"] == [
+        ("setup/start_warmup", {"programs": 3}),
+        ("setup/tokenize", {"rows": 64}),
+        ("setup/summary_writer", None),
+        ("setup/start_warmup", {"programs": 3}),
+    ]
+    restart = inside[-1]["ts"]
+    assert {"hits", "misses"} <= set(top[2]["args"])
+    assert top[3]["args"] == {"rounds": 0}
+    # every warmed program (the restart's; the abandoned ones finish in the
+    # background): one lowering and one compile on a warmup thread's track,
+    # each after its submit
+    warmed = sorted(t.compile_report.programs)
+    assert warmed == ["round_even", "round_odd", "seed"]
+    for name in ("compile/lower", "compile/compile"):
+        spans = [e for e in events
+                 if e["name"] == name and e["args"]["submitted_us"] >= restart]
+        assert sorted(e["args"]["program"] for e in spans) == warmed
+        for e in spans:
+            assert track[e["tid"]].startswith("acco-compile")
+            assert e["args"]["submitted_us"] <= e["ts"]
+            if name == "compile/compile":  # deserialised or compiled
+                assert e["args"]["hits"] + e["args"]["misses"] >= 1
+                rec = t.compile_report.programs[e["args"]["program"]]
+                assert rec.compile_ms == pytest.approx(e["dur"] / 1e3, abs=1e-3)
+    # nothing of set-up begins once the loop dispatches (the abandoned
+    # warmup's threads apart: they may still be compiling)
+    first_dispatch = next(e for e in events if e["name"] == "train/dispatch")
+    of_this_run = loop + [e for e in events if e["name"] in ("compile/lower", "compile/compile")
+                          and e["args"]["submitted_us"] >= restart]
+    assert not [e["name"] for e in of_this_run
+                if e["name"].startswith(("setup/", "compile/"))
+                and e["ts"] > first_dispatch["ts"]]
+    # the summary's phases are the spans'; a trainer built in code has no
+    # main.run phases, and its top-level ones cannot outlast setup_s
+    phases = summary["setup"]
+    assert list(phases) == ["trainer_init", "start_warmup", "tokenize",
+                            "summary_writer", "state_init", "warmup_join", "seed"]
+    assert phases["start_warmup"] == pytest.approx(
+        sum(e["dur"] for e in inside if e["name"] == "setup/start_warmup") / 1e6)
+    assert phases["trainer_init"] == pytest.approx(init["dur"] / 1e6)
+    assert (phases["trainer_init"] + phases["state_init"] + phases["warmup_join"]
+            + phases["seed"]) <= summary["setup_s"] + 1e-3
+
+
+def test_the_set_up_line_names_every_phase_and_what_the_join_learned():
+    from acco_tpu.trainer import _setup_line
+
+    def spans(phases, **args):
+        return [{"ph": "X", "name": name, "ts": 0.0, "dur": s * 1e6,
+                 **({"args": args[name.split("/")[1]]} if name.split("/")[1] in args else {})}
+                for name, s in phases.items()]
+
+    joined = {"hits": 3, "misses": 0, "cache_dir_bytes": 188 * 2**20,
+              "cache_max_bytes": 192 * 2**20}
+    events = spans(
+        {"setup/config": 0.31, "setup/imports": 0.12, "setup/build_model": 0.4,
+         "setup/load_data": 1.2, "setup/trainer_init": 9.8, "setup/start_warmup": 0.7,
+         "setup/tokenize": 6.1, "setup/summary_writer": 2.2, "setup/state_init": 7.2,
+         "compile/warmup_join": 58.3, "setup/seed": 2.9},
+        config={"cache_dir_bytes": 201 * 2**20}, warmup_join=joined)
+    assert _setup_line(84.1, events) == (
+        "set-up 84.1 s: config 0.3, imports 0.1, model 0.4, data 1.2, trainer 9.8 "
+        "(tokenize 6.1, writer 2.2), state 7.2, warmup join 58.3 [3 hits 0 misses, "
+        "cache 201->188/192 MiB], seed 2.9"
+    )
+    # no cap set, a trainer built in code (no main.run phases, nothing known of the launch)
+    events = spans({"setup/trainer_init": 1.0, "compile/warmup_join": 2.0},
+                   warmup_join={"hits": 0, "misses": 1, "cache_dir_bytes": 2**20})
+    assert _setup_line(3.0, events) == (
+        "set-up 3.0 s: trainer 1.0, warmup join 2.0 [0 hits 1 misses, cache 1 MiB]"
+    )
+    assert _setup_line(1.0, spans({"setup/state_init": 1.0})) == "set-up 1.0 s: state 1.0"
+
 
 @pytest.mark.parametrize("method", ["ddp", "dpu", "acco"])
 def test_heterogeneous_mask_bookkeeping(eight_devices, tmp_path, method):

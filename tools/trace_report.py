@@ -1,13 +1,18 @@
 """Summarize a telemetry ``trace_*.json`` into one terminal report.
 
 Reads the Chrome/Perfetto trace a run wrote (``acco_tpu/telemetry``),
-validates it, and prints two tables:
+validates it, and prints three tables:
 
 1. **top spans** — per span name: count, total/mean/median/max wall, so
    "where did the host's time go" has an answer without opening a viewer;
 2. **logging boundaries** — what each ``train/log_boundary_sync`` fence
    learned (round, loss, grad norm, committed grads, skipped rounds) and
-   how long the fence and the host work after it took.
+   how long the fence and the host work after it took;
+3. **set-up** — the main thread's set-up spans from the launch to the first
+   round (what the run's ``set-up`` log line holds), beneath them each warmed
+   program's ``compile/lower`` and ``compile/compile`` on its warmup thread
+   (how long it waited for a worker, hit or miss) and the main thread's
+   ``compile/backend`` events: what compiled lazily on the critical path.
 
 Where the DEVICE's time went, and which host span each of its idle gaps
 lies under, is read from the ``jax.profiler`` capture the trace names
@@ -36,7 +41,11 @@ from statistics import median
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from acco_tpu.telemetry import validate_trace  # noqa: E402
+from acco_tpu.telemetry import (  # noqa: E402
+    INSIDE_TRAINER_INIT,
+    setup_phases,
+    validate_trace,
+)
 
 def newest_trace(root: str = REPO) -> str | None:
     paths = glob.glob(os.path.join(root, "outputs", "**", "trace_*.json"),
@@ -121,6 +130,66 @@ def boundary_table(events: list[dict], last: int = 8) -> list[str]:
     return lines
 
 
+def setup_table(events: list[dict]) -> list[str]:
+    """Set-up by phase, then the warmup threads' programs and the lazy
+    compiles, all before the first ``train/dispatch``."""
+    phases = setup_phases(events)
+    if not phases:
+        return ["set-up: (no setup/* span in this trace)"]
+    spans = [e for e in events if e.get("ph") == "X"]
+    first_dispatch = min(
+        (e["ts"] for e in spans if e["name"] == "train/dispatch"),
+        default=float("inf"),
+    )
+    lines = ["set-up (main thread, s):"]
+    for phase, seconds in phases.items():
+        indent = "  " if phase in INSIDE_TRAINER_INIT else ""
+        lines.append("  {}{:<18} {:>10.3f}".format(indent, phase, seconds))
+    join = next((e for e in spans if e["name"] == "compile/warmup_join"), None)
+    if join and join.get("args"):
+        lines.append("  the join learned: " + ", ".join(
+            f"{k}={v}" for k, v in join["args"].items()
+        ))
+    programs: dict[str, dict] = {}
+    for e in spans:
+        if e["name"] in ("compile/lower", "compile/compile"):
+            programs.setdefault(e["args"]["program"], {})[e["name"]] = e
+    if programs:
+        lines.append("  warmup threads (s):")
+        lines.append("    {:<14} {:>8} {:>8} {:>8}  {}".format(
+            "program", "waited", "lower", "compile", "cache"))
+        for name, got in programs.items():
+            lower, comp = got.get("compile/lower"), got.get("compile/compile")
+            first = lower or comp
+            args = (comp or {}).get("args", {})
+            lines.append("    {:<14} {:>8.3f} {:>8} {:>8}  {}".format(
+                name, (first["ts"] - first["args"]["submitted_us"]) / 1e6,
+                "-" if lower is None else f"{lower['dur'] / 1e6:.3f}",
+                "-" if comp is None else f"{comp['dur'] / 1e6:.3f}",
+                "hit" if args.get("hits") and not args.get("misses")
+                else "miss" if args.get("misses") else "-",
+            ))
+    # the main thread's backend compiles: no warmed program owns them, the
+    # critical path compiled (or deserialised) them lazily
+    main = next((e["tid"] for e in spans if e["name"] in
+                 ("setup/trainer_init", "setup/state_init")), None)
+    bare = [
+        e for e in spans
+        if e["name"] == "compile/backend" and e["tid"] == main
+        and e["ts"] < first_dispatch
+    ]
+    if bare:
+        longest = max(bare, key=lambda e: e["dur"])
+        lines.append(
+            "  lazy compiles before the first dispatch: {} event(s), {:.3f} s "
+            "(longest {:.3f} s at {:.3f} s)".format(
+                len(bare), sum(e["dur"] for e in bare) / 1e6,
+                longest["dur"] / 1e6, longest["ts"] / 1e6,
+            )
+        )
+    return lines
+
+
 def report(path: str, top: int = 12) -> list[str]:
     with open(path, encoding="utf-8") as f:
         trace = json.load(f)
@@ -141,6 +210,8 @@ def report(path: str, top: int = 12) -> list[str]:
     lines += span_table(events, top)
     lines.append("")
     lines += boundary_table(events)
+    lines.append("")
+    lines += setup_table(events)
     if other.get("profile_dir"):
         lines.append("")
         lines.append(
