@@ -77,7 +77,7 @@ SPAN_NAMES = frozenset(
         "setup/trainer_init",    # the trainer's constructor (parent of the next three)
         "setup/start_warmup",    # step object, abstract state, jit objects, submits
         "setup/tokenize",        # tokenize/pack both datasets, const-len check, loaders
-        "setup/summary_writer",  # the TensorBoard writer and its import (in trainer_init)
+        "setup/summary_writer",  # the event writer (in trainer_init; args writer, heavy_modules)
         "setup/state_init",      # model.init + step.init_state (compile lazily here)
         "setup/restore",         # the resume_from restore, only when it runs
         "setup/seed",            # block source, seed program / DPU warm-up rounds, the

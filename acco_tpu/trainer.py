@@ -115,13 +115,19 @@ def _setup_line(total_s: float, events: list) -> str:
         set-up 84.1 s: config 0.3, imports 0.1, model 0.4, data 1.2, trainer
         9.8 (tokenize 6.1, writer 2.2), state 7.2, warmup join 58.3 [3 hits
         0 misses, cache 201->188/192 MiB], seed 2.9
+
+    ``writer 2.2 [heavy_modules torch, tensorflow]`` where the process had
+    loaded one of ``utils.logs.HEAVY_MODULES`` by the end of that span.
     """
     phases = setup_phases(events)
     args = {
         e["name"]: e.get("args") or {} for e in events
-        if e.get("name") in ("setup/config", "compile/warmup_join")
+        if e.get("name") in (
+            "setup/config", "setup/summary_writer", "compile/warmup_join"
+        )
     }
     joined = args.get("compile/warmup_join", {})
+    heavy = args.get("setup/summary_writer", {}).get("heavy_modules")
     at_launch = args.get("setup/config", {}).get("cache_dir_bytes")
     parts = []
     for phase, seconds in phases.items():
@@ -129,8 +135,9 @@ def _setup_line(total_s: float, events: list) -> str:
             continue
         part = f"{_SETUP_LABELS.get(phase, phase)} {seconds:.1f}"
         if phase == "trainer_init":
+            said = {"summary_writer": f" [heavy_modules {', '.join(heavy)}]"} if heavy else {}
             inside = [
-                f"{_SETUP_LABELS.get(p, p)} {phases[p]:.1f}"
+                f"{_SETUP_LABELS.get(p, p)} {phases[p]:.1f}{said.get(p, '')}"
                 for p in ("tokenize", "summary_writer") if p in phases
             ]
             part += f" ({', '.join(inside)})" if inside else ""
@@ -550,9 +557,12 @@ class DecoupledTrainer:
                 self.run_dir, f"trace_{self.id_run}.json"
             )
             run_name = str(_arg(args, "run_name", self.method))
-            # a span of its own inside setup/trainer_init: the writer's
-            # import (torch.utils.tensorboard) costs seconds of every launch
-            with self.tracer.span("setup/summary_writer", cat="setup"):
+            # a span of its own inside setup/trainer_init: the making of the
+            # run's event writer (utils/logs.EventWriter imports TensorBoard's
+            # protos and never torch, whose import is 25-69 s of a launch); its
+            # args say which writer the run got and which heavy packages the
+            # process has loaded all the same (a Hugging Face tokenizer's torch)
+            with self.tracer.span("setup/summary_writer", cat="setup") as made:
                 self.writer = (
                     logs_utils.make_summary_writer(
                         os.path.join(self.run_dir, "tensorboard", run_name, self.id_run)
@@ -560,6 +570,11 @@ class DecoupledTrainer:
                     if self.rank == 0
                     else logs_utils.NoOpWriter()
                 )
+                made["writer"] = (
+                    "events" if isinstance(self.writer, logs_utils.EventWriter)
+                    else "noop"
+                )
+                made["heavy_modules"] = logs_utils.heavy_modules()
             self.ckpt_dir = os.path.join(self.run_dir, "checkpoints", run_name)
             self.checkpoint_every_s = float(_arg(args, "checkpoint_every_s", 1800))
             # Resilience (acco_tpu/resilience): overlapped async checkpointing

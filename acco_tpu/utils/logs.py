@@ -5,20 +5,27 @@ Capability parity with the reference's observability layer
 (``loss_t`` / ``loss_step`` / ``loss_samples`` and the ``eval_loss_*``
 family, `:187-224`), the append-with-schema-merge ``results.csv`` ledger
 (`:83-138`), the per-N-grads progress log line (`:155-183`), and the
-run-id scheme (`:19-40`). TensorBoard writing goes through
-``torch.utils.tensorboard`` (available in this image) but degrades to a
-no-op writer when unavailable, so training never depends on it.
+run-id scheme (`:19-40`). The TensorBoard files are written by this
+module's own ``EventWriter`` over TensorBoard's protos and record framing:
+the files ``torch.utils.tensorboard.SummaryWriter`` lays down for the same
+calls, without importing ``torch`` (which pulls ``tensorflow`` and ``keras``
+in where the image has them: 25-69 s of a launch on the chip's host,
+PERF.md section 6). It degrades to a no-op writer where ``tensorboard`` is
+unavailable, so training never depends on it.
 """
 
 from __future__ import annotations
 
 import csv
 import datetime
+import itertools
 import os
 import random
+import socket
+import struct
+import sys
 import time
 from typing import Any, Dict, Iterable, Optional
-
 
 
 class NoOpWriter:
@@ -37,13 +44,121 @@ class NoOpWriter:
         pass
 
 
+def _crc32c_table() -> tuple:
+    """CRC-32C (Castagnoli), reflected: one entry a byte."""
+    table = []
+    for entry in range(256):
+        for _ in range(8):
+            entry = (entry >> 1) ^ 0x82F63B78 if entry & 1 else entry >> 1
+        table.append(entry)
+    return tuple(table)
+
+
+_CRC32C = _crc32c_table()
+
+
+def _masked_crc32c(data: bytes) -> bytes:
+    crc = 0xFFFFFFFF
+    for byte in data:
+        crc = _CRC32C[(crc ^ byte) & 0xFF] ^ (crc >> 8)
+    crc ^= 0xFFFFFFFF
+    return struct.pack("<I", (((crc >> 15) | (crc << 17)) + 0xA282EAD8) & 0xFFFFFFFF)
+
+
+def event_record(payload: bytes) -> bytes:
+    """One record of an event file (TFRecord framing, little-endian): the
+    payload's length as uint64, the masked crc32c of those eight bytes, the
+    payload, its masked crc32c. What ``tensorboard.summary.writer.
+    record_writer.RecordWriter`` writes; importing that module loads 98 more
+    (every plugin's summary, a stub of ``tf``), 3 s of the launch's main
+    thread under the lowering threads' GIL (PERF.md section 6, PR 38)."""
+    header = struct.pack("<Q", len(payload))
+    return header + _masked_crc32c(header) + payload + _masked_crc32c(payload)
+
+
+class EventWriter:
+    """The run's scalars as TensorBoard event files, laid down where
+    ``torch.utils.tensorboard.SummaryWriter`` puts them: ``add_scalar``
+    writes its tag into ``log_dir``'s own file, ``add_scalars(main_tag,
+    {key: value}, step)`` writes ``main_tag`` into a file of
+    ``log_dir/<main_tag>_<key>/``; ``step`` is ``int(step)`` and the wall
+    time is the call's. A file is opened once, at the writer's making or at
+    its directory's first scalar, and written through Python's buffer: a
+    call costs no system call, ``flush()`` and ``close()`` hand the buffers
+    to the OS (no ``fsync``), and so does the first call ``FLUSH_SECS``
+    after the last hand-over."""
+
+    FLUSH_SECS = 120.0  # torch's default
+    _file_ids = itertools.count()  # the file name's last part, process-wide
+
+    def __init__(self, log_dir: str) -> None:
+        from tensorboard.compat.proto import event_pb2, summary_pb2
+
+        self._event, self._summary = event_pb2.Event, summary_pb2.Summary
+        self.log_dir = log_dir
+        self._files: Dict[str, Any] = {}  # directory -> its open event file
+        self._last_flush = time.time()
+        self._file(log_dir)
+
+    def _file(self, directory: str):
+        file = self._files.get(directory)
+        if file is None:
+            os.makedirs(directory, exist_ok=True)
+            now = time.time()
+            name = "events.out.tfevents.%010d.%s.%s.%s" % (
+                now, socket.gethostname(), os.getpid(), next(self._file_ids)
+            )
+            file = open(os.path.join(directory, name), "wb")
+            version = self._event(wall_time=now, file_version="brain.Event:2")
+            file.write(event_record(version.SerializeToString()))
+            file.flush()  # a reader finds a whole file from the start
+            self._files[directory] = file
+        return file
+
+    def _write(self, directory: str, tag: str, value: Any, step: Any) -> None:
+        now = time.time()
+        summary = self._summary(
+            value=[self._summary.Value(tag=tag, simple_value=float(value))]
+        )
+        event = self._event(wall_time=now, step=int(step), summary=summary)
+        self._file(directory).write(event_record(event.SerializeToString()))
+        if now - self._last_flush >= self.FLUSH_SECS:
+            self.flush()
+
+    def add_scalar(self, tag: str, value: Any, step: Any) -> None:
+        self._write(self.log_dir, tag, value, step)
+
+    def add_scalars(self, main_tag: str, values: Dict[str, Any], step: Any) -> None:
+        for key, value in values.items():
+            directory = self.log_dir + "/" + main_tag.replace("/", "_") + "_" + key
+            self._write(directory, main_tag, value, step)
+
+    def flush(self) -> None:
+        self._last_flush = time.time()
+        for file in self._files.values():
+            file.flush()
+
+    def close(self) -> None:
+        for file in self._files.values():
+            file.close()
+        self._files = {}
+
+
 def make_summary_writer(log_dir: str):
     try:
-        from torch.utils.tensorboard import SummaryWriter
-
-        return SummaryWriter(log_dir)
+        return EventWriter(log_dir)
     except Exception:
         return NoOpWriter()
+
+
+HEAVY_MODULES = ("torch", "tensorflow", "keras")
+
+
+def heavy_modules() -> list[str]:
+    """Which of the packages that cost tens of seconds to import this
+    process has loaded (``setup/summary_writer`` reports it: ``[]`` is a
+    launch that paid for none)."""
+    return [name for name in HEAVY_MODULES if name in sys.modules]
 
 
 def create_id_run() -> str:

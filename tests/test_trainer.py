@@ -6,6 +6,7 @@ data; checkpoints round-trip through Orbax with real resume (the designed
 improvement over the reference's save-only path, SURVEY.md §5).
 """
 
+import glob
 import os
 
 import jax
@@ -17,6 +18,7 @@ from acco_tpu.configuration import config_from_dict
 from acco_tpu.data.tokenizer import ByteTokenizer
 from acco_tpu.models import LlamaConfig, LlamaModel
 from acco_tpu.trainer import DecoupledTrainer
+from acco_tpu.utils import logs as logs_utils
 
 CFG = LlamaConfig(
     vocab_size=257, hidden_size=32, intermediate_size=64, num_layers=1,
@@ -105,10 +107,18 @@ def test_acco_count_bookkeeping(eight_devices, tmp_path):
     # round parity: rounds = commits*2 (speculative+real), +seed not counted
     assert summary["rounds"] == 2 * (summary["count_grad_tot"] // 16)
 
+    # -- ISSUE 38 (same run): the scalars are on disk when train() returns,
+    # in the directories torch's writer gave them (rank 0's: "_0")
+    (events_dir,) = glob.glob(str(tmp_path / "tensorboard" / "*" / t.id_run))
+    assert sorted(os.listdir(events_dir))[-3:] == ["loss_samples_0", "loss_step_0", "loss_t_0"]
+    for sub in ("", "loss_t_0", "loss_step_0", "loss_samples_0"):
+        (name,) = [n for n in os.listdir(os.path.join(events_dir, sub)) if "tfevents" in n]
+        # the version record is 40 bytes, a scalar's 55 or more
+        assert os.path.getsize(os.path.join(events_dir, sub, name)) >= 95
+
     # -- ISSUE 19 / 23 acceptance (same run: one compile bill) --
     # the tiny smoke run writes a loadable Perfetto trace whose spans
     # tile the loop, the boundary's fence carrying what it learned
-    import glob
     import json
 
     from acco_tpu.telemetry import DECLARED_DEVICE_SCOPES, validate_trace
@@ -180,7 +190,11 @@ def test_acco_count_bookkeeping(eight_devices, tmp_path):
     assert [(e["name"], e.get("args")) for e in inside if e["name"] != "compile/backend"] == [
         ("setup/start_warmup", {"programs": 3}),
         ("setup/tokenize", {"rows": 64}),
-        ("setup/summary_writer", None),
+        # the run's own event writer; what this process has loaded is the
+        # collection's doing (test_hf_loader imports torch): a fresh process
+        # reads [] (tests/test_event_writer.py)
+        ("setup/summary_writer",
+         {"writer": "events", "heavy_modules": logs_utils.heavy_modules()}),
         ("setup/start_warmup", {"programs": 3}),
     ]
     restart = inside[-1]["ts"]
@@ -243,6 +257,16 @@ def test_the_set_up_line_names_every_phase_and_what_the_join_learned():
         "(tokenize 6.1, writer 2.2), state 7.2, warmup join 58.3 [3 hits 0 misses, "
         "cache 201->188/192 MiB], seed 2.9"
     )
+    # a launch that loaded a heavy package all the same says which, beside the writer
+    events = spans({"setup/trainer_init": 40.0, "setup/tokenize": 6.1,
+                    "setup/summary_writer": 30.5},
+                   summary_writer={"writer": "events", "heavy_modules": ["torch", "keras"]})
+    assert _setup_line(41.0, events) == (
+        "set-up 41.0 s: trainer 40.0 (tokenize 6.1, writer 30.5 [heavy_modules torch, keras])"
+    )
+    events = spans({"setup/trainer_init": 2.0, "setup/summary_writer": 0.5},
+                   summary_writer={"writer": "noop", "heavy_modules": []})
+    assert _setup_line(2.0, events) == "set-up 2.0 s: trainer 2.0 (writer 0.5)"
     # no cap set, a trainer built in code (no main.run phases, nothing known of the launch)
     events = spans({"setup/trainer_init": 1.0, "compile/warmup_join": 2.0},
                    warmup_join={"hits": 0, "misses": 1, "cache_dir_bytes": 2**20})
@@ -315,7 +339,6 @@ def test_profiled_rounds_put_the_loops_spans_on_the_host_plane(
     /host:CPU plane under the span's own name, on the trainer's thread,
     in the same file as the device's ops — and trace_<id>.json says
     where that file is and which rounds it holds."""
-    import glob
     import json
 
     from jax.profiler import ProfileData

@@ -145,11 +145,13 @@ def setup_table(events: list[dict]) -> list[str]:
     for phase, seconds in phases.items():
         indent = "  " if phase in INSIDE_TRAINER_INIT else ""
         lines.append("  {}{:<18} {:>10.3f}".format(indent, phase, seconds))
-    join = next((e for e in spans if e["name"] == "compile/warmup_join"), None)
-    if join and join.get("args"):
-        lines.append("  the join learned: " + ", ".join(
-            f"{k}={v}" for k, v in join["args"].items()
-        ))
+    for name, said in (("setup/summary_writer", "the writer"),
+                       ("compile/warmup_join", "the join learned")):
+        span = next((e for e in spans if e["name"] == name), None)
+        if span and span.get("args"):
+            lines.append(f"  {said}: " + ", ".join(
+                f"{k}={v}" for k, v in span["args"].items()
+            ))
     programs: dict[str, dict] = {}
     for e in spans:
         if e["name"] in ("compile/lower", "compile/compile"):
