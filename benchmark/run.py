@@ -124,6 +124,13 @@ def parent(args) -> int:
     manifest = Manifest()
     cell = manifest.cell(args.workload)
     manifest.config(cell["config"])  # a cell whose configuration is missing fails here
+    # the program's list of scope names; telemetry imports no JAX, so the chip stays the children's
+    from acco_tpu.telemetry.trace import DECLARED_DEVICE_SCOPES
+
+    breaches = manifest.breaches(DECLARED_DEVICE_SCOPES)
+    if breaches:  # here, and not as a line that lacks a metric its cell lists
+        raise ManifestError("what a cell reports must follow from what its configuration runs "
+                            "(harness/manifest.py):\n" + "\n".join(f"  {b}" for b in breaches))
     deadline = time.time() + CHILD_LIMIT_S
     children = {}
     for index, schedule in enumerate(cell["schedules"]):

@@ -193,21 +193,20 @@ def test_the_cell_is_what_the_issue_defined():
     names = {s["name"] for s in m.layer_metrics("laguna-l5-acco-1chip")}
     assert {"moe_router_ms", "moe_dispatch_ms", "moe_experts_ms", "moe_experts_roofline",
             "moe_shared_ms", "moe_shared_roofline", "moe_held_share_pct", "attn_kernel_ms",
-            "attn_kernel_roofline", "full_attn_kernel_ms", "full_attn_kernel_roofline",
+            "attn_kernel_roofline", "flash_attn_kernel_ms", "flash_attn_kernel_roofline",
             "block_ms", "mfu_pct"} <= names
-    # no other cell reports what this PR brings
-    new = {"moe_shared_ms", "moe_shared_roofline", "moe_held_share_pct", "full_attn_kernel_ms",
-           "full_attn_kernel_roofline"}
-    for other in m.cell_names():
-        if other != "laguna-l5-acco-1chip":
-            assert not new & {s["name"] for s in m.layer_metrics(other)}, other
+    # what the configuration says it runs: both kinds of kernel, a chip's share of the experts beside
+    # a shared one. Which cells list what follows from that (tests/benchmark/test_bench_rules.py).
+    assert m.features("laguna-l5-acco-1chip") == {
+        "own_attention_kernels", "stock_flash_kernels", "experts", "shared_expert", "held_experts"}
+    assert {s["needs"] for s in m.layer_metrics("laguna-l5-acco-1chip") if "needs" in s} == m.features(
+        "laguna-l5-acco-1chip")
 
 
 def test_the_new_metrics_read_what_the_program_writes(config):
     """The shared expert's scope has one owner among the scope metrics and its
     roofline reads the same ops; the gauge is read off the boundary span's
     arguments over the window, and a program that writes none gives nothing."""
-    from acco_tpu.telemetry import DECLARED_DEVICE_SCOPES
     from benchmark.harness.window import Fence, Window
 
     m = Manifest()
@@ -216,9 +215,11 @@ def test_the_new_metrics_read_what_the_program_writes(config):
     assert shared["scopes"] == roofline["scopes"] == ["model/moe_shared"]
     assert roofline["work"] == "shared_expert_work"
     assert shared["except_ops"] == spec["block_ms"]["args"]["except_ops"] == roofline["except_ops"]
-    # every declared scope has one owner among the cell's scope metrics ("" is what no scope holds)
-    scopes = [scope for s in spec.values() if s["reducer"] == "scope_op_time" for scope in s["args"]["scopes"]]
-    assert sorted(s for s in scopes if s) == sorted(DECLARED_DEVICE_SCOPES)
+    assert spec["moe_shared_ms"]["needs"] == spec["moe_shared_roofline"]["needs"] == "shared_expert"
+    # the stock flash kernels of the full layers are read under the one name the benchmark has for them
+    flash, share = spec["flash_attn_kernel_ms"]["args"], spec["flash_attn_kernel_roofline"]["args"]
+    assert flash["regex"] == "flash_attention|flash_mha_bwd" and share["kernels"] == {flash["regex"]: "global"}
+    assert spec["attn_kernel_roofline"]["args"]["kernels"] == {"acco_fused_attn": "global", "acco_banded_attn": "local"}
 
     def boundary(ts, **args):
         return {"ph": "X", "name": "train/log_boundary_sync", "ts": ts, "dur": 5.0, "args": args}

@@ -175,21 +175,21 @@ def test_new_files_need_no_edit_of_code(tmp_path):
     (bench / "configs" / "new-model" / "model.json").write_text(
         json.dumps({"model_type": "gpt_neo", "hidden_size": 1024}))
     (bench / "configs" / "new-model" / "config.json").write_text(
-        json.dumps({"source": "https://example.org/new", "reduced": []}))
+        json.dumps({"source": "https://example.org/new", "reduced": [], "runs": ["checkpoints"]}))
     data["configs"].append({"name": "new-model", "source": "https://example.org/new",
                             "file": "benchmark/configs/new-model/model.json",
                             "reduced": [], "why": "test"})
 
     with open(bench / "workloads" / "neo125m-ddp-1chip.json") as f:
         cell = json.load(f)
-    cell.update(config="new-model", traffic="ddp-bs16", batch_per_chip=16)
+    cell.update(config="new-model", traffic="ddp-bs16", batch_per_chip=16, why="test")
     (bench / "workloads" / "new-cell.json").write_text(json.dumps(cell))
     data["workloads"].append({"name": "new-cell", "config": "new-model",
                               "traffic": "ddp-bs16", "chips": 1, "why": "test"})
 
     (bench / "layer_metrics" / "ckpt_snapshot_ms.json").write_text(json.dumps({
         "name": "ckpt_snapshot_ms", "layer": "checkpoint", "unit": "ms", "better": "lower",
-        "source": "program_span", "moves": "tokens_per_s_per_chip",
+        "source": "program_span", "moves": "tokens_per_s_per_chip", "needs": "checkpoints",
         "reducer": "span_stat", "args": {"span": "ckpt/snapshot", "stat": "max"}}))
     data["per_layer"].append({"name": "ckpt_snapshot_ms", "unit": "ms", "better": "lower",
                               "source": "program_span", "layer": "checkpoint",
@@ -203,6 +203,7 @@ def test_new_files_need_no_edit_of_code(tmp_path):
     names = [s["name"] for s in m.layer_metrics("new-cell")]
     assert "ckpt_snapshot_ms" in names and "mfu_pct" in names
     assert "ckpt_snapshot_ms" not in [s["name"] for s in m.layer_metrics("neo125m-ddp-1chip")]
+    assert m.features("new-cell") == {"checkpoints"} and m.breaches(declared_scopes()) == []
     # the new metric's reader is an existing kind, found by name; it reads nothing
     # from a trace that has no such span, and says so by returning nothing
     from benchmark.harness import window as win
@@ -214,6 +215,131 @@ def test_new_files_need_no_edit_of_code(tmp_path):
     assert m.reducer(spec["reducer"])(ctx, spec["args"]) is None
     dispatch = next(s for s in m.layer_metrics("new-cell") if s["name"] == "dispatch_ms")
     assert m.reducer(dispatch["reducer"])(ctx, dispatch["args"]) > 0
+
+
+def declared_scopes() -> list:
+    from acco_tpu.telemetry import DECLARED_DEVICE_SCOPES
+
+    return list(DECLARED_DEVICE_SCOPES)
+
+
+def write_json(path, data) -> None:
+    path.write_text(json.dumps(data))
+
+
+def edit_json(path, change) -> None:
+    with open(path) as f:
+        data = json.load(f)
+    change(data)
+    write_json(path, data)
+
+
+@pytest.fixture()
+def drawn(tmp_path):
+    """A copy of the benchmark with what the next configuration would bring,
+    as files and manifest entries only: a model that holds a chip's share of
+    its experts, runs NEITHER attention kernel the benchmark reads by name, and
+    has a mixer of its own whose device scope gets a metric of its own. The
+    cell is in no metric's ``workloads`` yet."""
+    bench = tmp_path / "benchmark"
+    shutil.copytree(os.path.join(ROOT, "benchmark"), bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        data = json.load(f)
+    (bench / "configs" / "drawn-model").mkdir()
+    write_json(bench / "configs" / "drawn-model" / "model.json", {"model_type": "drawn", "hidden_size": 2048})
+    write_json(bench / "configs" / "drawn-model" / "config.json",
+               {"source": "https://example.org/drawn", "reduced": ["num_experts"],
+                "runs": ["experts", "held_experts", "made_up_mixer"]})
+    data["configs"].append({"name": "drawn-model", "source": "https://example.org/drawn",
+                            "file": "benchmark/configs/drawn-model/model.json",
+                            "reduced": ["num_experts"], "why": "test"})
+    with open(bench / "workloads" / "neo125m-acco-1chip.json") as f:
+        cell = json.load(f)
+    cell.update(config="drawn-model", traffic="acco-seq8192-bs1", why="test")
+    write_json(bench / "workloads" / "drawn-cell.json", cell)
+    data["workloads"].append({"name": "drawn-cell", "config": "drawn-model",
+                              "traffic": "acco-seq8192-bs1", "chips": 1, "why": "test"})
+    with open(bench / "layer_metrics" / "moe_router_ms.json") as f:
+        mixer = json.load(f)
+    mixer.update(name="made_up_mixer_ms", needs="made_up_mixer", what="test")
+    mixer["args"]["scopes"] = ["model/made_up_mixer"]
+    write_json(bench / "layer_metrics" / "made_up_mixer_ms.json", mixer)
+    data["per_layer"].append({"name": "made_up_mixer_ms", "unit": "ms/round", "better": "lower",
+                              "source": "device_trace", "layer": "model",
+                              "moves": "tokens_per_s_per_chip", "workloads": []})
+    write_json(tmp_path / "BENCHMARK.json", data)
+    return tmp_path
+
+
+def follow_the_sentences(root) -> list:
+    """Add the cell to every list that an R1 sentence says it owes; the
+    metrics so extended."""
+    owed = [b for b in Manifest(root=str(root)).breaches([*declared_scopes(), "model/made_up_mixer"])
+            if b.rule == "R1" and "add it to that metric's `workloads`" in b.sentence]
+
+    def extend(data):
+        for b in owed:
+            next(m for m in data["per_layer"] if m["name"] == b.metric)["workloads"].append(b.cell)
+
+    edit_json(root / "BENCHMARK.json", extend)
+    return sorted(b.metric for b in owed)
+
+
+def test_the_next_configuration_is_files_and_list_entries(drawn):
+    """The dress rehearsal of the configuration the harness could not take:
+    held experts, a mixer scope of its own, neither attention kernel. With the
+    cell's name added to the lists the rule names, and to no other, every rule
+    holds in the copy, and no file of code or test was touched there."""
+    scopes = [*declared_scopes(), "model/made_up_mixer"]
+    before = Manifest(root=str(drawn)).breaches(scopes)
+    assert {b.cell for b in before} == {"drawn-cell"}  # no accepted cell is touched
+    assert follow_the_sentences(drawn) == ["made_up_mixer_ms", "moe_dispatch_ms", "moe_experts_ms",
+                                           "moe_experts_roofline", "moe_held_share_pct", "moe_router_ms"]
+    m = Manifest(root=str(drawn))
+    assert m.breaches(scopes) == []
+    assert m.features("drawn-cell") == {"experts", "held_experts", "made_up_mixer"}
+    names = {s["name"] for s in m.layer_metrics("drawn-cell")}
+    assert {"made_up_mixer_ms", "moe_held_share_pct", "block_ms", "mfu_pct"} <= names
+    assert not {"attn_kernel_ms", "attn_kernel_roofline", "flash_attn_kernel_ms", "moe_shared_ms"} & names
+    # the program's list without the mixer's scope: the copy's metric owns a scope nobody declared
+    assert ["made_up_mixer_ms"] == [b.metric for b in m.breaches(declared_scopes())]
+    for cell in Manifest().cell_names():  # every accepted cell lists what it listed
+        assert [s["name"] for s in m.layer_metrics(cell)] == [s["name"] for s in Manifest().layer_metrics(cell)]
+
+
+def leave_out_of_the_held_share(root):
+    edit_json(root / "BENCHMARK.json", lambda data: next(
+        m for m in data["per_layer"] if m["name"] == "moe_held_share_pct")["workloads"].remove("drawn-cell"))
+
+
+def list_under_the_own_kernels(root):
+    edit_json(root / "BENCHMARK.json", lambda data: next(
+        m for m in data["per_layer"] if m["name"] == "attn_kernel_ms")["workloads"].append("drawn-cell"))
+
+
+def give_the_scope_a_second_owner(root):
+    edit_json(root / "benchmark" / "layer_metrics" / "block_ms.json",
+              lambda spec: spec["args"]["scopes"].append("model/made_up_mixer"))
+
+
+@pytest.mark.parametrize("fault, rule, metric, cell, says", [
+    (leave_out_of_the_held_share, "R1", "moe_held_share_pct", "drawn-cell",
+     "cell `drawn-cell` runs `held_experts`, so it owes `moe_held_share_pct`: add it to that metric's "
+     "`workloads` in BENCHMARK.json"),
+    (list_under_the_own_kernels, "R1", "attn_kernel_ms", "drawn-cell",
+     "cell `drawn-cell` does not run `own_attention_kernels`, which `attn_kernel_ms` needs: take it out of "
+     "that metric's `workloads` in BENCHMARK.json"),
+    (give_the_scope_a_second_owner, "R2", "made_up_mixer_ms", None,
+     "the scope `model/made_up_mixer` is owned by `block_ms` and by `made_up_mixer_ms`"),
+])
+def test_a_breach_is_a_sentence_that_names_the_metric_the_cell_and_the_list(drawn, fault, rule, metric, cell, says):
+    follow_the_sentences(drawn)
+    fault(drawn)
+    found = Manifest(root=str(drawn)).breaches([*declared_scopes(), "model/made_up_mixer"])
+    assert len(found) == 1, [str(b) for b in found]
+    assert (found[0].rule, found[0].metric, found[0].cell) == (rule, metric, cell)
+    assert says in found[0].sentence and str(found[0]).startswith(rule + ": ")
 
 
 TOY_REFERENCE = '''

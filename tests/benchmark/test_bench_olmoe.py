@@ -142,18 +142,17 @@ def test_the_cells_are_what_the_issue_defined():
     assert "moe_experts_ms" not in [s["name"] for s in m.layer_metrics("neo125m-dpu-1chip")]
 
 
-def test_every_cells_attention_kernels_have_a_reader():
-    """``attn_kernel_ms`` / ``attn_kernel_roofline`` read this repo's own
-    kernels by name: every cell that runs them reports both, the DPU cell
-    (the ACCO cell's kernels) included. The OLMoE cell runs the stock flash
-    kernel, which they do not look for: it reports the two that do."""
+def test_the_configuration_says_which_kernels_and_scopes_it_runs(config):
+    """The stock flash kernel and the three expert scopes, and not this repo's
+    own kernels, a shared expert or a chip's share: what the cell lists follows
+    from that (``tests/benchmark/test_bench_rules.py`` holds every cell to it)."""
+    assert config["meta"]["runs"] == ["stock_flash_kernels", "experts"]
     m = Manifest()
-    own = {"attn_kernel_ms", "attn_kernel_roofline"}
-    stock = {"flash_attn_kernel_ms", "flash_attn_kernel_roofline"}
     for cell in m.cell_names():
-        names = {s["name"] for s in m.layer_metrics(cell)}
-        wanted, other = (stock, own) if cell.startswith("olmoe") else (own, stock)
-        assert wanted <= names and not other & names, cell
+        if m.cell(cell)["config"] == config["name"]:
+            assert m.features(cell) == {"stock_flash_kernels", "experts"}
+            assert {"flash_attn_kernel_ms", "flash_attn_kernel_roofline"} <= {
+                s["name"] for s in m.layer_metrics(cell)}
 
 
 def test_the_flash_kernels_are_read_by_the_names_the_chip_gives_them(config):
@@ -201,23 +200,22 @@ def test_the_flash_kernels_are_read_by_the_names_the_chip_gives_them(config):
         assert m.reducer(own[name]["reducer"])(ctx, own[name]["args"]) is None
 
 
-def test_the_expert_scopes_have_one_owner_each_among_the_new_metrics():
-    """The accepted scope metrics partition ``DEVICE_SCOPES``
-    (test_bench_hostplane.py); the three new ones own the three scopes an
-    expert model adds, one each, and leave the same ops (the collectives) out:
-    in the OLMoE cell the ten of them still add up to the device's busy time."""
-    from acco_tpu.telemetry import ALL_DEVICE_SCOPES, DEVICE_SCOPES, EXPERT_DEVICE_SCOPES
-
-    def args(metric):
-        with open(os.path.join(ROOT, "benchmark", "layer_metrics", f"{metric}.json")) as f:
-            return json.load(f)["args"]
-
-    new = {m: args(m) for m in ("moe_router_ms", "moe_dispatch_ms", "moe_experts_ms")}
-    assert sorted(s for a in new.values() for s in a["scopes"]) == sorted(EXPERT_DEVICE_SCOPES)
-    assert set(ALL_DEVICE_SCOPES) == set(DEVICE_SCOPES) | set(EXPERT_DEVICE_SCOPES)
-    assert {a["except_ops"] for a in new.values()} == {args("block_ms")["except_ops"]}
-    roofline = args("moe_experts_roofline")
-    assert roofline["scopes"] == new["moe_experts_ms"]["scopes"] and roofline["work"] == "expert_matmul_work"
+def test_the_expert_scopes_have_one_owner_each_among_the_expert_metrics(config):
+    """The three scopes ``ops/moe.py`` gives an expert layer's ops have one
+    metric each, all three need the feature this configuration runs, and the
+    roofline counts the grouped matmuls' work over the experts' scope. (That
+    no scope has two owners, and that a cell's scope metrics add up, is rule
+    R2 of ``tests/benchmark/test_bench_rules.py``.)"""
+    m = Manifest()
+    spec = {s["name"]: s for s in m.layer_metrics("olmoe-l1-acco-1chip")}
+    new = {name: spec[name] for name in ("moe_router_ms", "moe_dispatch_ms", "moe_experts_ms")}
+    assert {name: s["args"]["scopes"] for name, s in new.items()} == {
+        "moe_router_ms": ["model/moe_router"], "moe_dispatch_ms": ["model/moe_dispatch"],
+        "moe_experts_ms": ["model/moe_experts"]}
+    assert {s["needs"] for s in new.values()} == {"experts"} <= set(config["meta"]["runs"])
+    assert {s["args"]["except_ops"] for s in new.values()} == {spec["block_ms"]["args"]["except_ops"]}
+    roofline = spec["moe_experts_roofline"]["args"]
+    assert roofline["scopes"] == new["moe_experts_ms"]["args"]["scopes"] and roofline["work"] == "expert_matmul_work"
 
 
 # -- the reference check on a tiny OLMoE, brought as files only -----------------

@@ -55,7 +55,7 @@ def test_rehearsal_runs_end_to_end_and_prints_no_result_line():
     assert result_lines(out) == []
     would = json.loads(out.split("what the line would hold: ", 1)[1].splitlines()[0])
     # spans, the warmup report and the profiler's trace were all read
-    assert {"dispatch_ms", "loader_wait_ms", "log_sync_ms", "compile_lower_s",
+    assert {"dispatch_ms", "loader_wait_ms", "log_sync_ms", "compile_lower_wall_s",
             "round_device_ms", "device_idle_pct"} <= set(would)
     assert "mfu_pct" not in would  # no peak on record for a CPU: no share of it
 
@@ -72,6 +72,27 @@ def test_the_measurement_path_refuses_a_cpu(trace):
 def test_unknown_cell_fails_before_any_child():
     done = run("--workload", "no-such-cell", "--seconds", "1", timeout=60)
     assert done.returncode != 0 and "no-such-cell" in done.stdout
+    assert result_lines(done.stdout) == []
+
+
+def test_a_breach_of_the_rule_is_met_before_any_child(tmp_path):
+    """A cell listed under a metric whose feature its configuration does not
+    run: the parent says so in a sentence and starts no child, where the driver
+    would have met a line that lacks the metric."""
+    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    for name in ("main.py", "acco_tpu", "config"):  # the program, as it stands
+        os.symlink(os.path.join(ROOT, name), tmp_path / name)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        data = json.load(f)
+    next(m for m in data["per_layer"] if m["name"] == "moe_held_share_pct")["workloads"].append(
+        "neo125m-ddp-1chip")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(data))
+    done = run("--workload", "neo125m-acco-1chip", "--seed", "1", "--seconds", "1", "--rehearse",
+               cwd=str(tmp_path), script=str(tmp_path / "benchmark" / "run.py"), timeout=60)
+    assert done.returncode != 0 and "--- schedule" not in done.stdout
+    assert ("R1: cell `neo125m-ddp-1chip` does not run `held_experts`, which `moe_held_share_pct` needs: "
+            "take it out of that metric's `workloads` in BENCHMARK.json") in done.stdout
     assert result_lines(done.stdout) == []
 
 
